@@ -50,6 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pytorch_distributed_tpu.utils.compat import vma_of
+
 LOG2E = 1.4426950408889634  # log2(e): natural-domain scores -> exp2 domain
 LN2 = 0.6931471805599453
 NEG_INF = -1e30  # finite; -inf would turn all-masked rows into NaNs
@@ -71,17 +73,19 @@ def _pick_block(t: int, preferred: int) -> int:
 def _compiler_params(vmem_limit_bytes: int | None = None):
     # b and h grid dims are independent; the innermost dim carries
     # sequential state (fwd: resident K/V reuse; bwd: dq accumulation).
-    kw = {"dimension_semantics": ("parallel", "parallel", "arbitrary")}
-    if vmem_limit_bytes is not None:
-        kw["vmem_limit_bytes"] = vmem_limit_bytes
-    # Staged fallback across jax-version signature drift: losing the new
-    # vmem kwarg must not silently drop dimension_semantics with it.
-    while kw:
-        try:
-            return {"compiler_params": pltpu.CompilerParams(**kw)}
-        except (TypeError, AttributeError):
-            kw.pop(sorted(kw)[-1])  # vmem_limit_bytes first, then the rest
-    return {}
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_limit_bytes,
+    )
+
+
+def out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """A ``pallas_call`` out_shape typed varying over every mesh axis an
+    operand varies over. Inside ``shard_map(check_vma=True)`` (explicit,
+    pipeline, Ulysses, TP serving) ``pallas_call`` refuses an out_shape
+    whose ``vma`` is None; outside one the set is empty."""
+    vma = frozenset().union(*(vma_of(x) for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 # --------------------------------------------------------------------------
@@ -198,8 +202,8 @@ def _fwd_call(q, k, v, causal, scale, bq, bk, interpret):
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, t, _LANES), jnp.float32),
+            out_struct((b, hq, t, d), q.dtype, q, k, v),
+            out_struct((b, hq, t, _LANES), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
@@ -207,7 +211,8 @@ def _fwd_call(q, k, v, causal, scale, bq, bk, interpret):
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        **_compiler_params(),
+        compiler_params=_compiler_params(),
+        name="flash_mha_fwd",
     )(q, k, v)
     # Compact residual: the padded copy is dead after this slice.
     return o, lse[..., 0]
@@ -366,9 +371,8 @@ def _bwd_call(q, k, v, do, lse, delta, causal, scale, bq, bk, interpret):
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, t, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, t, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, t, d), jnp.float32),
+            out_struct((b, hq, t, d), jnp.float32, q, k, v, do)
+            for _ in range(3)
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
@@ -383,11 +387,12 @@ def _bwd_call(q, k, v, do, lse, delta, causal, scale, bq, bk, interpret):
         # per-kernel limit so long-context training compiles out of the
         # box; at or below it (every bench shape), leave the default
         # untouched so the measured schedules don't shift.
-        **_compiler_params(
+        compiler_params=_compiler_params(
             vmem_limit_bytes=(
                 96 * 1024 * 1024 if t * d > 4096 * 64 else None
             )
         ),
+        name="flash_mha_bwd",
     )(q, k, v, do, lse8, delta8)
     return dq, dk, dv
 

@@ -10,7 +10,7 @@ lets ``slots`` scale with the pool instead of ``slots x max_len``
 The kernel is the piece that makes per-row attention cost scale with the
 row's DEPTH instead of ``max_len``:
 
-- grid ``(B, Hkv, n_pages)`` with the page dimension innermost and
+- grid ``(B, n_pages)`` with the page dimension innermost and
   sequential (online-softmax accumulator state lives in VMEM scratch
   across it);
 - the block tables and per-row lengths ride ``PrefetchScalarGridSpec``
@@ -22,21 +22,28 @@ row's DEPTH instead of ``max_len``:
   and their DMA re-reads the row's last useful page id — the host fills
   unallocated table entries with the scratch page 0, so the skipped
   fetch is bounded and harmless);
-- grouped-query heads share their KV head inside the kernel: the grid
-  walks KV heads and each step computes the whole ``group = H // Hkv``
-  query-head block against one [page, D] key block.
+- one grid step holds ALL heads of one page. Mosaic tiles the last two
+  block dims, so a block must cover them whole (or in (8, 128)
+  multiples): ``(1, page, Hkv, D)`` over the pool, ``(1, H, D)`` over the
+  queries, ``(1, page, Hkv)`` over the int8 scale pool. The body walks
+  the KV heads in a static loop, reading head ``g`` of the page as
+  ``k_ref[0, :, g, :]`` and computing the whole ``group = H // Hkv``
+  query-head block against that [page, D] key block, so grouped-query
+  heads share their KV head inside the kernel.
 
 GQA + per-row depth masking match ``models/decode._cached_attention``'s
 masked-softmax math up to online-softmax reassociation (floating-point
 reordering only — the equivalence test pins allclose, and engine-level
 token equality is pinned separately on the gather path).
 
-Off-TPU (this repo's CPU rig) the kernel runs in INTERPRET mode — the
-dispatcher defaults to it automatically — and the serving engine's
-default paged attention is the pure-XLA ``gather_pages`` fallback in
-models/decode.py, which is bit-identical to the dense engine's math (the
-property the paged-vs-dense token-equality pins rely on). Read
-/opt/skills/guides/pallas_guide.md before touching the kernel body.
+``interpret`` is the caller's decision: the compiled kernel needs a TPU,
+and a caller off the chip says ``interpret=True`` itself (the CPU tests
+do; the engine's ``paged_attention="kernel_interpret"`` does). The
+serving engine's default paged attention is the pure-XLA ``gather_pages``
+fallback in models/decode.py, which is bit-identical to the dense
+engine's math (the property the paged-vs-dense token-equality pins rely
+on). Read /opt/skills/guides/pallas_guide.md before touching the kernel
+body.
 """
 
 from __future__ import annotations
@@ -48,7 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pytorch_distributed_tpu.ops.flash_kernel import _compiler_params
+from pytorch_distributed_tpu.ops.flash_kernel import out_struct
 
 NEG_INF = -1e30  # finite mask (matches ops/attention.py): -inf NaNs softmax
 
@@ -56,20 +63,31 @@ NEG_INF = -1e30  # finite mask (matches ops/attention.py): -inf NaNs softmax
 def _paged_kernel(
     tables_ref,  # [B, n_pages] int32 (scalar prefetch)
     lens_ref,  # [B] int32 (scalar prefetch): row's query position
-    q_ref,  # [1, group, D]
-    k_ref,  # [1, page, 1, D] — the page tables_ref[b, i], head h
-    v_ref,  # [1, page, 1, D]
-    o_ref,  # [1, group, D]
-    acc_sc,  # [group, D] f32
-    m_sc,  # [group, 1] f32
-    l_sc,  # [group, 1] f32
-    *,
+    q_ref,  # [1, H, D]
+    k_ref,  # [1, page, Hkv, D] — the page tables_ref[b, i], all heads
+    v_ref,  # [1, page, Hkv, D]
+    *rest,  # int8 pages: ks_ref, vs_ref [1, page, Hkv] f32; then o_ref
+    # [1, H, D] and the f32 scratch acc [H, D], m [H, 1], l [H, 1]
     page: int,
     n_pages: int,
     scale: float,
+    quantized: bool,
 ):
+    """Online-softmax over one row's pages. With ``quantized`` the page
+    DMA moves INT8 K/V blocks plus their per-token f32 scales and
+    dequantization happens in VMEM right before the dot — HBM traffic
+    for a page drops to (D + 4)/(4D) of the f32 kernel's. Numerics past
+    the dequant are the full-precision kernel's exactly (same
+    accumulator dtypes, same masking), so quantized-vs-gather
+    equivalence is pinned the same way (tests/test_quant.py)."""
+    if quantized:
+        ks_ref, vs_ref, o_ref, acc_sc, m_sc, l_sc = rest
+    else:
+        o_ref, acc_sc, m_sc, l_sc = rest
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
+    hkv = k_ref.shape[2]
+    group = q_ref.shape[1] // hkv
 
     @pl.when(i == 0)
     def _init():
@@ -83,92 +101,35 @@ def _paged_kernel(
     # short row is its own page count, not max_len.
     @pl.when(i * page <= length)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [group, D]
-        kb = k_ref[0, :, 0, :].astype(jnp.float32)  # [page, D]
-        vb = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [group, page]
-        kpos = i * page + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        s = jnp.where(kpos <= length, s, NEG_INF)
-        m_new = jnp.maximum(m_sc[:], jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_sc[:] - m_new)
-        l_sc[:] = l_sc[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_sc[:] = m_new
-
-    @pl.when(i == n_pages - 1)
-    def _emit():
-        o_ref[0] = (
-            acc_sc[:] / jnp.maximum(l_sc[:], 1e-30)
-        ).astype(o_ref.dtype)
-
-
-def _paged_kernel_q8(
-    tables_ref,  # [B, n_pages] int32 (scalar prefetch)
-    lens_ref,  # [B] int32 (scalar prefetch): row's query position
-    q_ref,  # [1, group, D]
-    k_ref,  # [1, page, 1, D] int8 — the page tables_ref[b, i], head h
-    v_ref,  # [1, page, 1, D] int8
-    ks_ref,  # [1, page, 1] f32 per-token K scales for the same page/head
-    vs_ref,  # [1, page, 1] f32
-    o_ref,  # [1, group, D]
-    acc_sc,  # [group, D] f32
-    m_sc,  # [group, 1] f32
-    l_sc,  # [group, 1] f32
-    *,
-    page: int,
-    n_pages: int,
-    scale: float,
-):
-    """The int8 twin of ``_paged_kernel``: identical online-softmax
-    structure, but the page DMA moves INT8 K/V blocks plus their
-    per-token f32 scales, and dequantization happens in VMEM right
-    before the dot — HBM traffic for a page drops to (D + 4)/(4D) of
-    the f32 kernel's. Numerics past the dequant are the f32 kernel's
-    exactly (same accumulator dtypes, same masking), so quantized-vs-
-    gather equivalence is pinned the same way (tests/test_quant.py)."""
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc[:])
-        m_sc[:] = jnp.full_like(m_sc[:], NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc[:])
-
-    length = lens_ref[b]
-
-    @pl.when(i * page <= length)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [group, D]
-        # Dequant-in-kernel: int8 page block * per-token scale column.
-        kb = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-        vb = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [group, page]
-        kpos = i * page + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        s = jnp.where(kpos <= length, s, NEG_INF)
-        m_new = jnp.maximum(m_sc[:], jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_sc[:] - m_new)
-        l_sc[:] = l_sc[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_sc[:] = m_new
+        for g in range(hkv):
+            rows = slice(g * group, (g + 1) * group)
+            q = q_ref[0, rows, :].astype(jnp.float32)  # [group, D]
+            kb = k_ref[0, :, g, :].astype(jnp.float32)  # [page, D]
+            vb = v_ref[0, :, g, :].astype(jnp.float32)
+            if quantized:
+                # Dequant-in-kernel: int8 block * per-token scale column.
+                kb = kb * ks_ref[0, :, g:g + 1]
+                vb = vb * vs_ref[0, :, g:g + 1]
+            s = jax.lax.dot_general(
+                q, kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [group, page]
+            kpos = i * page + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1
+            )
+            s = jnp.where(kpos <= length, s, NEG_INF)
+            m_prev = m_sc[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_sc[rows, :] = l_sc[rows, :] * corr + jnp.sum(
+                p, axis=-1, keepdims=True
+            )
+            acc_sc[rows, :] = acc_sc[rows, :] * corr + jax.lax.dot_general(
+                p, vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_sc[rows, :] = m_new
 
     @pl.when(i == n_pages - 1)
     def _emit():
@@ -181,99 +142,55 @@ def _paged_kernel_q8(
 # K/V pages belong to the serving engine's donated cache (aliased at the
 # PROGRAM boundary, not here) and q is read by the caller's residual.
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_call(q, k_pages, v_pages, block_tables, lengths, interpret):
+def _paged_call(q, k_pages, v_pages, scales, block_tables, lengths,
+                interpret):
+    """``scales`` is ``()`` for full-precision pages or the
+    ``(k_scales, v_scales)`` pools for int8 pages."""
     b, h, d = q.shape
     n_pages = block_tables.shape[1]
     page, hkv = k_pages.shape[1], k_pages.shape[2]
-    group = h // hkv
     kernel = functools.partial(
         _paged_kernel,
         page=page, n_pages=n_pages, scale=1.0 / (d**0.5),
+        quantized=bool(scales),
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, n_pages),
-        in_specs=[
-            pl.BlockSpec(
-                (1, group, d), lambda bi, hi, i, tables, lens: (bi, hi, 0)
-            ),
-            pl.BlockSpec(
-                (1, page, 1, d),
-                lambda bi, hi, i, tables, lens: (tables[bi, i], 0, hi, 0),
-            ),
-            pl.BlockSpec(
-                (1, page, 1, d),
-                lambda bi, hi, i, tables, lens: (tables[bi, i], 0, hi, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, group, d), lambda bi, hi, i, tables, lens: (bi, hi, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
-        **_compiler_params(),
-    )(block_tables, lengths, q, k_pages, v_pages)
-
-
-# repolint: allow(jit-donation-decision) — functional attention op, same
-# aliasing story as _paged_call (the pool is donated at the engine
-# program boundary, never here).
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_call_q8(q, k_pages, v_pages, k_scales, v_scales,
-                   block_tables, lengths, interpret):
-    b, h, d = q.shape
-    n_pages = block_tables.shape[1]
-    page, hkv = k_pages.shape[1], k_pages.shape[2]
-    group = h // hkv
-    kernel = functools.partial(
-        _paged_kernel_q8,
-        page=page, n_pages=n_pages, scale=1.0 / (d**0.5),
+    row_spec = pl.BlockSpec(
+        (1, h, d), lambda bi, i, tables, lens: (bi, 0, 0)
     )
     page_spec = pl.BlockSpec(
-        (1, page, 1, d),
-        lambda bi, hi, i, tables, lens: (tables[bi, i], 0, hi, 0),
+        (1, page, hkv, d),
+        lambda bi, i, tables, lens: (tables[bi, i], 0, 0, 0),
     )
     scale_spec = pl.BlockSpec(
-        (1, page, 1),
-        lambda bi, hi, i, tables, lens: (tables[bi, i], 0, hi),
+        (1, page, hkv),
+        lambda bi, i, tables, lens: (tables[bi, i], 0, 0),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, n_pages),
-        in_specs=[
-            pl.BlockSpec(
-                (1, group, d), lambda bi, hi, i, tables, lens: (bi, hi, 0)
-            ),
-            page_spec,
-            page_spec,
-            scale_spec,
-            scale_spec,
-        ],
-        out_specs=pl.BlockSpec(
-            (1, group, d), lambda bi, hi, i, tables, lens: (bi, hi, 0)
-        ),
+        grid=(b, n_pages),
+        in_specs=[row_spec, page_spec, page_spec]
+        + [scale_spec] * len(scales),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=out_struct(
+            (b, h, d), q.dtype, q, k_pages, v_pages, *scales
+        ),
         interpret=interpret,
-        **_compiler_params(),
-    )(block_tables, lengths, q, k_pages, v_pages, k_scales, v_scales)
+        # Rows are independent; the page dim carries the online-softmax
+        # state.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        name="paged_decode_attention",
+    )(block_tables, lengths, q, k_pages, v_pages, *scales)
 
 
 def paged_decode_attention(
@@ -289,14 +206,22 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Paged single-query attention, [B, H, D] -> [B, H, D]. ``lengths``
     is each row's query position: key j is attended iff j <= lengths[b]
-    (the dense decode-step mask at T=1). ``interpret=None`` picks the
-    compiled kernel on TPU and interpreter mode elsewhere.
+    (the dense decode-step mask at T=1). ``interpret=None`` means the
+    compiled kernel and is an error off the chip — interpreter mode is
+    never chosen for the caller.
 
     ``k_scales``/``v_scales`` switch to the int8 kernel: pages are int8
     with per-token/per-head f32 scales and dequantization happens in
     VMEM (the bandwidth-bound read moves quarter-width pages)."""
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        platform = jax.devices()[0].platform
+        if platform != "tpu":
+            raise RuntimeError(
+                f"paged_decode_attention: the compiled kernel needs a "
+                f"TPU and jax.devices()[0].platform is {platform!r}; "
+                "pass interpret=True to run the Pallas interpreter"
+            )
+        interpret = False
     h, hkv = q.shape[1], k_pages.shape[2]
     if h % hkv:
         raise ValueError(
@@ -307,15 +232,9 @@ def paged_decode_attention(
             "k_scales and v_scales must be given together (int8 pages) "
             "or both omitted (full-precision pages)"
         )
-    if k_scales is not None:
-        return _paged_call_q8(
-            q, k_pages, v_pages, k_scales, v_scales,
-            jnp.asarray(block_tables, jnp.int32),
-            jnp.asarray(lengths, jnp.int32),
-            bool(interpret),
-        )
     return _paged_call(
         q, k_pages, v_pages,
+        () if k_scales is None else (k_scales, v_scales),
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(lengths, jnp.int32),
         bool(interpret),

@@ -20,6 +20,15 @@ two backends behind one entry point:
 
 GQA: the kernel maps query head h to KV head h // group via BlockSpec
 index maps (no materialized repeat); the blockwise fallback repeats.
+
+Partitioning: GSPMD cannot split a Mosaic custom call (on more than one
+chip jax refuses to lower one: "Mosaic kernels cannot be automatically
+partitioned"), so under the pjit path (parallel/api.py traces the step
+under its mesh) the kernel call is wrapped in a ``shard_map`` over the
+mesh axes that shard the batch and the heads — each chip runs the kernel
+on its own rows. Inside an enclosing
+``shard_map`` (explicit, pipeline, Ulysses, TP serving) every axis is
+already manual and the kernel sees local shapes as it is.
 """
 
 from __future__ import annotations
@@ -28,6 +37,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from pytorch_distributed_tpu.ops.attention import NEG_INF, _repeat_kv
 from pytorch_distributed_tpu.utils.compat import vma_of
@@ -54,6 +64,28 @@ def _pallas_supported(t: int, s: int, d: int) -> bool:
     )
 
 
+def _gspmd_activation_spec(n_kv_head: int) -> P | None:
+    """How the ambient mesh shards a [B, T, H, D] activation over its
+    AUTOMATIC axes — batch over data/fsdp/expert, heads over tensor when
+    it divides the KV heads (query heads then split into the same
+    groups) — or None when there is nothing to partition: no ambient
+    mesh (single device), every axis already manual (inside a
+    shard_map), or every automatic axis of size 1."""
+    from pytorch_distributed_tpu.parallel.mesh import BATCH_AXES
+
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {ax for ax in mesh.auto_axes if mesh.shape[ax] > 1}
+    batch = tuple(ax for ax in BATCH_AXES if ax in auto)
+    heads = (
+        "tensor"
+        if "tensor" in auto and n_kv_head % mesh.shape["tensor"] == 0
+        else None
+    )
+    if not batch and heads is None:
+        return None
+    return P(batch or None, None, heads, None)
+
+
 def flash_attention(
     q: jax.Array,  # [B, T, H, D]
     k: jax.Array,  # [B, S, Hkv, D]
@@ -71,7 +103,13 @@ def flash_attention(
     b, t, h, d = q.shape
     s = k.shape[1]
     if _pallas_supported(t, s, d):
-        return _pallas_flash(q, k, v, causal=causal)
+        kernel = functools.partial(_pallas_flash, causal=causal)
+        spec = _gspmd_activation_spec(k.shape[2])
+        if spec is None:
+            return kernel(q, k, v)
+        return jax.shard_map(
+            kernel, in_specs=(spec, spec, spec), out_specs=spec
+        )(q, k, v)
     return blockwise_attention(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k
     )

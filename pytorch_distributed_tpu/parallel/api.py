@@ -82,6 +82,14 @@ def make_parallel_train_step(
         accum_dtype=accum_dtype,
         guard=guard,
     )
+
+    def step_under_mesh(state, batch, dropout_key):
+        # Traced under the mesh so an op GSPMD cannot partition (the
+        # Pallas flash custom call, ops/pallas_flash.py) can find it and
+        # shard_map itself over the axes that shard its operands.
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return base_step(state, batch, dropout_key)
+
     batch_sharding = NamedSharding(mesh, batch_spec)
     metrics_sharding = NamedSharding(mesh, jax.sharding.PartitionSpec())
 
@@ -89,7 +97,7 @@ def make_parallel_train_step(
     if guard is not None:
         metrics_shardings["anomaly"] = metrics_sharding
     step = jax.jit(
-        base_step,
+        step_under_mesh,
         in_shardings=(
             shardings,
             {"inputs": batch_sharding, "targets": batch_sharding},

@@ -23,19 +23,29 @@ from jax.experimental import mesh_utils
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pytorch_distributed_tpu.config import MeshConfig
+from pytorch_distributed_tpu.utils.logging import get_logger
 
 
 def initialize_distributed() -> None:
-    """Multi-host rendezvous (idempotent). On a single-process TPU or CPU
-    testbed this is a no-op; on a pod each host calls it once before any
-    devices are used (the torchrun-rendezvous analogue)."""
-    if jax.process_count() > 1:
-        return  # already initialised by the launcher
+    """Multi-host rendezvous (the torchrun-rendezvous analogue): each
+    host calls it once BEFORE anything touches a device — jax refuses to
+    join a cluster once its backend is up. A process for which jax's
+    cluster detection comes up empty — no coordinator in the environment
+    (ValueError), or a lone TPU host whose metadata server cannot be
+    reached (OSError; the sealed v5e host answers so) — is left
+    single-process, and the failure is logged by name. Every other
+    failure — a detected cluster whose rendezvous fails, a call made
+    after the backend was initialised — raises: hosts that silently train
+    alone would each report a healthy run."""
+    if jax.distributed.is_initialized():
+        return  # already joined by the launcher
     try:
         jax.distributed.initialize()
-    except (ValueError, RuntimeError):
-        # Single-process: no coordinator configured — fine.
-        pass
+    except (ValueError, OSError) as e:
+        get_logger().warning(
+            f"jax.distributed.initialize() found no cluster to join "
+            f"({type(e).__name__}: {e}); running single-process"
+        )
 
 
 def process_info() -> dict:
@@ -73,13 +83,24 @@ def make_mesh(cfg: MeshConfig, devices=None) -> Mesh:
             f"{len(devices)} available"
         )
     shape = tuple(cfg.shape.values())
-    try:
-        arr = mesh_utils.create_device_mesh(
-            shape, devices=list(devices)[:n]
-        )
-    except (ValueError, NotImplementedError, AssertionError):
-        # Non-TPU topologies (CPU test meshes): plain reshape is fine.
-        arr = np.array(list(devices)[:n]).reshape(shape)
+    devices = list(devices)[:n]
+    if devices[0].platform != "tpu":
+        # CPU meshes have no topology to honour: device order is the mesh.
+        arr = np.array(devices).reshape(shape)
+    else:
+        try:
+            arr = mesh_utils.create_device_mesh(shape, devices=devices)
+        except (ValueError, NotImplementedError, AssertionError) as e:
+            # Any device order is a correct mesh, only a slower one
+            # (e.g. a replica's sub-slice that is no torus): keep going,
+            # but name what was lost.
+            get_logger().warning(
+                f"create_device_mesh({shape}) failed on "
+                f"{[d.id for d in devices]}: {type(e).__name__}: {e} — "
+                "falling back to device order; collectives may not ride "
+                "neighbouring ICI links"
+            )
+            arr = np.array(devices).reshape(shape)
     return Mesh(arr, axis_names=cfg.axis_order)
 
 
@@ -104,6 +125,10 @@ def fold_batch_shard_key(dropout_key, mesh_cfg: MeshConfig):
     return dropout_key
 
 
+# Mesh axes the global batch is split over (see batch_partition_spec).
+BATCH_AXES = ("data", "fsdp", "expert")
+
+
 def batch_partition_spec(cfg: MeshConfig) -> P:
     """Global-batch sharding: batch dim split over data AND fsdp axes (FSDP
     is data parallelism with sharded state — each fsdp shard still consumes
@@ -112,7 +137,7 @@ def batch_partition_spec(cfg: MeshConfig) -> P:
     sequence dim split over seq for context parallelism. [A, B, T] batches
     shard B and T."""
     batch_axes = tuple(
-        ax for ax in ("data", "fsdp", "expert") if getattr(cfg, ax) > 1
+        ax for ax in BATCH_AXES if getattr(cfg, ax) > 1
     ) or None
     seq_axis = "seq" if cfg.seq > 1 else None
     return P(None, batch_axes, seq_axis)

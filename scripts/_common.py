@@ -127,7 +127,9 @@ def add_common_args(p: argparse.ArgumentParser, *, preset: str) -> None:
 
 
 def setup_platform(args) -> None:
-    """MUST run before any jax import."""
+    """MUST run before any jax import. Also places the persistent compile
+    cache (utils/compile_cache.py), so a script's second run on a machine
+    does not compile again."""
     if args.cpu_devices:
         # Strip any stale device-count flag first: re-entrant calls (or a
         # flag inherited from the environment) must not leave two counts
@@ -148,6 +150,11 @@ def setup_platform(args) -> None:
         import jax
 
         jax.config.update("jax_debug_nans", True)
+    from pytorch_distributed_tpu.utils.compile_cache import (
+        place_compile_cache,
+    )
+
+    place_compile_cache()
 
 
 def build_model_cfg(args):

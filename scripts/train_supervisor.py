@@ -198,6 +198,9 @@ def run_worker(args) -> int:
 
 
 def _spawn_worker(args, leg: str, attempt: int, log_dir: Path) -> int:
+    # The supervisor never initialises jax, so it holds no chip: workers
+    # run one at a time and may take whatever platform the environment
+    # names (the CPU when it names none).
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
     log = log_dir / f"worker_{leg}_{attempt}.log"
