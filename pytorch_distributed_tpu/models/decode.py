@@ -27,9 +27,16 @@ Design (TPU-first):
   out, so stale cache contents are never read — the invariant that makes
   both prompt bucketing and dirty-buffer cache donation sound).
 - Layers run under the shared ``ops/layer_scan.scan_layers`` scan-over-
-  stacked-params (``collect_ys=True`` carries the per-layer cache slices),
-  so the windowed double-buffer prefetch schedule training uses applies to
-  ZeRO-3 decode as well (``block_transform`` + ``prefetch_buffers``).
+  stacked-params, so the windowed double-buffer prefetch schedule training
+  uses applies to ZeRO-3 decode as well (``block_transform`` +
+  ``prefetch_buffers``). The stacked cache rides the scan's CARRY beside
+  the activations and the layer index is the only per-layer input: each
+  block scatters its new tokens into the stacked leaves at ``(layer, ...)``
+  and attention reads them back at ``(layer, ...)``, so a donated cache is
+  updated where it lies — no per-layer slice out, no stacked copy back.
+  (On the v5e the runtime stores a ``[..., Hkv, D]`` leaf page-axis-minor
+  and XLA still converts the whole pool at program entry and exit: what
+  is left of the cost is the stored SHAPE's, PERF.md section 5.)
 - Attention here is the naive einsum path in f32: decode is matmul-light
   ([B, H, T, S] with T = 1), so flash-kernel dispatch is pointless.
 - Sampling params (``temperature``/``top_k``/``top_p``) are TRACED runtime
@@ -112,26 +119,29 @@ def init_paged_cache(
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def gather_pages(cache_layer: jax.Array, block_tables: jax.Array):
-    """[P, page, ...] pool + [B, n_pages] tables -> the [B, S, ...]
-    contiguous per-row view dense attention expects (S = n_pages * page;
-    trailing dims pass through, so int8 value pools [P, page, Hkv, D]
-    and their scale pools [P, page, Hkv] gather through the same code).
-    Unallocated table entries point at the scratch page — garbage the
-    ``pos`` mask already excludes, exactly like a dense row's unwritten
-    tail. This is the XLA fallback the CPU rig runs; the Pallas decode
-    kernel (ops/paged_kernel.py) reads pages in place instead."""
+def gather_pages(pool: jax.Array, layer, block_tables: jax.Array):
+    """Stacked [L, P, page, ...] pool + layer index + [B, n_pages]
+    tables -> the [B, S, ...] contiguous per-row view of that layer
+    dense attention expects (S = n_pages * page; trailing dims pass
+    through, so int8 value pools [L, P, page, Hkv, D] and their scale
+    pools [L, P, page, Hkv] gather through the same code). ONE gather
+    indexed by (layer, page id): the layer's pool is never sliced out on
+    its own. Unallocated table entries point at the scratch page —
+    garbage the ``pos`` mask already excludes, exactly like a dense
+    row's unwritten tail. This is the XLA fallback the CPU rig runs; the
+    Pallas decode kernel (ops/paged_kernel.py) reads pages in place
+    instead."""
     b, n_pages = block_tables.shape
-    page = cache_layer.shape[1]
-    return cache_layer[block_tables].reshape(
-        (b, n_pages * page) + cache_layer.shape[2:]
+    page = pool.shape[2]
+    return pool[layer, block_tables].reshape(
+        (b, n_pages * page) + pool.shape[3:]
     )
 
 
-def _cached_attention(q, kv, pos, block_tables=None,
+def _cached_attention(q, cache, layer, pos, block_tables=None,
                       paged_impl="gather", kv_quant="none"):
-    """q [B, T, H, D] against the full cache ``kv`` ({"k", "v"} leaves
-    [B, S, Hkv, D]); queries sit at
+    """q [B, T, H, D] against layer ``layer`` of the stacked cache
+    ({"k", "v"} leaves [L, B, S, Hkv, D]); queries sit at
     global positions pos..pos+T-1, keys j are valid iff j <= pos + i.
     ``pos`` is a scalar (every row at the same position — the single-request
     paths) or a [B] vector (slot-batched decode: each row carries its own
@@ -139,20 +149,19 @@ def _cached_attention(q, kv, pos, block_tables=None,
     ever read — is independent of its neighbours).
 
     ``block_tables`` [B, n_pages] switches to the PAGED cache layout
-    (k/v are [P, page, Hkv, D] pools): the gather fallback materialises
+    (k/v are [L, P, page, Hkv, D] pools): the gather fallback materialises
     the per-row view and runs the identical masked math (bit-equal to the
     dense path wherever the valid positions hold the same values); for
     single-token decode, ``paged_impl`` of "kernel"/"kernel_interpret"
     dispatches the Pallas paged-attention kernel instead, which reads
     pages in place and skips pages past each row's depth.
 
-    ``kv_quant="int8"`` (paged only): ``kv`` additionally carries
+    ``kv_quant="int8"`` (paged only): ``cache`` additionally carries
     ``k_scale``/``v_scale`` pools; the gather path dequantizes the
     gathered view (one int8->f32 convert per K and V — the audit's q8
     cast budget counts them) and runs the identical masked math, the
     kernel path dequantizes page blocks in VMEM (dequant-in-kernel —
     HBM only ever moves int8 pages + scales)."""
-    ck, cv = kv["k"], kv["v"]
     if block_tables is not None and q.shape[1] == 1 and (
         paged_impl in ("kernel", "kernel_interpret")
     ):
@@ -160,28 +169,29 @@ def _cached_attention(q, kv, pos, block_tables=None,
             paged_decode_attention,
         )
 
-        scales = (
-            (kv["k_scale"], kv["v_scale"]) if kv_quant == "int8"
-            else (None, None)
-        )
         out = paged_decode_attention(
-            q[:, 0], ck, cv, block_tables, pos,
-            k_scales=scales[0], v_scales=scales[1],
+            q[:, 0], cache["k"], cache["v"], block_tables, pos,
+            k_scales=cache.get("k_scale"), v_scales=cache.get("v_scale"),
+            layer=layer,
             interpret=paged_impl == "kernel_interpret",
         )
         return out[:, None]
     if block_tables is not None:
-        ck = gather_pages(ck, block_tables)
-        cv = gather_pages(cv, block_tables)
+        ck = gather_pages(cache["k"], layer, block_tables)
+        cv = gather_pages(cache["v"], layer, block_tables)
         if kv_quant == "int8":
             from pytorch_distributed_tpu.ops.quant import dequantize_kv
 
             ck = dequantize_kv(
-                ck, gather_pages(kv["k_scale"], block_tables), q.dtype
+                ck, gather_pages(cache["k_scale"], layer, block_tables),
+                q.dtype,
             )
             cv = dequantize_kv(
-                cv, gather_pages(kv["v_scale"], block_tables), q.dtype
+                cv, gather_pages(cache["v_scale"], layer, block_tables),
+                q.dtype,
             )
+    else:
+        ck, cv = cache["k"][layer], cache["v"][layer]
     b, t, h, d = q.shape
     s, hkv = ck.shape[1], ck.shape[2]
     if hkv != h:
@@ -202,15 +212,17 @@ def _cached_attention(q, kv, pos, block_tables=None,
     return jnp.einsum("bhts,bshd->bthd", w, cv)
 
 
-def _write(cache_layer, new, pos, block_tables=None):
-    """Insert new [B, T, Hkv, D] at time offset pos. A [B] vector pos
-    writes each row at ITS OWN offset (slot-batched decode) via a vmapped
-    per-row update — pure data movement either way, so a row written at
-    pos[b] holds bit-identical values to the scalar-pos write at the same
-    offset.
+def _write(leaf, layer, new, pos, block_tables=None):
+    """Insert new [B, T, Hkv, D] into layer ``layer`` of the STACKED cache
+    leaf [L, B, S, Hkv, D] at time offset pos, and return the whole leaf:
+    the layer is one more index of the update, so a leaf that is a loop
+    carry (the layer scan's) is written in place. A [B] vector pos writes
+    each row at ITS OWN offset (slot-batched decode) via one scatter —
+    pure data movement either way, so a row written at pos[b] holds
+    bit-identical values to the scalar-pos write at the same offset.
 
-    With ``block_tables`` [B, n_pages] the cache layer is a PAGED pool
-    [P, page, Hkv, D]: token i of row b lands at page
+    With ``block_tables`` [B, n_pages] the leaf is a PAGED pool
+    [L, P, page, Hkv, D]: token i of row b lands at page
     ``table[b, (pos[b]+i) // page]``, offset ``(pos[b]+i) % page`` — one
     scatter, pure data movement again. The host guarantees distinct live
     rows write distinct pages (the copy-on-write discipline of
@@ -218,49 +230,44 @@ def _write(cache_layer, new, pos, block_tables=None):
     free rows' tables are all-zero, colliding harmlessly on the
     never-read scratch page.
 
-    Multi-token windows past a row's extent are SAFE, not clamped: the
+    Per-row windows past a row's extent are SAFE, not clamped: the
     speculative verify step (serving engines, ``speculative_k``) writes
     T = k+1 tokens per row, and a deep row's draft lanes can index past
     its table (paged) or past ``max_len`` (dense). XLA's default gather/
     dynamic_update_slice clamping would silently redirect those writes
     onto LIVE positions, so they are handled explicitly: paged lanes
     past the table redirect to the never-read scratch page (page 0),
-    and dense per-row multi-token writes use a scatter with
-    ``mode="drop"`` so out-of-range lanes write nothing. The host only
+    and dense per-row writes are a scatter with ``mode="drop"`` so
+    out-of-range lanes write nothing (dynamic_update_slice's clamp-shift
+    would slide the whole window onto committed rows). The host only
     ever commits tokens whose positions were in range, so dropped lanes
     are always rejected-draft garbage."""
-    new = new.astype(cache_layer.dtype)
-    if block_tables is not None:
-        page = cache_layer.shape[1]
-        b, t = new.shape[:2]
-        n_pages = block_tables.shape[1]
-        gpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]  # [B,T]
-        pidx = gpos // page
-        pids = jnp.take_along_axis(
-            block_tables, jnp.minimum(pidx, n_pages - 1), axis=1
+    new = new.astype(leaf.dtype)
+    if not getattr(pos, "ndim", 0):
+        return jax.lax.dynamic_update_slice(
+            leaf, new[None], (layer, 0, pos, 0, 0)
         )
-        pids = jnp.where(pidx < n_pages, pids, 0)  # OOB -> scratch page
-        return cache_layer.at[pids, gpos % page].set(new)
-    if getattr(pos, "ndim", 0):
-        if new.shape[1] > 1:
-            # Per-row MULTI-token write (the dense speculative verify
-            # window): scatter with mode="drop" — a lane past max_len is
-            # dropped instead of dynamic_update_slice's clamp-shift,
-            # which would slide the whole window onto committed rows.
-            b, t = new.shape[:2]
-            gpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
-            rows = jax.lax.broadcasted_iota(jnp.int32, (b, t), 0)
-            return cache_layer.at[rows, gpos].set(new, mode="drop")
-        return jax.vmap(
-            lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (p, 0, 0))
-        )(cache_layer, new, pos)
-    return jax.lax.dynamic_update_slice(cache_layer, new, (0, pos, 0, 0))
+    b, t = new.shape[:2]
+    gpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]  # [B, T]
+    if block_tables is None:
+        rows = jax.lax.broadcasted_iota(jnp.int32, (b, t), 0)
+        return leaf.at[layer, rows, gpos].set(new, mode="drop")
+    page = leaf.shape[2]
+    n_pages = block_tables.shape[1]
+    pidx = gpos // page
+    pids = jnp.take_along_axis(
+        block_tables, jnp.minimum(pidx, n_pages - 1), axis=1
+    )
+    pids = jnp.where(pidx < n_pages, pids, 0)  # OOB -> scratch page
+    return leaf.at[layer, pids, gpos % page].set(new)
 
 
-def _write_kv(kv, k_new, v_new, pos, block_tables=None, kv_quant="none"):
-    """Insert this step's [B, T, Hkv, D] K/V into the per-layer cache
-    dict. ``kv_quant="int8"`` (paged only) QUANTIZES ON APPEND: the new
-    tokens' values are rounded to int8 with per-token/per-head scales
+def _write_kv(cache, layer, k_new, v_new, pos, block_tables=None,
+              kv_quant="none"):
+    """Insert this step's [B, T, Hkv, D] K/V into layer ``layer`` of the
+    stacked cache dict. ``kv_quant="int8"`` (paged only) QUANTIZES ON
+    APPEND: the new tokens' values are rounded to int8 with
+    per-token/per-head scales
     (ops/quant.quantize_kv — one f32->int8 convert each for K and V, the
     audit-counted quantize sites) and the value + scale pools are
     scattered through the same page indirection; already-written
@@ -271,15 +278,12 @@ def _write_kv(kv, k_new, v_new, pos, block_tables=None, kv_quant="none"):
 
         kq, ks = quantize_kv(k_new)
         vq, vs = quantize_kv(v_new)
-        return {
-            "k": _write(kv["k"], kq, pos, block_tables),
-            "v": _write(kv["v"], vq, pos, block_tables),
-            "k_scale": _write(kv["k_scale"], ks, pos, block_tables),
-            "v_scale": _write(kv["v_scale"], vs, pos, block_tables),
-        }
+        new = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    else:
+        new = {"k": k_new, "v": v_new}
     return {
-        "k": _write(kv["k"], k_new, pos, block_tables),
-        "v": _write(kv["v"], v_new, pos, block_tables),
+        name: _write(cache[name], layer, val, pos, block_tables)
+        for name, val in new.items()
     }
 
 
@@ -353,7 +357,7 @@ def _moe_mlp(m, mlp_params, cfg, act, tensor_axis=None):
     return out
 
 
-def _gpt2_block(x, bp, kv, pos, cfg, tensor_axis=None,
+def _gpt2_block(x, bp, cache, layer, pos, cfg, tensor_axis=None,
                 block_tables=None, paged_impl="gather", kv_quant="none",
                 lora=None, lora_rows=None):
     eps = cfg.layer_norm_epsilon
@@ -366,9 +370,9 @@ def _gpt2_block(x, bp, kv, pos, cfg, tensor_axis=None,
         # so cached pages keep their pure-function-of-tokens soundness
         # (serving/adapters.py).
         q = q + lora_delta(a, lora["q"], lora_rows).astype(q.dtype)
-    kv = _write_kv(kv, k, v, pos, block_tables, kv_quant)
+    cache = _write_kv(cache, layer, k, v, pos, block_tables, kv_quant)
     a = _cached_attention(
-        q, kv, pos, block_tables, paged_impl, kv_quant
+        q, cache, layer, pos, block_tables, paged_impl, kv_quant
     ).reshape(b, t, -1)
     proj_extra = (
         lora_delta(a, lora["c_proj"], lora_rows)
@@ -382,12 +386,13 @@ def _gpt2_block(x, bp, kv, pos, cfg, tensor_axis=None,
     act = activation(cfg.activation_function)
     if cfg.n_experts:
         m = _moe_mlp(m, bp["mlp"], cfg, act, tensor_axis)
-        return x + m, kv
+        return x + m, cache
     m = act(dense(m, bp["mlp"]["c_fc"]))
-    return x + dense(m, bp["mlp"]["c_proj"], tp_reduce_axis=tensor_axis), kv
+    x = x + dense(m, bp["mlp"]["c_proj"], tp_reduce_axis=tensor_axis)
+    return x, cache
 
 
-def _llama_block(x, bp, kv, pos, cfg, cos, sin, tensor_axis=None,
+def _llama_block(x, bp, cache, layer, pos, cfg, cos, sin, tensor_axis=None,
                  block_tables=None, paged_impl="gather", kv_quant="none",
                  lora=None, lora_rows=None):
     from pytorch_distributed_tpu.ops.quant import qdot
@@ -410,9 +415,9 @@ def _llama_block(x, bp, kv, pos, cfg, cos, sin, tensor_axis=None,
     q = apply_rope(q_pre.reshape(b, t, -1, d), cos, sin)
     k = apply_rope(qdot(a, bp["attn"]["wk"]).reshape(b, t, -1, d), cos, sin)
     v = qdot(a, bp["attn"]["wv"]).reshape(b, t, -1, d)
-    kv = _write_kv(kv, k, v, pos, block_tables, kv_quant)
+    cache = _write_kv(cache, layer, k, v, pos, block_tables, kv_quant)
     a = _cached_attention(
-        q, kv, pos, block_tables, paged_impl, kv_quant
+        q, cache, layer, pos, block_tables, paged_impl, kv_quant
     ).reshape(b, t, -1)
     wo_out = qdot(a, bp["attn"]["wo"])
     if lora is not None:
@@ -422,11 +427,12 @@ def _llama_block(x, bp, kv, pos, cfg, cos, sin, tensor_axis=None,
     x = x + tp_reduce(wo_out, tensor_axis)
     m = rms_norm(x, bp["ln_mlp"], eps=eps)
     if cfg.n_experts:
-        return x + _moe_mlp(m, bp["mlp"], cfg, jax.nn.silu, tensor_axis), kv
+        m = _moe_mlp(m, bp["mlp"], cfg, jax.nn.silu, tensor_axis)
+        return x + m, cache
     gate = jax.nn.silu(qdot(m, bp["mlp"]["gate"]))
     up = qdot(m, bp["mlp"]["up"])
     down = qdot(gate * up, bp["mlp"]["down"])
-    return x + tp_reduce(down, tensor_axis), kv
+    return x + tp_reduce(down, tensor_axis), cache
 
 
 def forward(
@@ -545,39 +551,39 @@ def forward(
     else:
         raise KeyError(f"unknown model family {cfg.family!r}")
 
-    def block_body(x, bp, extra):
-        # ``extra["kv"]`` is one layer's cache-leaf dict (k/v, plus the
-        # scale pools when quantized) — scan_layers slices/stacks the
-        # whole dict, so the leaf set is the cache layout's business,
-        # not the scan's. ``extra["lora"]`` (when adapters ride the
-        # dispatch) is that layer's [slots, ...] adapter slice; the
-        # [B] rows vector is layer-invariant and closes over the scan.
-        kv_l = extra["kv"]
+    def block_body(carry, bp, extra):
+        # The carry is (activations, the whole stacked cache dict — k/v,
+        # plus the scale pools when quantized: the leaf set is the cache
+        # layout's business, not the scan's). ``extra["layer"]`` says
+        # which layer of it this block writes and reads.
+        # ``extra["lora"]`` (when adapters ride the dispatch) is that
+        # layer's [slots, ...] adapter slice; the [B] rows vector is
+        # layer-invariant and closes over the scan.
+        x, kv = carry
         if lora_tree is not None:
             return block(
-                x, bp, kv_l, pos,
+                x, bp, kv, extra["layer"], pos,
                 lora=extra["lora"], lora_rows=lora_rows,
             )
-        return block(x, bp, kv_l, pos)
+        return block(x, bp, kv, extra["layer"], pos)
 
-    extras = {"kv": cache}
+    extras = {"layer": jnp.arange(cache["k"].shape[0], dtype=jnp.int32)}
     if lora_tree is not None:
         extras["lora"] = lora_tree
-    x, kv = scan_layers(
+    x, cache = scan_layers(
         block_body,
-        x,
+        (x, cache),
         params["blocks"],
         extras=extras,
         remat_mode="none",
         block_transform=block_transform,
         prefetch_buffers=prefetch_buffers,
-        collect_ys=True,
     )
 
     from pytorch_distributed_tpu.models import get_model
 
     logits = get_model(cfg).head(params, x, cfg)
-    return logits, kv
+    return logits, cache
 
 
 # -- sampling --------------------------------------------------------------

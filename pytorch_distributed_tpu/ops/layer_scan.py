@@ -44,7 +44,6 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-import jax.numpy as jnp
 
 from pytorch_distributed_tpu.ops.remat import apply_remat
 
@@ -76,7 +75,6 @@ def scan_layers(
     block_transform: Callable | None = None,
     prefetch_buffers: int = 0,
     unroll: int = 1,
-    collect_ys: bool = False,
 ):
     """Run ``block_fn`` over every layer of a stacked [L, ...] param tree.
 
@@ -88,15 +86,10 @@ def scan_layers(
     transforms of a whole window are hoisted above its compute (see
     module docstring). Returns the final carry.
 
-    ``collect_ys``: when True, ``block_fn`` returns ``(carry, y)`` and the
-    per-layer ys are stacked back to [L, ...] and returned alongside the
-    carry — the decode path's per-layer KV-cache updates ride this the
-    same way training's scan outputs would, so the windowed prefetch
-    schedule applies to inference too (serving/engine.py's ZeRO-3 decode).
-    In window mode the per-window ys are stacked [W, ...] inside the body
-    and reshaped [n_windows, W, ...] -> [L, ...] afterwards — the same
-    layer order as the W=1 scan, so ys stay bit-identical across window
-    sizes.
+    The scan has no per-layer outputs: state a block updates layer by
+    layer (decode's stacked KV cache) belongs in ``carry``, addressed by
+    a layer index riding ``extras``, so a donated buffer is written where
+    it lies instead of being sliced out as xs and stacked back as ys.
     """
     n_layer = jax.tree.leaves(blocks)[0].shape[0]
     window = effective_window(prefetch_buffers, n_layer)
@@ -109,17 +102,15 @@ def scan_layers(
         # model code): transform + compute inside one rematted body.
         def body(c, xs):
             bp, extra = xs
-            if collect_ys:
-                return block_fn(c, transform(bp), extra)
             return block_fn(c, transform(bp), extra), None
 
-        (carry, ys) = jax.lax.scan(
+        carry, _ = jax.lax.scan(
             apply_remat(body, remat_mode),
             carry,
             (blocks, extras),
             unroll=unroll,
         )
-        return (carry, ys) if collect_ys else carry
+        return carry
 
     n_windows = n_layer // window
     blocks_w = jax.tree.map(
@@ -138,29 +129,16 @@ def scan_layers(
             transform(jax.tree.map(lambda a, j=j: a[j], bw))
             for j in range(window)
         ]
-        ys_w = []
         for j in range(window):
-            out = block_fn(
+            c = block_fn(
                 c, gathered[j], jax.tree.map(lambda a, j=j: a[j], ew)
             )
-            if collect_ys:
-                c, y = out
-                ys_w.append(y)
-            else:
-                c = out
-        if collect_ys:
-            return c, jax.tree.map(lambda *zs: jnp.stack(zs), *ys_w)
         return c, None
 
-    (carry, ys) = jax.lax.scan(
+    carry, _ = jax.lax.scan(
         apply_remat(window_body, remat_mode),
         carry,
         (blocks_w, extras_w),
         unroll=unroll,
     )
-    if collect_ys:
-        ys = jax.tree.map(
-            lambda a: a.reshape((n_layer,) + a.shape[2:]), ys
-        )
-        return carry, ys
     return carry

@@ -13,19 +13,20 @@ row's DEPTH instead of ``max_len``:
 - grid ``(B, n_pages)`` with the page dimension innermost and
   sequential (online-softmax accumulator state lives in VMEM scratch
   across it);
-- the block tables and per-row lengths ride ``PrefetchScalarGridSpec``
-  scalar prefetch, so the K/V BlockSpec *index maps* resolve
-  ``tables[b, i]`` before the body runs — the page "gather" is just the
-  kernel's own DMA picking its source block, never a materialised
-  [B, max_len] copy;
+- the layer index, the block tables and per-row lengths ride
+  ``PrefetchScalarGridSpec`` scalar prefetch, so the K/V BlockSpec *index
+  maps* resolve ``(layer, tables[b, i])`` before the body runs — the page
+  "gather" is just the kernel's own DMA picking its source block out of
+  the STACKED ``[L, P, page, Hkv, D]`` pool, never a materialised
+  [B, max_len] copy nor a per-layer slice of the pool;
 - pages past a row's depth are skipped with ``pl.when`` (no MXU work,
   and their DMA re-reads the row's last useful page id — the host fills
   unallocated table entries with the scratch page 0, so the skipped
   fetch is bounded and harmless);
 - one grid step holds ALL heads of one page. Mosaic tiles the last two
   block dims, so a block must cover them whole (or in (8, 128)
-  multiples): ``(1, page, Hkv, D)`` over the pool, ``(1, H, D)`` over the
-  queries, ``(1, page, Hkv)`` over the int8 scale pool. The body walks
+  multiples): ``(1, page, Hkv, D)`` of one layer of the pool, ``(1, H, D)``
+  over the queries, ``(1, page, Hkv)`` of the int8 scale pool. The body walks
   the KV heads in a static loop, reading head ``g`` of the page as
   ``k_ref[0, :, g, :]`` and computing the whole ``group = H // Hkv``
   query-head block against that [page, D] key block, so grouped-query
@@ -61,6 +62,7 @@ NEG_INF = -1e30  # finite mask (matches ops/attention.py): -inf NaNs softmax
 
 
 def _paged_kernel(
+    layer_ref,  # [1] int32 (scalar prefetch): read by the index maps only
     tables_ref,  # [B, n_pages] int32 (scalar prefetch)
     lens_ref,  # [B] int32 (scalar prefetch): row's query position
     q_ref,  # [1, H, D]
@@ -142,31 +144,35 @@ def _paged_kernel(
 # K/V pages belong to the serving engine's donated cache (aliased at the
 # PROGRAM boundary, not here) and q is read by the caller's residual.
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_call(q, k_pages, v_pages, scales, block_tables, lengths,
+def _paged_call(q, k_pages, v_pages, scales, layer, block_tables, lengths,
                 interpret):
-    """``scales`` is ``()`` for full-precision pages or the
-    ``(k_scales, v_scales)`` pools for int8 pages."""
+    """``k_pages``/``v_pages`` are STACKED [L, P, page, Hkv, D] pools and
+    ``layer`` [1] picks the layer; ``scales`` is ``()`` for full-precision
+    pages or the ``(k_scales, v_scales)`` [L, P, page, Hkv] pools for
+    int8 pages."""
     b, h, d = q.shape
     n_pages = block_tables.shape[1]
-    page, hkv = k_pages.shape[1], k_pages.shape[2]
+    page, hkv = k_pages.shape[2], k_pages.shape[3]
     kernel = functools.partial(
         _paged_kernel,
         page=page, n_pages=n_pages, scale=1.0 / (d**0.5),
         quantized=bool(scales),
     )
     row_spec = pl.BlockSpec(
-        (1, h, d), lambda bi, i, tables, lens: (bi, 0, 0)
+        (1, h, d), lambda bi, i, layer, tables, lens: (bi, 0, 0)
     )
     page_spec = pl.BlockSpec(
-        (1, page, hkv, d),
-        lambda bi, i, tables, lens: (tables[bi, i], 0, 0, 0),
+        (None, 1, page, hkv, d),
+        lambda bi, i, layer, tables, lens: (
+            layer[0], tables[bi, i], 0, 0, 0
+        ),
     )
     scale_spec = pl.BlockSpec(
-        (1, page, hkv),
-        lambda bi, i, tables, lens: (tables[bi, i], 0, 0),
+        (None, 1, page, hkv),
+        lambda bi, i, layer, tables, lens: (layer[0], tables[bi, i], 0, 0),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_pages),
         in_specs=[row_spec, page_spec, page_spec]
         + [scale_spec] * len(scales),
@@ -190,7 +196,7 @@ def _paged_call(q, k_pages, v_pages, scales, block_tables, lengths,
             dimension_semantics=("parallel", "arbitrary")
         ),
         name="paged_decode_attention",
-    )(block_tables, lengths, q, k_pages, v_pages, *scales)
+    )(layer, block_tables, lengths, q, k_pages, v_pages, *scales)
 
 
 def paged_decode_attention(
@@ -202,6 +208,7 @@ def paged_decode_attention(
     *,
     k_scales: jax.Array | None = None,  # [P, page, Hkv] f32 (int8 pages)
     v_scales: jax.Array | None = None,
+    layer: jax.Array | int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Paged single-query attention, [B, H, D] -> [B, H, D]. ``lengths``
@@ -209,6 +216,11 @@ def paged_decode_attention(
     (the dense decode-step mask at T=1). ``interpret=None`` means the
     compiled kernel and is an error off the chip — interpreter mode is
     never chosen for the caller.
+
+    ``layer`` (a traced scalar inside the layer scan): the pools — and
+    scale pools — are then the STACKED [L, P, ...] leaves of the serving
+    cache and the kernel reads layer ``layer`` of them in place. Without
+    it the pools are one layer's, as in the signature.
 
     ``k_scales``/``v_scales`` switch to the int8 kernel: pages are int8
     with per-token/per-head f32 scales and dequantization happens in
@@ -222,7 +234,7 @@ def paged_decode_attention(
                 "pass interpret=True to run the Pallas interpreter"
             )
         interpret = False
-    h, hkv = q.shape[1], k_pages.shape[2]
+    h, hkv = q.shape[1], k_pages.shape[-2]
     if h % hkv:
         raise ValueError(
             f"query heads {h} must be a multiple of kv heads {hkv}"
@@ -232,9 +244,14 @@ def paged_decode_attention(
             "k_scales and v_scales must be given together (int8 pages) "
             "or both omitted (full-precision pages)"
         )
+    scales = () if k_scales is None else (k_scales, v_scales)
+    if layer is None:  # one layer's pools: a stack of one (a free reshape)
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        scales = tuple(sc[None] for sc in scales)
+        layer = 0
     return _paged_call(
-        q, k_pages, v_pages,
-        () if k_scales is None else (k_scales, v_scales),
+        q, k_pages, v_pages, scales,
+        jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(lengths, jnp.int32),
         bool(interpret),
@@ -253,13 +270,16 @@ def paged_decode_attention_reference(
 
     b, h, d = q.shape
     tables = jnp.asarray(block_tables, jnp.int32)
-    ck = gather_pages(k_pages, tables)
-    cv = gather_pages(v_pages, tables)
+
+    def view(pool):  # one layer's pool is a stack of one
+        return gather_pages(pool[None], 0, tables)
+
+    ck, cv = view(k_pages), view(v_pages)
     if k_scales is not None:
         from pytorch_distributed_tpu.ops.quant import dequantize_kv
 
-        ck = dequantize_kv(ck, gather_pages(k_scales, tables), q.dtype)
-        cv = dequantize_kv(cv, gather_pages(v_scales, tables), q.dtype)
+        ck = dequantize_kv(ck, view(k_scales), q.dtype)
+        cv = dequantize_kv(cv, view(v_scales), q.dtype)
     s = ck.shape[1]
     hkv = ck.shape[2]
     if hkv != h:

@@ -349,3 +349,99 @@ def test_generate_tp_rejects_bad_meshes(eight_devices):
         decode.generate_tp(
             moe_params, prompt, moe_cfg, MeshConfig(tensor=2), 2
         )
+
+
+# -- how the cache travels through the layer scan ---------------------------
+#
+# The stacked cache is part of the scan's CARRY and the layer index the only
+# per-layer input, so a donated buffer is written where it lies. A cache leaf
+# among the scan's xs/ys is sliced out and stacked back whole, every layer of
+# every dispatch (ISSUE 28: 17% of the serving cell's device time).
+
+_B, _S, _PAGE = 3, 24, 4
+
+
+def _cache_case(layout):
+    """(cfg, forward kwargs, cache, ids, pos) for one cache layout."""
+    cfg = _cfg("gpt2")
+    if layout.startswith("dense"):
+        cache = decode.init_cache(cfg, _B, _S)
+        kw = {}
+    else:
+        kv_quant = "int8" if layout == "paged_int8" else "none"
+        n_pages = _S // _PAGE
+        cache = decode.init_paged_cache(
+            cfg, 1 + _B * n_pages, _PAGE, kv_quant=kv_quant
+        )
+        tables = 1 + jnp.arange(_B * n_pages, dtype=jnp.int32).reshape(
+            _B, n_pages
+        )
+        kw = {"block_tables": tables, "kv_quant": kv_quant}
+    pos = jnp.int32(5) if layout == "dense_scalar" else jnp.array(
+        [5, 0, 9], jnp.int32
+    )
+    return cfg, kw, cache, jnp.zeros((_B, 1), jnp.int32), pos
+
+
+@pytest.mark.parametrize(
+    "layout", ["dense_scalar", "dense_per_row", "paged", "paged_int8"]
+)
+def test_cache_rides_the_layer_scans_carry(layout):
+    cfg, kw, cache, ids, pos = _cache_case(layout)
+    params = get_model(cfg).init(jax.random.key(0), cfg)
+    leaves = jax.tree.leaves(cache)
+    jaxpr = jax.make_jaxpr(
+        lambda c, p: decode.forward(params, ids, cfg, c, p, **kw)
+    )(cache, pos).jaxpr
+    cache_vars = jaxpr.invars[: len(leaves)]
+    [scan] = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    carries = scan.invars[n_consts : n_consts + n_carry]
+    assert all(any(v is c for c in carries) for v in cache_vars)
+    per_layer = {(leaf.shape[1:], leaf.dtype) for leaf in leaves}
+    xs_ys = scan.invars[n_consts + n_carry :] + scan.outvars[n_carry:]
+    assert not [
+        v.aval for v in xs_ys
+        if (v.aval.shape[1:], v.aval.dtype) in per_layer
+    ]
+
+
+def test_compiled_paged_step_updates_the_pool_in_place():
+    """An f32 pool: XLA:CPU upcasts a bf16 pool around a scatter with a
+    whole-pool ``convert`` that the chip does not make."""
+    from pytorch_distributed_tpu.analysis.memory import (
+        parse_module,
+        shape_dims,
+    )
+
+    cfg, kw, cache, ids, pos = _cache_case("paged")
+    params = get_model(cfg).init(jax.random.key(0), cfg)
+    step = jax.jit(
+        lambda c, p: decode.forward(params, ids, cfg, c, p, **kw),
+        donate_argnums=0,
+    )
+    module = parse_module(step.lower(cache, pos).compile().as_text())
+    [loop] = [
+        i for i in module.entry.instructions if i.opcode == "while"
+    ]
+    todo, body, dims = list(loop.called), [], {}
+    while todo:  # the while body and every fusion it calls
+        comp = module.computations[todo.pop()]
+        body += comp.instructions
+        dims.update((i.name, shape_dims(i.shape)) for i in comp.instructions)
+        todo += [c for i in comp.instructions for c in i.called]
+    pool = cache["k"].shape
+    gathers = [
+        i for i in body
+        if i.opcode == "gather" and dims[i.operands[0]] == pool
+    ]
+    assert len(gathers) == 2  # one for k, one for v
+    assert all("start_index_map={0,1}" in g.attrs for g in gathers)
+    scatters = [i for i in body if i.opcode == "scatter"]
+    assert [shape_dims(i.shape) for i in scatters] == [pool, pool]
+    per_layer = (pool[1:], (1,) + pool[1:])
+    assert not [
+        i for i in body
+        if i.opcode in ("dynamic-slice", "dynamic-update-slice", "copy")
+        and shape_dims(i.shape) in per_layer
+    ]
