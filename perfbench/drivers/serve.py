@@ -20,7 +20,7 @@ from pathlib import Path
 
 import numpy as np
 
-from perfbench import compare, flops, reference, stats, trace, traffic
+from perfbench import compare, flops, preset, reference, stats, trace, traffic
 
 DRAIN_S = 90.0  # how long an answer may come after the window has closed
 CLOSE_GRACE_S = 1.0  # a closed loop keeps sending this long past the nominal
@@ -144,14 +144,12 @@ def build(ctx, params):
     """(router, server) as scripts/serve.py builds them; one replica."""
     import jax
 
-    from pytorch_distributed_tpu.config import model_config
     from pytorch_distributed_tpu.serving.engine import PagedBatchedDecodeEngine
     from pytorch_distributed_tpu.serving.router import ReplicaRouter
     from pytorch_distributed_tpu.serving.server import ServingServer
 
-    prog, eng = ctx.config["program"], ctx.traffic["engine"]
-    cfg = model_config(prog["preset"], **prog["serve_overrides"]).replace(
-        n_ctx=max(eng["max_len"], 64))
+    eng = ctx.traffic["engine"]
+    cfg = preset.of(ctx.config, "serve").replace(n_ctx=max(eng["max_len"], 64))
 
     def make_engine(rep_id: int):
         # the mix's ``engine`` group is the engine's own keyword arguments
@@ -190,6 +188,9 @@ async def drive(ctx, server, router, reqs, warm_reqs):
                 host, port, reqs, t_close, mix["clients"], records)
         await asyncio.sleep(max(0.0, t_start - time.perf_counter()))
         out["setup_s"] = t_start - ctx.t0
+        # /healthz whole, at both ends of the nominal window: a reader takes
+        # any counter or timer the program serves, and its difference
+        out["health"] = {"open": await healthz(host, port)}
         cap = None
         if ctx.trace:
             lead = min(ctx.seconds, mix["trace_seconds"])
@@ -198,7 +199,7 @@ async def drive(ctx, server, router, reqs, warm_reqs):
             win_span = trace.span("window")
             win_span.__enter__()
         await asyncio.sleep(max(0.0, t_close - time.perf_counter()))
-        out["health"] = await healthz(host, port)
+        out["health"]["close"] = await healthz(host, port)
         # the token event that closes the window comes within a burst or two
         # of the nominal close; stopping the profiler blocks this loop for
         # seconds, so it waits until that event has been stamped
@@ -331,6 +332,7 @@ def run(ctx) -> dict:
 
     mix, model = ctx.traffic, ctx.config["model"]
     eng = mix["engine"]
+    count = flops.of(ctx.config)
     pdt = ctx.config["program"]["serve_overrides"]["param_dtype"]
     params = reference.of(ctx.config).init_params(ctx.seed, model, pdt)
     cfg, router, server, n_programs = build(ctx, params)
@@ -373,9 +375,9 @@ def run(ctx) -> dict:
     for r in records:
         p = len(r.req["body"]["prompt"])
         if r.times and inside(r.times[0]):
-            work += flops.serve_flops_span(model, 0, p)
+            work += count.serve_flops_span(model, 0, p)
         n_dec = sum(1 for t in r.times[1:] if inside(t))
-        work += flops.serve_flops_span(model, p, p + n_dec)
+        work += count.serve_flops_span(model, p, p + n_dec)
 
     def in_flight(t):
         return sum(1 for r in records if r.sent is not None and r.sent <= t
@@ -396,7 +398,7 @@ def run(ctx) -> dict:
         "value": got["compiles_after"] - got["compiles_before"], "limit": 0}
     numbers["unanswered_or_wrong_length"] = {"value": len(failed), "limit": 0}
 
-    health = got["health"]["replicas"]
+    health = got["health"]["close"]["replicas"]
     tick = [r["tick_ema_s"] for r in health.values()
             if r.get("tick_ema_s") is not None]
     at_close = next(iter(health.values()), {})
@@ -440,6 +442,7 @@ def run(ctx) -> dict:
             "active_rows_at_close": at_close.get("active_rows"),
             "first_errors": "; ".join(str(r.error) for r in failed[:3]),
         },
+        "health": got["health"],  # both bodies, unaltered
         "trace": form,
         "sample": sample,  # tools and tests read the control over the same
     }

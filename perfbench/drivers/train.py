@@ -18,7 +18,7 @@ from pathlib import Path
 
 import numpy as np
 
-from perfbench import compare, flops, reference, stats, trace, traffic
+from perfbench import compare, flops, preset, reference, stats, trace, traffic
 
 CHECK_STEPS = 3  # the reference follows the first three
 LAG = 2  # the host dispatches at most this many steps ahead of the device
@@ -38,23 +38,14 @@ def _adam_mu(opt_state):
 
 def build(ctx):
     """Program objects for this cell: (trainer, loader, model cfg)."""
-    from pytorch_distributed_tpu.config import TrainConfig, model_config
+    from pytorch_distributed_tpu.config import TrainConfig
     from pytorch_distributed_tpu.data import TokenShardLoader, bin_format
     from pytorch_distributed_tpu.models import get_model
     from pytorch_distributed_tpu.train import Trainer
 
-    prog, tr = ctx.config["program"], ctx.traffic
+    tr = ctx.traffic
     opt = tr["optimizer"]
-    cfg = model_config(prog["preset"], **prog["train_overrides"])
-    model_keys = ctx.config["model"]
-    for ours, theirs in (("n_embd", "n_embd"), ("n_layer", "n_layer"),
-                         ("n_head", "n_head"), ("n_positions", "n_ctx"),
-                         ("vocab_size", "vocab_size")):
-        if getattr(cfg, theirs) != model_keys[ours]:
-            raise SystemExit(
-                f"perfbench: preset {prog['preset']!r} has {theirs}="
-                f"{getattr(cfg, theirs)}, the configuration file says "
-                f"{model_keys[ours]}")
+    cfg = preset.of(ctx.config, "train")
     tcfg = TrainConfig(
         global_batch_size=tr["batch"], micro_batch_size=tr["batch"],
         num_steps=opt["schedule_steps"], learning_rate=opt["learning_rate"],
@@ -83,7 +74,7 @@ def run(ctx, keep_grad: bool = False) -> dict:
 
     tr, model = ctx.traffic, ctx.config["model"]
     opt = tr["optimizer"]
-    ref = reference.of(ctx.config)
+    ref, count = reference.of(ctx.config), flops.of(ctx.config)
     trainer, loader, cfg = build(ctx)
     ctx.mark("trainer_built")
     step_fn = trainer.train_step
@@ -221,7 +212,7 @@ def run(ctx, keep_grad: bool = False) -> dict:
             "tokens": steps * tokens_per_step,
             "window_s": window_s,
             "spans_s": dict(spans),
-            "train_flops_per_token": flops.train_flops_per_token(
+            "train_flops_per_token": count.train_flops_per_token(
                 model, tr["seq_len"]),
             "batch": tr["batch"], "seq_len": tr["seq_len"],
             "traced_steps": traced_steps,

@@ -1,7 +1,7 @@
 """The flash attention kernels' share of their roofline: the least time the
 chip could take for the causal attention the traced steps NEED (forward and
-backward of every layer once per step, from the cell's shapes,
-perfbench/flops.py) over the summed device time of the events named
+backward of every layer once per step, from the cell's shapes, by the
+family's count module) over the summed device time of the events named
 flash_mha_fwd* and flash_mha_bwd*. A remat that ran the forward kernel twice
 shows as a lower share, not as more work. No matching event: nothing to
 read (the harness leaves the metric out), never 0."""
@@ -20,7 +20,7 @@ def read(res):
     if spent_s <= 0 or n_bwd <= 0:
         return None
     f = res["facts"]
-    work = flops.flash_attention_work(
+    work = flops.of(res["config"]).flash_attention_work(
         res["model"], f["batch"] // res.get("chips", 1), f["seq_len"])
     least_fwd, _ = flops.roofline_seconds(work["fwd"], res["peak"])
     least_bwd, _ = flops.roofline_seconds(work["bwd"], res["peak"])

@@ -2,7 +2,8 @@
 mix>; training has step_mfu.train.py): forward FLOPs the
 tokens processed inside the window needed (prompt tokens of requests whose
 first token came inside it, and every generated token received inside it;
-2 x matmul parameters + 4 L E (position+1) each, perfbench/flops.py) over
+the family's ``serve_flops_span``; for GPT-2, 2 x matmul parameters + 4 L E
+(position+1) each, perfbench/counts/gpt2.py) over
 window x peak bf16, in percent."""
 
 

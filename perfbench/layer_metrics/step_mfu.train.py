@@ -1,4 +1,5 @@
-"""Whole-step model FLOP/s utilisation: (6N + 12 L E T) FLOPs per token x
+"""Whole-step model FLOP/s utilisation: the family's
+``train_flops_per_token`` (GPT-2: 6N + 12 L E T, perfbench/counts/gpt2.py) x
 all tokens of the window / (window x chips x peak bf16). Recomputation is
 not counted. Source: program_counter (steps the harness dispatched) over
 the host clock of the whole window."""

@@ -165,7 +165,8 @@ def execute(ctx: Context, bench: dict, device: dict | None, peak: dict | None):
 
     numbers = res["numbers"]
     correct = compare.verdict(numbers) and res["failed"] == 0
-    res.update(peak=peak, model=ctx.config["model"], chips=ctx.chips)
+    res.update(peak=peak, config=ctx.config, model=ctx.config["model"],
+               chips=ctx.chips)
     metrics: dict = {}
     if not ctx.trace:
         # set-up is everything from the interpreter's start to the window's,

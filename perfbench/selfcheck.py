@@ -21,7 +21,7 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 sys.path.insert(0, str(ROOT))
 
-from perfbench import compare, flops, stats, trace, traffic  # noqa: E402
+from perfbench import compare, flops, run, stats, trace, traffic  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -98,21 +98,26 @@ def check_traffic():
 
 
 def check_flops():
-    small = json.loads((HERE / "configs" / "gpt2-124m.json").read_text())["model"]
-    large = json.loads((HERE / "configs" / "gpt2-large.json").read_text())["model"]
+    files = [json.loads((HERE / "configs" / f"{name}.json").read_text())
+             for name in ("gpt2-124m", "gpt2-large")]
+    small, large = (f["model"] for f in files)
+    # each file's family names its count; both are GPT-2's
+    count = flops.of(files[0])
+    assert flops.of(files[1]) is count
+    assert count.__name__ == "perfbench.counts.gpt2"
     # By hand: 124M = 50257*768 + 1024*768 + 12*(12*768^2 + 13*768) + 2*768
-    assert flops.n_params(small) == 124_439_808
-    assert flops.n_params(large) == 774_030_080
-    assert close(flops.train_flops_per_token(small, 1024),
+    assert count.n_params(small) == 124_439_808
+    assert count.n_params(large) == 774_030_080
+    assert close(count.train_flops_per_token(small, 1024),
                  6 * 124_439_808 + 12 * 12 * 768 * 1024)
     # non-embedding: 12 * 12 * 768^2 + 50257 * 768
-    assert flops.n_params_non_embedding(small) == 12 * 12 * 768**2 + 50257 * 768
-    one = flops.serve_flops_span(large, 100, 101)
-    assert close(one, 2 * flops.n_params_non_embedding(large)
+    assert count.n_params_non_embedding(small) == 12 * 12 * 768**2 + 50257 * 768
+    one = count.serve_flops_span(large, 100, 101)
+    assert close(one, 2 * count.n_params_non_embedding(large)
                  + 4 * 36 * 1280 * 101)
-    assert close(flops.serve_flops_span(large, 0, 192),
-                 flops.serve_flops(large, range(192)))
-    w = flops.flash_attention_work(small, 8, 1024)
+    assert close(count.serve_flops_span(large, 0, 192),
+                 count.serve_flops(large, range(192)))
+    w = count.flash_attention_work(small, 8, 1024)
     full = 2 * 8 * 12 * 1024 * 1024 * 64
     assert close(w["fwd"]["flops"], full) and close(w["bwd"]["flops"], 2.5 * full)
     peak = json.loads((HERE / "peaks.json").read_text())["TPU v5 lite"]
@@ -147,6 +152,15 @@ def check_benchmark_json():
         names.append(c["name"])
         assert (ROOT / c["file"]).is_file(), c["file"]
         assert all(NAME.match(k) for k in c["reduced"])
+        held = json.loads((ROOT / c["file"]).read_text())
+        # its family's plain reference and count are there, and each path the
+        # program has overrides for holds pairs that name keys of ``model``
+        assert (HERE / "reference" / f"{held['reference']}.py").is_file(), c
+        flops.of(held)
+        for key in held["program"]:
+            if key.endswith("_overrides"):
+                pairs = held["program"][key.replace("_overrides", "_holds")]
+                assert pairs and set(pairs.values()) <= set(held["model"]), c
     for w in bench["workloads"]:
         names += [w["name"], w["config"], w["traffic"]]
         assert (HERE / "traffic" / f"{w['traffic']}.json").is_file(), w
@@ -214,6 +228,17 @@ def check_trace():
     assert k["flash_mha_bwd"][0] == expect["n_bwd"], k
     assert k["flash_mha_fwd"][0] == expect["n_fwd"], k
     assert close(k["flash_mha_bwd"][1], expect["bwd_ns"], 1e-9)
+    # the kernels' reader, which finds GPT-2's count through the
+    # configuration file: 12 layers' forward and backward, 3.5 x 2 B H T T D
+    config = json.loads((HERE / "configs" / "gpt2-124m.json").read_text())
+    peak = json.loads((HERE / "peaks.json").read_text())["TPU v5 lite"]
+    share = run.load_reader("flash_roofline.train")({
+        "trace": form, "peak": peak, "config": config,
+        "model": config["model"], "chips": 1,
+        "facts": {"batch": 8, "seq_len": 1024}})
+    spent_s = (k["flash_mha_fwd"][1] + k["flash_mha_bwd"][1]) / 1e9
+    assert close(share, 100 * 12 * 3.5 * (2 * 8 * 12 * 1024 * 1024 * 64)
+                 / 197e12 / spent_s), share
     top = trace.top_device_ops(form, 3)
     assert top[0][0] == expect["top_op"], top
     gaps = trace.idle_gaps(form)

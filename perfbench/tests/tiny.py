@@ -21,11 +21,16 @@ TINY_CONFIG = {
             "embd_pdrop": 0.0, "vocab_size": 512, "n_ctx": 128, "n_embd": 64,
             "n_layer": 2, "n_head": 2,
         },
+        "train_holds": {"n_embd": "n_embd", "n_layer": "n_layer",
+                        "n_head": "n_head", "vocab_size": "vocab_size",
+                        "n_ctx": "n_positions"},
         "serve_overrides": {
             "dtype": "bfloat16", "param_dtype": "bfloat16", "attn_pdrop": 0.0,
             "resid_pdrop": 0.0, "embd_pdrop": 0.0, "vocab_size": 512,
             "n_ctx": 128, "n_embd": 64, "n_layer": 2, "n_head": 2,
         },
+        "serve_holds": {"n_embd": "n_embd", "n_layer": "n_layer",
+                        "n_head": "n_head", "vocab_size": "vocab_size"},
     },
 }
 

@@ -21,7 +21,7 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from perfbench import compare, run  # noqa: E402
+from perfbench import compare, reference, run  # noqa: E402
 
 
 def main() -> int:
@@ -33,7 +33,8 @@ def main() -> int:
 
     base, *_ = run.open_cell(args.workload, 0, 0.5)
     from perfbench.drivers import train
-    from perfbench.reference import gpt2 as ref
+
+    ref = reference.of(base.config)
 
     out = Path(args.out)
     out.parent.mkdir(parents=True, exist_ok=True)
