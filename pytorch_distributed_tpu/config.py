@@ -23,9 +23,12 @@ class ModelConfig:
     activation_function, layer_norm_epsilon, *_pdrop).
     """
 
-    # Family: "gpt2" (learned positions, LayerNorm, gelu MLP, tied head) or
+    # Family: "gpt2" (learned positions, LayerNorm, gelu MLP, tied head),
     # "llama" (RoPE, RMSNorm, SwiGLU, untied head) — SURVEY.md §7 stage 8 /
-    # BASELINE.md configs 4-5.
+    # BASELINE.md configs 4-5 — or "kimi_k2" (the DeepSeek-V3 block as
+    # Kimi-K2.5 publishes it: latent attention, leading dense layers, then
+    # sigmoid-routed dropless experts beside a shared one, YaRN; served
+    # only — models/kimi_k2.py; its fields are at the end of this class).
     family: str = "gpt2"
 
     vocab_size: int = 50257
@@ -114,13 +117,73 @@ class ModelConfig:
     # form), "auto" picks by dispatch-tensor size (ops/moe.py).
     moe_dispatch: str = "auto"
 
+    # -- family "kimi_k2": the published config.json keys ------------------
+    # (hidden_size, num_hidden_layers, num_attention_heads,
+    # intermediate_size, rms_norm_eps, rope_theta and vocab_size are
+    # n_embd, n_layer, n_head, n_inner, layer_norm_epsilon, rope_theta and
+    # vocab_size above.) Latent attention: queries through a rank-
+    # q_lora_rank bottleneck to n_head heads of qk_nope_head_dim +
+    # qk_rope_head_dim; keys and values from ONE cached latent of
+    # kv_lora_rank numbers plus one shared rotated key of qk_rope_head_dim.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Layers 0..first_k_dense_replace-1 carry one SwiGLU of n_inner; each
+    # later layer n_routed_experts experts of moe_intermediate_size (the
+    # router scores all of them with a sigmoid and picks
+    # num_experts_per_tok by score + selection bias; gates are the picked
+    # scores renormalised and scaled by routed_scaling_factor) beside
+    # n_shared_experts shared ones every token passes through.
+    first_k_dense_replace: int = 0
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    n_shared_experts: int = 0
+    moe_intermediate_size: int = 0
+    routed_scaling_factor: float = 1.0
+    # rope_scaling (type "yarn"), flat because the config is hashed.
+    rope_factor: float = 1.0
+    rope_original_max_position: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # The share of an expert-parallel deployment this process holds:
+    # experts expert_offset .. expert_offset + experts_held - 1 of every
+    # expert layer (0 held = all of them). The router still scores all
+    # n_routed_experts; what the absent ones would add is left out.
+    experts_held: int = 0
+    expert_offset: int = 0
+
     def __post_init__(self) -> None:
         if self.n_embd % self.n_head != 0:
             raise ValueError(
                 f"n_embd={self.n_embd} not divisible by n_head={self.n_head}"
             )
-        if self.family not in ("gpt2", "llama"):
+        if self.family not in ("gpt2", "llama", "kimi_k2"):
             raise ValueError(f"unknown model family: {self.family!r}")
+        if self.family == "kimi_k2":
+            held = self.experts_held or self.n_routed_experts
+            if not (
+                0 < self.num_experts_per_tok <= self.n_routed_experts
+                and 0 <= self.expert_offset
+                and self.expert_offset + held <= self.n_routed_experts
+                and 0 <= self.first_k_dense_replace <= self.n_layer
+            ):
+                raise ValueError(
+                    "kimi_k2: need 0 < num_experts_per_tok <= "
+                    "n_routed_experts, the held experts "
+                    f"[{self.expert_offset}, {self.expert_offset + held}) "
+                    f"inside the {self.n_routed_experts} routed ones, and "
+                    "first_k_dense_replace <= n_layer"
+                )
+            if self.n_experts:
+                raise ValueError(
+                    "kimi_k2 routes through its own dropless layer "
+                    "(n_routed_experts); n_experts selects the capacity-"
+                    "routed one and must stay 0"
+                )
         # Ring attention is selected by the parallelism layer (seq_axis in
         # ops/attention.py), not by this per-model switch.
         if self.attention_impl not in ("naive", "flash"):
@@ -207,6 +270,27 @@ _LLAMA_PRESETS: dict[str, dict[str, Any]] = {
 }
 
 
+_KIMI_K2_PRESETS: dict[str, dict[str, Any]] = {
+    # One chip's share of Kimi-K2.5 in a 32-chip decode deployment
+    # (https://huggingface.co/moonshotai/Kimi-K2.5/blob/main/config.json):
+    # every width as published; 1 dense + 4 expert layers of the 61, 12 of
+    # the 384 routed experts, 20480 of the 163840 vocabulary rows
+    # (perfbench/configs/kimi-k2.5-ep32.json states the cut).
+    "kimi-k2.5-ep32": dict(
+        vocab_size=20480, n_ctx=4096, n_embd=7168, n_layer=5, n_head=64,
+        n_inner=18432, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        first_k_dense_replace=1, n_routed_experts=384,
+        num_experts_per_tok=8, n_shared_experts=1,
+        moe_intermediate_size=2048, routed_scaling_factor=2.827,
+        rope_theta=50000.0, rope_factor=64.0,
+        rope_original_max_position=4096, rope_beta_fast=32.0,
+        rope_beta_slow=1.0, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+        experts_held=12, expert_offset=0,
+    ),
+}
+
+
 def model_config(name: str, **overrides: Any) -> ModelConfig:
     """Look up a preset by name (the TPU-native analogue of
     ``AutoConfig.from_pretrained`` in reference train_baseline.py:24)."""
@@ -222,10 +306,20 @@ def model_config(name: str, **overrides: Any) -> ModelConfig:
             resid_pdrop=0.0,
             **_LLAMA_PRESETS[name],
         )
+    elif name in _KIMI_K2_PRESETS:
+        base = dict(
+            family="kimi_k2",
+            activation_function="silu",
+            layer_norm_epsilon=1e-5,
+            embd_pdrop=0.0,
+            attn_pdrop=0.0,
+            resid_pdrop=0.0,
+            **_KIMI_K2_PRESETS[name],
+        )
     else:
         raise KeyError(
             f"unknown model preset {name!r}; known: "
-            f"{sorted(_GPT2_PRESETS) + sorted(_LLAMA_PRESETS)}"
+            f"{sorted(_GPT2_PRESETS) + sorted(_LLAMA_PRESETS) + sorted(_KIMI_K2_PRESETS)}"
         )
     base.update(overrides)
     return ModelConfig(**base)

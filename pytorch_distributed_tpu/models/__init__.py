@@ -44,4 +44,13 @@ def get_model(cfg: ModelConfig) -> ModelApi:
             lambda params: (params["lm_head"], "ev"),
             llama.final_norm,
         )
+    if cfg.family == "kimi_k2":
+        from pytorch_distributed_tpu.models import kimi_k2
+
+        return ModelApi(
+            kimi_k2.init, kimi_k2.apply, kimi_k2.embed, kimi_k2.run_blocks,
+            kimi_k2.head,
+            lambda params: (params["lm_head"], "ev"),
+            kimi_k2.final_norm,
+        )
     raise KeyError(f"unknown model family {cfg.family!r}")

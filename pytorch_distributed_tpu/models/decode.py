@@ -70,6 +70,41 @@ Params = dict[str, Any]
 Cache = dict[str, jax.Array]
 
 
+def has_dense_cache(cfg: ModelConfig) -> bool:
+    """False for a family that caches PAGES only (kimi_k2's latent pool):
+    ``init_cache`` has no [B, max_len] layout for it."""
+    return cfg.family != "kimi_k2"
+
+
+def kv_bytes_per_position(cfg: ModelConfig, kv_quant: str = "none") -> int:
+    """Bytes one GLOBAL cache position costs across all layers, in the
+    family's own layout (TP divides the head dim across shards, so the
+    global figure is the comparable one either way). Per-head K and V;
+    int8 pages carry one f32 scale per token per KV head next to the
+    values (ops/quant.quantize_kv), so a quantized position costs
+    head_dim + 4 bytes per head instead of head_dim x itemsize. The
+    kimi_k2 family's page holds ONE latent all heads share
+    (kv_lora_rank + qk_rope_head_dim numbers, stored in whole lanes)."""
+    if kv_quant == "int8":
+        return cfg.n_layer * 2 * cfg.kv_heads * (cfg.head_dim + 4)
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    if cfg.family == "kimi_k2":
+        from pytorch_distributed_tpu.models.kimi_k2 import page_width
+
+        return cfg.n_layer * page_width(cfg) * itemsize
+    return cfg.n_layer * 2 * cfg.kv_heads * cfg.head_dim * itemsize
+
+
+def aux_counts(cfg: ModelConfig) -> tuple[str, ...]:
+    """Names, in order, of the int32 counts ``forward(return_aux=True)``
+    hands back beside the logits; () for a family that counts nothing."""
+    if cfg.family == "kimi_k2":
+        from pytorch_distributed_tpu.models.kimi_k2 import AUX_COUNTS
+
+        return AUX_COUNTS
+    return ()
+
+
 def init_cache(
     cfg: ModelConfig, batch: int, max_len: int, dtype=None,
     n_kv: int | None = None,
@@ -79,6 +114,13 @@ def init_cache(
     each shard caches only its LOCAL kv heads (1/tp of the HBM)."""
     if max_len > cfg.n_ctx:
         raise ValueError(f"max_len {max_len} exceeds n_ctx {cfg.n_ctx}")
+    if not has_dense_cache(cfg):
+        raise NotImplementedError(
+            "the kimi_k2 family caches a LATENT page pool "
+            "(init_paged_cache -> models/kimi_k2.init_latent_pool): serve "
+            "it through PagedBatchedDecodeEngine; there is no dense "
+            "[B, max_len] cache layout for it"
+        )
     dtype = jnp.dtype(dtype or cfg.dtype)
     shape = (
         cfg.n_layer, batch, max_len, n_kv or cfg.kv_heads, cfg.head_dim
@@ -104,6 +146,17 @@ def init_paged_cache(
         raise ValueError(
             f"kv_quant must be 'none' or 'int8', got {kv_quant!r}"
         )
+    if cfg.family == "kimi_k2":
+        # ONE leaf [L, pool_pages, page_size, C + Dr]: the latent, read
+        # expanded in prefill and absorbed in decode (models/kimi_k2.py)
+        if kv_quant != "none" or n_kv is not None:
+            raise NotImplementedError(
+                "kimi_k2's latent pages are neither quantized nor "
+                "head-sharded: a page holds one latent all heads share"
+            )
+        from pytorch_distributed_tpu.models.kimi_k2 import init_latent_pool
+
+        return init_latent_pool(cfg, pool_pages, page_size, dtype)
     dtype = jnp.dtype(dtype or cfg.dtype)
     shape = (
         cfg.n_layer, pool_pages, page_size, n_kv or cfg.kv_heads,
@@ -449,6 +502,9 @@ def forward(
     paged_impl: str = "gather",
     kv_quant: str = "none",
     lora: tuple | None = None,
+    live: jax.Array | None = None,
+    logits_index: jax.Array | None = None,
+    return_aux: bool = False,
 ) -> tuple[jax.Array, Cache]:
     """Run T tokens at positions pos..pos+T-1. Returns ([B, T, V] logits,
     updated cache). MoE configs route each token through the expert MLPs
@@ -491,6 +547,14 @@ def forward(
     with ``block_transform`` (the ZeRO-3 gather hook transforms the
     whole sliced tree — adapters are plain operands, not sharded
     params), rejected loudly.
+
+    ``logits_index`` [B]: the logits come back [B, 1, V], of position
+    ``logits_index[b]`` of each row (a prefill chunk samples from its last
+    real token only). ``live`` [B, T] bool marks the entries that are
+    tokens, for a family whose layers count their work (padding and free
+    rows then route nowhere and count nothing); the others ignore it.
+    ``return_aux``: return (logits, cache, aux) with aux the [n] int32
+    counts ``aux_counts(cfg)`` names, summed over the layers.
     """
     b, t = input_ids.shape
     dtype = jnp.dtype(cfg.dtype)
@@ -524,6 +588,21 @@ def forward(
         lora_tree, lora_rows = lora
         lora_rows = jnp.asarray(lora_rows, jnp.int32)
 
+    if cfg.family == "kimi_k2":
+        if (block_tables is None or tensor_axis is not None
+                or block_transform is not None or lora is not None):
+            raise NotImplementedError(
+                "the kimi_k2 family runs on the paged latent pool of one "
+                "device (block_tables given; no tensor axis, ZeRO-3 "
+                "transform or LoRA): models/kimi_k2.forward"
+            )
+        from pytorch_distributed_tpu.models import kimi_k2
+
+        logits, cache, aux = kimi_k2.forward(
+            params, input_ids, cfg, cache, pos, block_tables,
+            live=live, logits_index=logits_index,
+        )
+        return (logits, cache, aux) if return_aux else (logits, cache)
     if cfg.family == "gpt2":
         if per_row:
             rows = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
@@ -583,6 +662,12 @@ def forward(
     from pytorch_distributed_tpu.models import get_model
 
     logits = get_model(cfg).head(params, x, cfg)
+    if logits_index is not None:
+        logits = jnp.take_along_axis(
+            logits, logits_index[:, None, None], axis=1
+        )
+    if return_aux:
+        return logits, cache, jnp.zeros((0,), jnp.int32)
     return logits, cache
 
 

@@ -53,6 +53,25 @@ both trainer paths add ``moe_aux_coef * aux`` to the objective; under EP it
 is computed per token-shard and averaged (the standard distributed
 convention — differs from the global-batch product only at O(1e-4) on
 balanced batches).
+
+``moe_dropless`` (the kimi_k2 family's layer, serving): no capacity and no
+drop. The router scores ALL experts with a sigmoid, picks ``top_k`` by
+score + selection bias, and gates by the picked scores renormalised and
+scaled; the layer is told which experts it HOLDS (``expert_offset`` .. +
+the expert stacks' leading size), computes those experts' part for the
+pairs (token, expert) routed to them, and leaves the absent experts' part
+out — what an expert-parallel chip computes before the exchange. The pairs
+are sorted by expert (stably, as the sort path's are) and run through
+``_expert_compute`` in blocks of rows that each belong to ONE expert, as
+many blocks as the routing needs (a loop with a traced trip count: static
+shapes, work in proportion to the pairs, an expert nobody chose is never
+read); every pair's product lands in its own row of a [T * top_k, D] buffer
+and a token sums its own rows in rank order, so a row's result depends on
+no other row.
+The expert stacks may come WHOLE, with the layer's index beside them
+(``layer``): the block loop then slices (layer, expert) where the product
+reads it. A layer's slice handed to the loop instead is copied whole on its
+way in, hit or not (1 GB a layer at kimi-k2.5-ep32, 10 ms a dispatch).
 """
 
 from __future__ import annotations
@@ -291,3 +310,125 @@ def moe_mlp(
     else:
         raise ValueError(f"unknown dispatch_impl {dispatch_impl!r}")
     return out.astype(x.dtype).reshape(b, t, d), aux_loss
+
+
+def _route_sigmoid(xt, router, bias, top_k: int, routed_scale: float):
+    """(expert_idx [T, K], gates [T, K] f32). In float32, as published:
+    scores z = sigmoid(x @ router); the K largest of z + bias are chosen
+    (the bias moves the choice only); gates are the chosen z over their
+    sum (+1e-20), times ``routed_scale``."""
+    z = jax.nn.sigmoid(
+        xt.astype(jnp.float32) @ router.astype(jnp.float32)
+    )  # [T, X]
+    _, idx = jax.lax.top_k(z + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(z, idx, axis=-1)
+    gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return idx, gates * routed_scale
+
+
+def dropless_block_rows(tokens: int, top_k: int, n_experts: int) -> int:
+    """Rows of one expert block: the power of two from 8 to 128 that
+    holds four times an expert's expected load of ``tokens`` tokens, so
+    that an expert needs a second block only where the routing sends it
+    several times its share. Up to some 240 rows a block costs a v5e what
+    streaming the expert's weights costs, whatever its rows."""
+    rows = 8
+    while rows < 128 and rows < 4 * tokens * top_k / n_experts:
+        rows *= 2
+    return rows
+
+
+def _experts_grouped(xt, stacks, layer, e_flat, counts, gates, rows,
+                     activation):
+    """The pairs sorted by expert, in blocks of ``rows`` rows of ONE expert,
+    as many blocks as the routing needs. Returns (routed [T, D] f32, the
+    blocks run)."""
+    t, d = xt.shape
+    top_k = gates.shape[1]
+    a = t * top_k
+    order = jnp.argsort(e_flat, stable=True)  # by expert, then token
+    starts = jnp.cumsum(counts) - counts  # first sorted pair of each
+    blocks = (counts + rows - 1) // rows  # blocks each expert needs
+    block_ends = jnp.cumsum(blocks)
+
+    def one_block(i, out_pairs):
+        e = jnp.searchsorted(block_ends, i, side="right").astype(jnp.int32)
+        first = (i - (block_ends[e] - blocks[e])) * rows  # within expert e
+        lane = first + jnp.arange(rows, dtype=jnp.int32)
+        valid = lane < counts[e]
+        pair = order[jnp.minimum(starts[e] + lane, a - 1)]
+        x_in = jnp.where(valid[:, None], xt[pair // top_k], 0)
+        # (layer, expert) sliced where the product reads it
+        w = {
+            name: jax.lax.dynamic_slice(
+                v, (layer, e, 0, 0), (1, 1) + v.shape[2:]
+            )[0]
+            for name, v in stacks.items()
+        }
+        out = _expert_compute(x_in[None], w, activation, None)[0]
+        # each pair has one row of the buffer; invalid lanes fall outside
+        return out_pairs.at[jnp.where(valid, pair, a)].set(
+            out.astype(out_pairs.dtype), mode="drop"
+        )
+
+    out_pairs = jax.lax.fori_loop(
+        0, block_ends[-1], one_block, jnp.zeros((a, d), xt.dtype)
+    )
+    routed = jnp.einsum(
+        "tk,tkd->td", gates, out_pairs.reshape(t, top_k, d),
+        preferred_element_type=jnp.float32,
+    )
+    return routed, block_ends[-1]
+
+
+def moe_dropless(
+    xt: jax.Array,  # [T, D]
+    params: dict,  # router [D, X], bias [X]; w_gate, w_in [H, D, F],
+    #               w_out [H, F, D] of the H experts held ([Le, H, ...]
+    #               where ``layer`` is given); shared {gate [D, Fs],
+    #               up [D, Fs], down [Fs, D]}
+    *,
+    top_k: int,
+    expert_offset: int,
+    routed_scale: float,
+    activation,
+    live: jax.Array | None = None,  # [T] bool: rows that are tokens
+    layer: jax.Array | None = None,  # index into whole expert stacks
+) -> tuple[jax.Array, jax.Array]:
+    """Returns (output [T, D], counts [3] int32): pairs (token, expert)
+    routed to experts held here, rows the expert products ran over,
+    experts held that received a token. See the module docstring."""
+    t = xt.shape[0]
+    stacks = {name: params[name] if layer is not None else params[name][None]
+              for name in ("w_gate", "w_in", "w_out")}
+    layer = jnp.asarray(0 if layer is None else layer, jnp.int32)
+    held = stacks["w_in"].shape[1]
+
+    with jax.named_scope("moe_route"):
+        idx, gates = _route_sigmoid(
+            xt, params["router"], params["bias"], top_k, routed_scale
+        )
+        local = idx - expert_offset
+        here = (local >= 0) & (local < held)
+        if live is not None:
+            here = here & live[:, None]
+        # pairs of absent experts form group ``held``, never computed
+        e_flat = jnp.where(here, local, held).reshape(-1).astype(jnp.int32)
+        counts = jnp.bincount(e_flat, length=held + 1)[:held]
+
+    with jax.named_scope("moe_experts"):
+        rows = dropless_block_rows(t, top_k, params["router"].shape[-1])
+        routed, n_blocks = _experts_grouped(
+            xt, stacks, layer, e_flat, counts, gates, rows, activation
+        )
+
+    with jax.named_scope("moe_shared"):
+        sh = params["shared"]
+        g = activation(xt @ sh["gate"].astype(xt.dtype))
+        u = xt @ sh["up"].astype(xt.dtype)
+        shared = (g * u) @ sh["down"].astype(xt.dtype)
+
+    stats = jnp.stack([
+        jnp.sum(here), n_blocks * rows, jnp.sum(counts > 0),
+    ]).astype(jnp.int32)
+    return (routed + shared.astype(jnp.float32)).astype(xt.dtype), stats

@@ -120,17 +120,7 @@ _KV_PROGRAM_KINDS = ("kv_export", "kv_import")
 _EMPTY_DRAFT = np.zeros((0,), np.int32)
 
 
-def _kv_bytes_per_position(cfg: ModelConfig, kv_quant: str = "none") -> int:
-    """K+V bytes one GLOBAL cache position costs across all layers (TP
-    divides the head dim across shards, so the global figure is the
-    comparable one either way). int8 pages carry one f32 scale per
-    token per KV head next to the values (ops/quant.quantize_kv), so a
-    quantized position costs head_dim + 4 bytes per head instead of
-    head_dim x itemsize."""
-    if kv_quant == "int8":
-        return cfg.n_layer * 2 * cfg.kv_heads * (cfg.head_dim + 4)
-    itemsize = jnp.dtype(cfg.dtype).itemsize
-    return cfg.n_layer * 2 * cfg.kv_heads * cfg.head_dim * itemsize
+_kv_bytes_per_position = decode.kv_bytes_per_position
 
 
 def _check_quant_arg(name: str, value: str) -> str:
@@ -434,6 +424,7 @@ class DecodeEngine:
             "prefix_hits": None,
             "evictions": None,
             "kv_quant": "none",
+            "kv_bytes_per_position": _kv_bytes_per_position(self.cfg),
             "speculative_k": 0,
             "spec_accept_rate": _spec_accept_rate(self.counters),
             "counters": dict(self.counters),
@@ -1136,11 +1127,22 @@ class BatchedDecodeEngine:
         if max_len > cfg.n_ctx:
             raise ValueError(f"max_len {max_len} exceeds n_ctx {cfg.n_ctx}")
         if cfg.n_experts:
+            # (a dropless layer, ops/moe.moe_dropless, has no capacity and
+            # is served: n_experts is 0 there)
             raise NotImplementedError(
-                "BatchedDecodeEngine does not serve MoE configs: expert "
+                "BatchedDecodeEngine does not serve capacity-routed MoE "
+                "configs (n_experts > 0): expert "
                 "capacity couples batch rows through the dispatch, so a "
                 "row's output would depend on its neighbours — use the "
                 "serial DecodeEngine for MoE decode"
+            )
+        if not decode.has_dense_cache(cfg) and not isinstance(
+            self, PagedBatchedDecodeEngine
+        ):
+            raise NotImplementedError(
+                f"the {cfg.family} family caches pages only (a latent "
+                "pool): serve it through PagedBatchedDecodeEngine "
+                "(decode.init_cache has no dense layout for it)"
             )
         self.cfg = cfg
         self.slots = int(slots)
@@ -2036,19 +2038,20 @@ class BatchedDecodeEngine:
             mapping[q.rid] = rid
         return mapping
 
-    def peek_tokens(self, rid: int) -> np.ndarray | None:
+    def peek_tokens(self, rid: int, since: int = 0) -> np.ndarray | None:
         """Tokens-so-far for a live OR terminal request (prompt + every
-        clean token generated to date) — the host-side progress read the
-        SSE streaming front door polls between ticks. None for unknown
-        rids; never touches device state."""
+        clean token generated to date), from index ``since`` on — the
+        host-side progress read the SSE streaming front door makes every
+        tick (past the prompt it copies the new tokens only). None for
+        unknown rids; never touches device state."""
         for s in self._slots:
             if s is not None and s.rid == rid:
-                return self._partial_tokens(s.prompt, s.generated)
+                return self._partial_tokens(s.prompt, s.generated, since)
         for q in self._queue:
             if q.rid == rid:
-                return self._partial_tokens(q.prompt, q.gen)
+                return self._partial_tokens(q.prompt, q.gen, since)
         res = self.results.get(rid)
-        return None if res is None else np.asarray(res.tokens)
+        return None if res is None else np.asarray(res.tokens)[since:]
 
     # -- scheduler internals -----------------------------------------------
 
@@ -2093,10 +2096,12 @@ class BatchedDecodeEngine:
             tenant_slot=s.tenant_slot, stamps=s.stamps,
         )
 
-    def _partial_tokens(self, prompt, gen) -> np.ndarray:
+    def _partial_tokens(self, prompt, gen, since: int = 0) -> np.ndarray:
+        if since >= len(prompt):
+            return np.asarray(gen[since - len(prompt):], np.int32)
         return np.concatenate(
             [np.asarray(prompt, np.int32), np.asarray(gen, np.int32)]
-        )
+        )[since:]
 
     def _stamp_row(self, st: _Stamps) -> None:
         """The request holds a row; the first time ends its queue wait."""
@@ -2648,6 +2653,9 @@ class BatchedDecodeEngine:
             "prefix_hits": None,
             "evictions": None,
             "kv_quant": "none",
+            # what one cache position costs across all layers, in the
+            # family's own page layout (per-head K and V, or one latent)
+            "kv_bytes_per_position": self._bytes_per_position(),
             "speculative_k": self.speculative_k,
             "spec_accept_rate": _spec_accept_rate(self.counters),
             "counters": dict(self.counters),
@@ -2979,6 +2987,28 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         # ``export_handoff``; "decode" accepts rows only via
         # ``import_handoff``/``adopt`` and never prefills fresh prompts.
         self.role = _check_role(role)
+        # A model that counts its work (``decode.aux_counts``: the
+        # kimi_k2 family's expert layers) has its programs hand the counts
+        # back between the tokens and the sentinel; they accumulate here
+        # per program kind beside the tokens each kind processed, and the
+        # cache positions the decode program's rows reached. Only the two
+        # plain programs carry them: one device, unquantized pages, the
+        # gather path.
+        self._aux_counts = decode.aux_counts(cfg)
+        if self._aux_counts:
+            if (self.mode != "plain" or self.kv_quant != "none"
+                    or self.weight_quant != "none" or self.adapters
+                    or self.speculative_k or self._paged_impl != "gather"):
+                raise NotImplementedError(
+                    f"the {cfg.family} family is served on one device "
+                    "from unquantized latent pages by the gather path: no "
+                    "mesh, kv_quant, weight_quant, adapters, "
+                    "speculative_k or paged_attention kernel"
+                )
+            for kind in ("prefill", "decode_step"):
+                for name in (*self._aux_counts, "moe_tokens"):
+                    self.counters[f"{name}.{kind}"] = 0
+            self.counters["latent_positions_read"] = 0
         self.counters["preemptions"] = 0
         self.counters["preempt_priority"] = 0
         self.counters["batch_yield_ticks"] = 0
@@ -3121,16 +3151,24 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
 
     # -- programs ----------------------------------------------------------
 
-    def _forward_paged(self, params, ids, cache, pos, tables, lora=None):
+    def _forward_paged(self, params, ids, cache, pos, tables, lora=None,
+                       **plain):
+        """``decode.forward`` on the paged pool. ``plain``: what the two
+        plain programs pass (``live``, ``logits_index``); a model that
+        counts its work (``self._aux_counts``) then hands the counts back
+        as a third value."""
         kwargs = {
             "block_tables": tables,
             "paged_impl": self._paged_impl,
             "kv_quant": self.kv_quant,
+            **plain,
         }
         if self.mode == "tp":
             kwargs["tensor_axis"] = "tensor"
         if lora:
             kwargs["lora"] = lora
+        if plain and self._aux_counts:
+            kwargs["return_aux"] = True
         return decode.forward(params, ids, self.cfg, cache, pos, **kwargs)
 
     def _bodies(self):
@@ -3146,28 +3184,33 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             # garbage past the write point into the row's own padded
             # extent — the dense dirty-cache discipline at page
             # granularity). The sampled token only matters for rows on
-            # their final chunk; the host discards the rest.
-            logits, cache = self._forward_paged(
-                params, chunks, cache, start, tables, lora
+            # their final chunk; the host discards the rest. The logits
+            # are those of valid-1; a model that counts its work counts
+            # the chunk's real tokens only.
+            lanes = jnp.arange(chunks.shape[1], dtype=jnp.int32)
+            logits, cache, *aux = self._forward_paged(
+                params, chunks, cache, start, tables, lora,
+                live=lanes[None] < valid[:, None], logits_index=valid - 1,
             )
-            last = jnp.take_along_axis(
-                logits, (valid - 1)[:, None, None], axis=1
-            )[:, 0]
+            last = logits[:, 0]
             keys = jax.random.wrap_key_data(keydata)
             tok = decode.sample_token_rows(last, greedy, t, keys, k, p)
-            return tok, decode.nonfinite_rows(last), cache
+            return (tok, *aux, decode.nonfinite_rows(last), cache)
 
         def decode_step(params, toks, cache, pos, tables, folds,
                         greedy, t, k, p, keydata, *lora):
-            logits, cache = self._forward_paged(
-                params, toks[:, None], cache, pos, tables, lora
+            # (a free or mid-prefill lane's table is all scratch page:
+            # page 0, which no live row is ever given)
+            logits, cache, *aux = self._forward_paged(
+                params, toks[:, None], cache, pos, tables, lora,
+                live=(tables[:, :1] != 0),
             )
             last = logits[:, -1]
             keys = jax.vmap(jax.random.fold_in)(
                 jax.random.wrap_key_data(keydata), folds
             )
             tok = decode.sample_token_rows(last, greedy, t, keys, k, p)
-            return tok, decode.nonfinite_rows(last), cache
+            return (tok, *aux, decode.nonfinite_rows(last), cache)
 
         def decode_spec_step(params, toks, cache, pos, tables, folds,
                              greedy, t, k, p, keydata, n_draft, *lora):
@@ -3605,8 +3648,10 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         res = self._dispatch("prefill", params, [], finished, *args)
         if res is None:
             return  # recovery converted every in-flight row already
-        toks, bad = res
+        toks, *aux, bad = res
         with self.timers.span("engine.settle.prefill"):
+            if aux:
+                self._count_aux("prefill", aux[0], int(valid[:n].sum()))
             for j in range(n):
                 row, s = rows[j]
                 if bad[j]:
@@ -3821,8 +3866,14 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         res = self._dispatch("decode_step", params, None, finished, *args)
         if res is None:
             return
-        out, bad = res
+        out, *aux, bad = res
         with self.timers.span("engine.settle.decode"):
+            if aux:
+                self._count_aux("decode_step", aux[0], len(ready))
+                # each row's token at pos attends positions 0..pos
+                self.counters["latent_positions_read"] += sum(
+                    s.pos + 1 for _, s in ready
+                )
             for i, s in ready:
                 if bad[i]:
                     self._slots[i] = None
@@ -3833,6 +3884,14 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 s.pos += 1
                 s.fold += 1
                 self._maybe_retire(i, finished)
+
+    def _count_aux(self, kind: str, counts, tokens: int) -> None:
+        """One dispatch's counts (``decode.aux_counts``, summed over the
+        layers by the program) and the tokens it processed, onto the
+        counters of its program kind."""
+        for name, n in zip(self._aux_counts, counts):
+            self.counters[f"{name}.{kind}"] += int(n)
+        self.counters[f"moe_tokens.{kind}"] += tokens
 
     def _ensure_decode_pages(
         self, finished: list[int], skip_batch: bool = False
@@ -3965,7 +4024,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             args = self.example_args(
                 "prefill", params, group=g, cache=self._take_cache()
             )
-            _, _, cache = self.program("prefill")(*args)
+            *_, cache = self.program("prefill")(*args)
             self._cache = cache
         self._rewarm_first_prefill(params)
         step_kind = self._program_kinds()[-1]

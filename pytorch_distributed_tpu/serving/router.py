@@ -126,9 +126,10 @@ class ReplicaRouter:
 
     - ``shed_queue_depth``: a replica whose engine queue is this deep is
       not admissible (default: 2x its slot count).
-    - ``shed_page_free``: a paged replica with fewer free pages is not
-      admissible (default 1 — "has any headroom at all"; raise it to
-      shed earlier under page pressure).
+    - ``shed_page_free``: a paged replica with fewer ALLOCATABLE pages
+      (free, or cached and evictable: ``stats()["allocatable_pages"]``)
+      is not admissible (default 1 — "has any headroom at all"; raise it
+      to shed earlier under page pressure).
     - ``degrade_factor`` / ``degrade_min_s`` / ``ema_alpha``: brown-out
       detection — DEGRADED when the replica's step-latency EMA exceeds
       ``max(degrade_min_s, degrade_factor * fleet-median EMA)``;
@@ -275,7 +276,12 @@ class ReplicaRouter:
             return None
         page_pressure = 0.0
         if st["free_pages"] is not None:
-            if st["free_pages"] < self.shed_page_free:
+            # what the allocator can DELIVER: the free list plus the
+            # retired prompts' cached chunks it evicts on demand. The
+            # free list alone empties on a busy pool that is full of
+            # reusable pages, and shedding there refuses work the
+            # replica has room for.
+            if st["allocatable_pages"] < self.shed_page_free:
                 return None
             # Session-pinned pages count as UNAVAILABLE capacity: they
             # are off the allocator's table until their session goes
@@ -554,23 +560,23 @@ class ReplicaRouter:
             return True
         return False
 
-    def progress(self, rid: int):
-        """Tokens-so-far for a live or terminal router rid (the SSE
-        streaming read) — None for unknown rids."""
+    def progress(self, rid: int, since: int = 0):
+        """Tokens-so-far, from index ``since`` on, for a live or terminal
+        router rid (the SSE streaming read) — None for unknown rids."""
         with self.timers.span("router.progress"):
             if rid in self.results:
-                return np.asarray(self.results[rid].tokens)
+                return np.asarray(self.results[rid].tokens)[since:]
             for orid, q in self._orphans:
                 if orid == rid:
                     return np.concatenate([
                         np.asarray(q.prompt, np.int32),
                         np.asarray(q.gen, np.int32),
-                    ])
+                    ])[since:]
             loc = self._assign.get(rid)
             if loc is None:
                 return None
             rep_id, erid = loc
-            return self._replicas[rep_id].engine.peek_tokens(erid)
+            return self._replicas[rep_id].engine.peek_tokens(erid, since)
 
     def has_work(self) -> bool:
         return bool(self._orphans) or any(

@@ -112,6 +112,12 @@ class ServingServer:
         # broadcast event per tick for SSE progress pollers.
         self._done_events: dict[int, asyncio.Event] = {}
         self._tick_event = asyncio.Event()
+        # Open SSE streams, rid -> tokens sent so far, and what each has
+        # to send next, read ONCE a tick under the tick's own hold of the
+        # lock (``_tick``): rid -> ``_peek``'s answer. Both are written
+        # and read on the event loop only.
+        self._streams: dict[int, int] = {}
+        self._streamed: dict[int, tuple] = {}
         self._work_event = asyncio.Event()
         self._log = get_logger("pdtpu.serving")
         # What an operator alerts on, served under "server" in /healthz.
@@ -177,10 +183,35 @@ class ServingServer:
         holds it for a whole engine tick)."""
         return await asyncio.to_thread(self._locked, fn, *args, **kw)
 
+    def _peek(self, rid: int, since: int) -> tuple:
+        """(since, the tokens from index ``since`` on or None, terminal
+        result in?) — call locked."""
+        return (since, self.router.progress(rid, since),
+                rid in self.router.results)
+
+    def _tick(self, streams: tuple[tuple[int, int], ...]):
+        """One router tick and, under the same hold of the lock, every open
+        stream's ``_peek``; None when the router has no work. ONE locked
+        call a tick however many streams are open, asked for before the
+        pollers wake: pollers that each take the lock themselves queue
+        between two ticks with the next tick behind them, and the chip
+        waits (21 ms a tick at 64 streams; PERF.md section 6, PR 30)."""
+        if not self.router.has_work():
+            return None
+        finished = self.router.step(self.params)
+        return finished, {rid: self._peek(rid, n) for rid, n in streams}
+
     async def _drive_loop(self) -> None:
         while self._running:
-            has_work = await self._router_call(self.router.has_work)
-            if not has_work:
+            try:
+                ticked = await self._router_call(
+                    self._tick, tuple(self._streams.items())
+                )
+            except Exception:  # a dead fleet must not kill the server
+                self._log.exception("router step failed")
+                await asyncio.sleep(self.idle_poll_s)
+                continue
+            if ticked is None:
                 self._work_event.clear()
                 try:
                     await asyncio.wait_for(
@@ -189,14 +220,7 @@ class ServingServer:
                 except (asyncio.TimeoutError, TimeoutError):
                     pass
                 continue
-            try:
-                finished = await self._router_call(
-                    self.router.step, self.params
-                )
-            except Exception:  # a dead fleet must not kill the server
-                self._log.exception("router step failed")
-                await asyncio.sleep(self.idle_poll_s)
-                continue
+            finished, self._streamed = ticked
             for rid in finished:
                 ev = self._done_events.pop(rid, None)
                 if ev is not None:
@@ -443,15 +467,20 @@ class ServingServer:
         )
         sent = prompt_len
         self.counters["streams_open"] += 1
+        self._streams[rid] = sent
         try:
             while True:
-                tokens = await self._router_call(self.router.progress, rid)
-                done = await self._router_call(
-                    lambda: rid in self.router.results
+                # (taken before the read: a tick that lands after it ends
+                # the wait below at once)
+                tick = self._tick_event
+                since, tokens, done = self._streamed.get(
+                    rid, (sent, None, False)
                 )
                 if tokens is not None:
-                    self._write_events(writer, np.asarray(tokens)[sent:])
-                    sent = max(sent, len(tokens))
+                    # (a read made before the last write overlaps it)
+                    self._write_events(writer, tokens[sent - since:])
+                    sent = max(sent, since + len(tokens))
+                    self._streams[rid] = sent
                     await writer.drain()  # raises if the client left
                 if done:
                     res = await self._router_call(
@@ -466,13 +495,16 @@ class ServingServer:
                     )
                     await writer.drain()
                     return True
-                # Wait for the next scheduler tick (or the idle poll —
-                # a parked/queued rid makes no progress between ticks).
-                tick = self._tick_event
+                # Wait for the next scheduler tick, which reads for every
+                # stream. Without one for a while (an idle router; a
+                # result delivered outside a tick, as abort's is) this
+                # stream reads for itself.
                 try:
                     await asyncio.wait_for(tick.wait(), 0.25)
                 except (asyncio.TimeoutError, TimeoutError):
-                    pass
+                    self._streamed[rid] = await self._router_call(
+                        self._peek, rid, sent
+                    )
         except (ConnectionResetError, BrokenPipeError):
             # Client hung up mid-stream: abort the request — the row
             # frees, the partial result delivers and is discarded.
@@ -486,6 +518,8 @@ class ServingServer:
             return False
         finally:
             self.counters["streams_open"] -= 1
+            self._streams.pop(rid, None)
+            self._streamed.pop(rid, None)
             self._done_events.pop(rid, None)
 
     async def _abort(self, body, writer) -> None:

@@ -291,6 +291,44 @@ def test_page_pressure_excludes_starved_replica():
     assert router._assign[rid2][0] == 0  # headroom back -> lowest id wins
 
 
+def test_reusable_pages_are_headroom_for_the_shed_gate():
+    """A pool whose free list is empty but whose prefix cache holds retired
+    prompts' chunks is NOT starved: the allocator evicts them on demand
+    (``allocatable_pages``), so the router admits. Only when nothing is
+    evictable either does it shed. (The gate read the free list alone and
+    answered 429 on a pool full of reusable pages.)"""
+    cfg = _cfg()
+    clock = VirtualClock()
+
+    def make_engine(rep_id):
+        return PagedBatchedDecodeEngine(
+            cfg, slots=2, max_len=32, page_size=8, prefill_chunk=8,
+            pool_pages=9, clock=clock, sleep=clock.sleep,
+        )
+
+    router = ReplicaRouter(make_engine, 1, clock=clock)
+    params = _params(cfg)
+    # Two retired prompts of two whole chunks each leave four cached pages.
+    for seed in (1, 2):
+        router.submit(_prompt(17, seed), 2)
+    router.run(params)
+    pool = router._replicas[0].engine.pool
+    taken = pool.alloc(pool.free_pages())  # the free list, emptied
+    st = router._replicas[0].engine.stats()
+    assert st["free_pages"] == 0 and st["allocatable_pages"] >= 4
+    rid = router.submit(_prompt(17, 3), 2)  # admitted: eviction delivers
+    router.run(params)
+    assert router.pop_result(rid).state == "DONE"
+    assert router.counters.get("shed", 0) == 0
+    # nothing free and nothing evictable: now the replica is starved
+    pool.reset()
+    taken = pool.alloc(pool.free_pages())
+    assert router._replicas[0].engine.stats()["allocatable_pages"] == 0
+    with pytest.raises(RouterOverloaded):
+        router.submit(_prompt(4, 4), 2)
+    pool.release(taken)
+
+
 def test_shed_rejects_loudly_with_retry_after():
     """When every replica is past its admission threshold the router
     raises RouterOverloaded carrying a retry_after_s hint — reject

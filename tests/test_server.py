@@ -325,3 +325,47 @@ def test_healthz_keeps_its_keys_and_counts_the_front_door():
     done = rep["counters"]["done"]
     assert rep["timers"]["queue_wait"]["count"] == done == 7 - shed
     assert rep["timers"]["engine.tick"]["count"] >= 15
+
+
+def test_open_streams_take_no_lock_of_their_own_between_ticks():
+    """The drive loop reads every open stream's progress under the tick's
+    own hold of the router lock (``_tick``): however many streams are open,
+    a tick is ONE locked call, and a stream adds its submit and the pop of
+    its result. (Each poller took the lock twice a tick: at 64 streams the
+    next tick queued 21 ms behind them with the chip idle.) Every stream
+    still gets every token and its ``done`` event."""
+    cfg = _cfg()
+    params = get_model(cfg).init(jax.random.key(0), cfg)
+    server = _setup(cfg, params, n_replicas=1)
+    calls = []
+    locked = server._locked
+    server._locked = lambda fn, *a, **kw: (
+        calls.append(getattr(fn, "__name__", "?")), locked(fn, *a, **kw))[1]
+
+    async def scenario():
+        host, port = await server.start()
+        try:
+            return await asyncio.gather(*[
+                _http(host, port, "POST", "/v1/generate",
+                      {"prompt": [3 + i] * 6, "max_new_tokens": 12,
+                       "stream": True})
+                for i in range(2)
+            ])
+        finally:
+            await server.stop()
+
+    for status, _, raw in asyncio.run(scenario()):
+        events = _sse_events(raw)
+        assert status == 200 and len(events) == 12 + 1
+        assert events[-1][0] == "done"
+    ticks = server.router.timers.snapshot()["router.tick"]["count"]
+    assert ticks >= 11
+    # what the two streams asked for themselves; a slow tick (its compile,
+    # a loaded host) may add a stream's own read after 0.25 s without one
+    assert calls.count("submit") == 2 and calls.count("pop_result") == 2
+    assert calls.count("_peek") < ticks / 2  # (it was four a tick)
+    assert calls.count("progress") == 0  # never on its own: inside _peek
+    # every other call is the drive loop's: one a tick, some that found
+    # no work
+    assert calls.count("_tick") >= ticks
+    assert set(calls) <= {"submit", "pop_result", "_peek", "_tick"}
