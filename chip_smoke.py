@@ -481,6 +481,8 @@ def paged_case(rng, sizes: Sizes, h: int, hkv: int, quantized: bool):
         scales = dict(k_scales=ks, v_scales=vs)
     else:
         k, v, scales = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16), {}
+    # the pool's stored shape: the heads merged head-major on the minor axis
+    k, v = (x.reshape(pool, PAGE_SIZE, hkv * d) for x in (k, v))
     return q, k, v, jnp.asarray(tables), jnp.asarray(lengths), scales
 
 

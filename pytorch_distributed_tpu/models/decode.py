@@ -34,9 +34,11 @@ Design (TPU-first):
   block scatters its new tokens into the stacked leaves at ``(layer, ...)``
   and attention reads them back at ``(layer, ...)``, so a donated cache is
   updated where it lies — no per-layer slice out, no stacked copy back.
-  (On the v5e the runtime stores a ``[..., Hkv, D]`` leaf page-axis-minor
-  and XLA still converts the whole pool at program entry and exit: what
-  is left of the cost is the stored SHAPE's, PERF.md section 5.)
+  The PAGED pool is stored ``[L, P, page, Hkv*D]``: whole lanes on the
+  minor axis, so the TPU runtime keeps it row-major and no program
+  converts it at entry and exit (a minor axis of D = 64, half a lane, is
+  stored page-axis-minor, and the scatter and the gather then cost four
+  whole-pool copies a dispatch: PERF.md section 6, PR 31).
 - Attention here is the naive einsum path in f32: decode is matmul-light
   ([B, H, T, S] with T = 1), so flash-kernel dispatch is pointless.
 - Sampling params (``temperature``/``top_k``/``top_p``) are TRACED runtime
@@ -132,9 +134,12 @@ def init_paged_cache(
     cfg: ModelConfig, pool_pages: int, page_size: int, dtype=None,
     n_kv: int | None = None, kv_quant: str = "none",
 ) -> Cache:
-    """Preallocate a PAGED [L, pool_pages, page_size, Hkv, D] key/value
+    """Preallocate a PAGED [L, pool_pages, page_size, Hkv*D] key/value
     pool pair (serving/block_pool.py owns the host-side allocation; page
-    0 is the reserved scratch page). ``n_kv`` as in ``init_cache``.
+    0 is the reserved scratch page). ``n_kv`` as in ``init_cache``. The
+    minor axis merges the heads HEAD-MAJOR (head g is columns
+    [g*D, (g+1)*D)), so a position's K (or V) is one run of whole lanes
+    and a tensor-parallel shard of the axis is Hkv/tp whole heads.
 
     ``kv_quant="int8"``: the value pools are int8 and two f32 scale
     pools ``k_scale``/``v_scale`` of [L, pool_pages, page_size, Hkv]
@@ -158,16 +163,15 @@ def init_paged_cache(
 
         return init_latent_pool(cfg, pool_pages, page_size, dtype)
     dtype = jnp.dtype(dtype or cfg.dtype)
-    shape = (
-        cfg.n_layer, pool_pages, page_size, n_kv or cfg.kv_heads,
-        cfg.head_dim,
-    )
+    hkv = n_kv or cfg.kv_heads
+    shape = (cfg.n_layer, pool_pages, page_size, hkv * cfg.head_dim)
     if kv_quant == "int8":
+        scales = shape[:-1] + (hkv,)
         return {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.ones(shape[:-1], jnp.float32),
-            "v_scale": jnp.ones(shape[:-1], jnp.float32),
+            "k_scale": jnp.ones(scales, jnp.float32),
+            "v_scale": jnp.ones(scales, jnp.float32),
         }
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -176,8 +180,9 @@ def gather_pages(pool: jax.Array, layer, block_tables: jax.Array):
     """Stacked [L, P, page, ...] pool + layer index + [B, n_pages]
     tables -> the [B, S, ...] contiguous per-row view of that layer
     dense attention expects (S = n_pages * page; trailing dims pass
-    through, so int8 value pools [L, P, page, Hkv, D] and their scale
-    pools [L, P, page, Hkv] gather through the same code). ONE gather
+    through, so value pools [L, P, page, Hkv*D], their int8 scale pools
+    [L, P, page, Hkv] and kimi_k2's latent pool gather through the same
+    code). ONE gather
     indexed by (layer, page id): the layer's pool is never sliced out on
     its own. Unallocated table entries point at the scratch page —
     garbage the ``pos`` mask already excludes, exactly like a dense
@@ -202,8 +207,9 @@ def _cached_attention(q, cache, layer, pos, block_tables=None,
     ever read — is independent of its neighbours).
 
     ``block_tables`` [B, n_pages] switches to the PAGED cache layout
-    (k/v are [L, P, page, Hkv, D] pools): the gather fallback materialises
-    the per-row view and runs the identical masked math (bit-equal to the
+    (k/v are [L, P, page, Hkv*D] pools): the gather fallback materialises
+    the per-row [B, S, Hkv*D] view, splits the heads out of its minor axis
+    AFTER the gather, and runs the identical masked math (bit-equal to the
     dense path wherever the valid positions hold the same values); for
     single-token decode, ``paged_impl`` of "kernel"/"kernel_interpret"
     dispatches the Pallas paged-attention kernel instead, which reads
@@ -229,9 +235,15 @@ def _cached_attention(q, cache, layer, pos, block_tables=None,
             interpret=paged_impl == "kernel_interpret",
         )
         return out[:, None]
+    b, t, h, d = q.shape
     if block_tables is not None:
-        ck = gather_pages(cache["k"], layer, block_tables)
-        cv = gather_pages(cache["v"], layer, block_tables)
+        ck, cv = (  # [B, S, Hkv*D] -> [B, S, Hkv, D]
+            x.reshape(x.shape[:2] + (-1, d))
+            for x in (
+                gather_pages(cache["k"], layer, block_tables),
+                gather_pages(cache["v"], layer, block_tables),
+            )
+        )
         if kv_quant == "int8":
             from pytorch_distributed_tpu.ops.quant import dequantize_kv
 
@@ -245,7 +257,6 @@ def _cached_attention(q, cache, layer, pos, block_tables=None,
             )
     else:
         ck, cv = cache["k"][layer], cache["v"][layer]
-    b, t, h, d = q.shape
     s, hkv = ck.shape[1], ck.shape[2]
     if hkv != h:
         rep = h // hkv
@@ -275,7 +286,9 @@ def _write(leaf, layer, new, pos, block_tables=None):
     bit-identical values to the scalar-pos write at the same offset.
 
     With ``block_tables`` [B, n_pages] the leaf is a PAGED pool
-    [L, P, page, Hkv, D]: token i of row b lands at page
+    [L, P, page, ...] (value pools merge their heads, [..., Hkv*D]; scale
+    pools [..., Hkv]; the latent pool [..., width]) and ``new``'s axes
+    past [B, T] are flattened to the leaf's: token i of row b lands at page
     ``table[b, (pos[b]+i) // page]``, offset ``(pos[b]+i) % page`` — one
     scatter, pure data movement again. The host guarantees distinct live
     rows write distinct pages (the copy-on-write discipline of
@@ -312,7 +325,9 @@ def _write(leaf, layer, new, pos, block_tables=None):
         block_tables, jnp.minimum(pidx, n_pages - 1), axis=1
     )
     pids = jnp.where(pidx < n_pages, pids, 0)  # OOB -> scratch page
-    return leaf.at[layer, pids, gpos % page].set(new)
+    return leaf.at[layer, pids, gpos % page].set(
+        new.reshape((b, t) + leaf.shape[3:])
+    )
 
 
 def _write_kv(cache, layer, k_new, v_new, pos, block_tables=None,
@@ -512,7 +527,7 @@ def forward(
     KV cache is untouched by the choice of MLP.
 
     ``block_tables`` [B, n_pages] switches the cache to the PAGED pool
-    layout (``init_paged_cache``: [L, P, page, Hkv, D] leaves) with
+    layout (``init_paged_cache``: [L, P, page, Hkv*D] leaves) with
     per-row page indirection — the serving block-pool mode
     (serving/engine.PagedBatchedDecodeEngine). ``pos`` must then be a
     [B] vector. ``paged_impl`` picks the paged attention backend for

@@ -35,7 +35,7 @@ NEG_INF = -1e30  # finite mask value: -inf breaks softmax when a row is all-mask
 def paged_decode_attention(*args, **kwargs):
     """Lazy re-export of ops/paged_kernel.paged_decode_attention (see
     module docstring): paged single-query decode attention, [B, H, D]
-    queries against a [P, page, Hkv, D] pool via [B, n_pages] block
+    queries against a [P, page, Hkv*D] pool via [B, n_pages] block
     tables. Lazy so importing the training attention surface never pays
     the Pallas import."""
     from pytorch_distributed_tpu.ops.paged_kernel import (

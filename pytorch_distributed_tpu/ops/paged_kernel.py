@@ -2,7 +2,9 @@
 
 Single-query attention for the paged serving engine
 (serving/engine.PagedBatchedDecodeEngine): each batch row's K/V lives in
-fixed-size PAGES of a shared pool ``[P, page, Hkv, D]``, addressed
+fixed-size PAGES of a shared pool ``[P, page, Hkv*D]`` (the heads merged
+head-major on the minor axis, so a page's rows are whole lanes: the
+stored shape of ``models/decode.init_paged_cache``), addressed
 through a per-row block table — the vLLM cache layout, which is what
 lets ``slots`` scale with the pool instead of ``slots x max_len``
 (ROADMAP direction 1; serving practice surveyed in PAPERS.md #1).
@@ -17,7 +19,7 @@ row's DEPTH instead of ``max_len``:
   ``PrefetchScalarGridSpec`` scalar prefetch, so the K/V BlockSpec *index
   maps* resolve ``(layer, tables[b, i])`` before the body runs — the page
   "gather" is just the kernel's own DMA picking its source block out of
-  the STACKED ``[L, P, page, Hkv, D]`` pool, never a materialised
+  the STACKED ``[L, P, page, Hkv*D]`` pool, never a materialised
   [B, max_len] copy nor a per-layer slice of the pool;
 - pages past a row's depth are skipped with ``pl.when`` (no MXU work,
   and their DMA re-reads the row's last useful page id — the host fills
@@ -25,12 +27,12 @@ row's DEPTH instead of ``max_len``:
   fetch is bounded and harmless);
 - one grid step holds ALL heads of one page. Mosaic tiles the last two
   block dims, so a block must cover them whole (or in (8, 128)
-  multiples): ``(1, page, Hkv, D)`` of one layer of the pool, ``(1, H, D)``
+  multiples): ``(1, page, Hkv*D)`` of one layer of the pool, ``(1, H, D)``
   over the queries, ``(1, page, Hkv)`` of the int8 scale pool. The body walks
-  the KV heads in a static loop, reading head ``g`` of the page as
-  ``k_ref[0, :, g, :]`` and computing the whole ``group = H // Hkv``
-  query-head block against that [page, D] key block, so grouped-query
-  heads share their KV head inside the kernel.
+  the KV heads in a static loop, reading head ``g`` of the page as the
+  static lane slice ``k_ref[0, :, g*D:(g+1)*D]`` and computing the whole
+  ``group = H // Hkv`` query-head block against that [page, D] key block,
+  so grouped-query heads share their KV head inside the kernel.
 
 GQA + per-row depth masking match ``models/decode._cached_attention``'s
 masked-softmax math up to online-softmax reassociation (floating-point
@@ -66,8 +68,8 @@ def _paged_kernel(
     tables_ref,  # [B, n_pages] int32 (scalar prefetch)
     lens_ref,  # [B] int32 (scalar prefetch): row's query position
     q_ref,  # [1, H, D]
-    k_ref,  # [1, page, Hkv, D] — the page tables_ref[b, i], all heads
-    v_ref,  # [1, page, Hkv, D]
+    k_ref,  # [1, page, Hkv*D] — the page tables_ref[b, i], all heads
+    v_ref,  # [1, page, Hkv*D]
     *rest,  # int8 pages: ks_ref, vs_ref [1, page, Hkv] f32; then o_ref
     # [1, H, D] and the f32 scratch acc [H, D], m [H, 1], l [H, 1]
     page: int,
@@ -88,7 +90,8 @@ def _paged_kernel(
         o_ref, acc_sc, m_sc, l_sc = rest
     b = pl.program_id(0)
     i = pl.program_id(1)
-    hkv = k_ref.shape[2]
+    d = q_ref.shape[2]
+    hkv = k_ref.shape[2] // d
     group = q_ref.shape[1] // hkv
 
     @pl.when(i == 0)
@@ -106,8 +109,9 @@ def _paged_kernel(
         for g in range(hkv):
             rows = slice(g * group, (g + 1) * group)
             q = q_ref[0, rows, :].astype(jnp.float32)  # [group, D]
-            kb = k_ref[0, :, g, :].astype(jnp.float32)  # [page, D]
-            vb = v_ref[0, :, g, :].astype(jnp.float32)
+            lanes = slice(g * d, (g + 1) * d)  # head g of the merged axis
+            kb = k_ref[0, :, lanes].astype(jnp.float32)  # [page, D]
+            vb = v_ref[0, :, lanes].astype(jnp.float32)
             if quantized:
                 # Dequant-in-kernel: int8 block * per-token scale column.
                 kb = kb * ks_ref[0, :, g:g + 1]
@@ -146,13 +150,13 @@ def _paged_kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_call(q, k_pages, v_pages, scales, layer, block_tables, lengths,
                 interpret):
-    """``k_pages``/``v_pages`` are STACKED [L, P, page, Hkv, D] pools and
+    """``k_pages``/``v_pages`` are STACKED [L, P, page, Hkv*D] pools and
     ``layer`` [1] picks the layer; ``scales`` is ``()`` for full-precision
     pages or the ``(k_scales, v_scales)`` [L, P, page, Hkv] pools for
     int8 pages."""
     b, h, d = q.shape
     n_pages = block_tables.shape[1]
-    page, hkv = k_pages.shape[2], k_pages.shape[3]
+    page, hkv = k_pages.shape[2], k_pages.shape[3] // d
     kernel = functools.partial(
         _paged_kernel,
         page=page, n_pages=n_pages, scale=1.0 / (d**0.5),
@@ -162,10 +166,8 @@ def _paged_call(q, k_pages, v_pages, scales, layer, block_tables, lengths,
         (1, h, d), lambda bi, i, layer, tables, lens: (bi, 0, 0)
     )
     page_spec = pl.BlockSpec(
-        (None, 1, page, hkv, d),
-        lambda bi, i, layer, tables, lens: (
-            layer[0], tables[bi, i], 0, 0, 0
-        ),
+        (None, 1, page, hkv * d),
+        lambda bi, i, layer, tables, lens: (layer[0], tables[bi, i], 0, 0),
     )
     scale_spec = pl.BlockSpec(
         (None, 1, page, hkv),
@@ -201,8 +203,8 @@ def _paged_call(q, k_pages, v_pages, scales, layer, block_tables, lengths,
 
 def paged_decode_attention(
     q: jax.Array,  # [B, H, D] — ONE query token per row
-    k_pages: jax.Array,  # [P, page, Hkv, D] (int8 when quantized)
-    v_pages: jax.Array,  # [P, page, Hkv, D]
+    k_pages: jax.Array,  # [P, page, Hkv*D] (int8 when quantized)
+    v_pages: jax.Array,  # [P, page, Hkv*D], heads merged head-major
     block_tables: jax.Array,  # [B, n_pages] int32 page ids
     lengths: jax.Array,  # [B] int32: the row's position (keys <= it valid)
     *,
@@ -234,10 +236,14 @@ def paged_decode_attention(
                 "pass interpret=True to run the Pallas interpreter"
             )
         interpret = False
-    h, hkv = q.shape[1], k_pages.shape[-2]
-    if h % hkv:
+    h, d = q.shape[1:]
+    want = "[P, page, Hkv*D]" if layer is None else "[L, P, page, Hkv*D]"
+    width = k_pages.shape[-1]
+    if k_pages.ndim != want.count(",") + 1 or width % d or h % (width // d):
         raise ValueError(
-            f"query heads {h} must be a multiple of kv heads {hkv}"
+            f"pages {k_pages.shape}: want {want} with whole kv heads of "
+            f"D={d} on the minor axis and the query heads ({h}) a multiple "
+            "of them"
         )
     if (k_scales is None) != (v_scales is None):
         raise ValueError(
@@ -274,7 +280,10 @@ def paged_decode_attention_reference(
     def view(pool):  # one layer's pool is a stack of one
         return gather_pages(pool[None], 0, tables)
 
-    ck, cv = view(k_pages), view(v_pages)
+    ck, cv = (  # [B, S, Hkv*D] -> [B, S, Hkv, D]
+        x.reshape(x.shape[:2] + (-1, d))
+        for x in (view(k_pages), view(v_pages))
+    )
     if k_scales is not None:
         from pytorch_distributed_tpu.ops.quant import dequantize_kv
 

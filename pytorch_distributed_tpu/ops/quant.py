@@ -10,8 +10,9 @@ by where its bytes sit:
    with a PER-TOKEN, PER-KV-HEAD f32 scale (``scale[b, t, h] =
    max|x[b, t, h, :]| / 127``), stored page-aligned next to the value
    pages (``[L, P, page, Hkv]`` scale leaves beside the
-   ``[L, P, page, Hkv, D]`` int8 leaves — serving/block_pool.py's pool
-   layout). Per-token granularity is NOT a tuning choice, it is the
+   ``[L, P, page, Hkv*D]`` int8 leaves, the heads merged head-major on
+   the minor axis — serving/block_pool.py's pool layout). Per-token
+   granularity is NOT a tuning choice, it is the
    soundness condition of the paged cache: pages fill incrementally
    (append on decode, chunk-at-a-time on prefill), so a scale shared
    across a page would be re-derived every append and silently

@@ -2,7 +2,7 @@
 
 The paged ``BatchedDecodeEngine`` variant (serving/engine.py:
 ``PagedBatchedDecodeEngine``) stores K/V in a flat pool of fixed-size
-PAGES — ``[L, pool_pages, page_size, Hkv, D]`` on device — and gives each
+PAGES — ``[L, pool_pages, page_size, Hkv*D]`` on device — and gives each
 request a per-row BLOCK TABLE of page ids instead of a dedicated
 ``max_len`` cache row. This module is the pool's host-side brain; nothing
 here is traced (the device only ever sees page-id int32 operands), so

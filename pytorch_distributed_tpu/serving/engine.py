@@ -2825,7 +2825,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
     The dense engine's ``(slots, max_len)`` cache charges every row
     O(max_len) HBM and O(max_len) attention regardless of its depth.
     Here the cache is a flat pool of fixed-size PAGES —
-    ``[L, pool_pages, page_size, Hkv, D]`` — and each row holds a BLOCK
+    ``[L, pool_pages, page_size, Hkv*D]`` — and each row holds a BLOCK
     TABLE of page ids instead of a dedicated row. Three consequences,
     all machine-checked:
 
@@ -3054,19 +3054,16 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
     # -- cache -------------------------------------------------------------
 
     def _cache_pspec(self) -> dict:
-        """Per-leaf PartitionSpecs for the paged cache under TP: value
-        pools shard their Hkv dim; the int8 layout's scale pools shard
-        the same dim (their last — scales live with their heads)."""
+        """Per-leaf PartitionSpecs for the paged cache under TP: every
+        leaf shards its last axis. The value pools' is the head-major
+        merged Hkv*D (a shard is Hkv/tp whole heads), the int8 layout's
+        scale pools' is Hkv (scales live with their heads)."""
         from jax.sharding import PartitionSpec as P
 
-        spec = {
-            "k": P(None, None, None, "tensor", None),
-            "v": P(None, None, None, "tensor", None),
-        }
+        names = ("k", "v")
         if self.kv_quant == "int8":
-            s = P(None, None, None, "tensor")
-            spec.update(k_scale=s, v_scale=s)
-        return spec
+            names += ("k_scale", "v_scale")
+        return {name: P(None, None, None, "tensor") for name in names}
 
     def _new_cache(self) -> decode.Cache:
         self.counters["cache_allocs"] += 1

@@ -134,8 +134,8 @@ def test_cache_entry_count_ignores_atime_files(tmp_path):
 def _paged_inputs(b=2, h=4, hkv=2, d=16, pool=5, page=8, n_pages=2):
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(pool, page, hkv, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(pool, page, hkv, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(pool, page, hkv * d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(pool, page, hkv * d)), jnp.float32)
     tables = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
     lengths = jnp.asarray([11, 3], jnp.int32)
     return q, k, v, tables, lengths

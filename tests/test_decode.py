@@ -406,6 +406,51 @@ def test_cache_rides_the_layer_scans_carry(layout):
     ]
 
 
+@pytest.mark.parametrize(
+    "family,kv_quant,n_kv",
+    [("gpt2", "none", None), ("llama", "none", None),
+     ("llama", "int8", None), ("gpt2", "none", 2)],
+)
+def test_paged_pool_leaf_is_L_P_page_heads_by_D(family, kv_quant, n_kv):
+    """The stored shape: heads merged into the minor axis (whole lanes at the
+    published widths), scales one per head; ``n_kv`` (a TP shard's local
+    heads) sizes both."""
+    cfg = _cfg(family)
+    cache = decode.init_paged_cache(cfg, 7, _PAGE, kv_quant=kv_quant, n_kv=n_kv)
+    hkv = n_kv or cfg.kv_heads
+    want = {"k", "v"} | ({"k_scale", "v_scale"} if kv_quant == "int8" else set())
+    assert set(cache) == want
+    for name, leaf in cache.items():
+        width = hkv if name.endswith("_scale") else hkv * cfg.head_dim
+        assert leaf.shape == (cfg.n_layer, 7, _PAGE, width), name
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_paged_pool_holds_the_dense_caches_heads_head_major(family):
+    """One prefill through both layouts: position s of row b lies at page
+    table[b, s // page], offset s % page, and head g of it is columns
+    [g*D, (g+1)*D) of the merged axis (llama: Hkv < H, so a GQA repeat
+    after the gather reads whole heads); the logits agree (bit-equal for
+    gpt2; XLA:CPU fuses llama's repeat differently: an ulp)."""
+    cfg = _cfg(family)
+    params = get_model(cfg).init(jax.random.key(0), cfg)
+    ids = jax.random.randint(jax.random.key(1), (_B, 10), 0, cfg.vocab_size)
+    pos = jnp.zeros((_B,), jnp.int32)
+    n_pages = _S // _PAGE
+    tables = 1 + jnp.arange(_B * n_pages, dtype=jnp.int32).reshape(_B, n_pages)
+    want, dense = decode.forward(
+        params, ids, cfg, decode.init_cache(cfg, _B, _S), pos)
+    got, paged = decode.forward(
+        params, ids, cfg, decode.init_paged_cache(cfg, 1 + _B * n_pages, _PAGE),
+        pos, block_tables=tables)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    for name in ("k", "v"):
+        view = np.asarray(paged[name])[:, np.asarray(tables)]  # [L,B,n,page,HD]
+        view = view.reshape(cfg.n_layer, _B, _S, cfg.kv_heads, cfg.head_dim)
+        np.testing.assert_allclose(
+            view[:, :, :10], np.asarray(dense[name])[:, :, :10], atol=1e-5)
+
+
 def test_compiled_paged_step_updates_the_pool_in_place():
     """An f32 pool: XLA:CPU upcasts a bf16 pool around a scatter with a
     whole-pool ``convert`` that the chip does not make."""
@@ -431,6 +476,7 @@ def test_compiled_paged_step_updates_the_pool_in_place():
         dims.update((i.name, shape_dims(i.shape)) for i in comp.instructions)
         todo += [c for i in comp.instructions for c in i.called]
     pool = cache["k"].shape
+    assert len(pool) == 4  # [L, P, page, Hkv*D]
     gathers = [
         i for i in body
         if i.opcode == "gather" and dims[i.operands[0]] == pool

@@ -320,11 +320,14 @@ def test_q8_cast_budget_fails_on_injected_f32_roundtrip(audit):
         # The classic silent leak: materialise the int8 pool wide, do
         # nothing useful, round it back. Numerically ~lossless-looking,
         # bandwidth-catastrophic — and invisible without the budget.
-        wide = cache["k"].astype(jnp.float32) * cache["k_scale"][..., None]
+        scale = cache["k_scale"][..., None]  # per head of the merged axis
+        wide = cache["k"].astype(jnp.float32).reshape(
+            scale.shape[:-1] + (-1,)
+        ) * scale
         requant = jnp.round(
-            wide / jnp.maximum(cache["k_scale"], 1e-30)[..., None]
+            wide / jnp.maximum(scale, 1e-30)
         ).astype(jnp.int8)
-        cache = dict(cache, k=requant)
+        cache = dict(cache, k=requant.reshape(cache["k"].shape))
         return body(params, toks, cache, *rest)
 
     args = eng.example_args("decode_step", params)
@@ -386,6 +389,8 @@ def test_paged_kernel_q8_matches_dequant_gather_reference():
     vf = jnp.asarray(rng.normal(size=(pool, page, hkv, d)), jnp.float32)
     kq, ks = quantize_kv(kf)
     vq, vs = quantize_kv(vf)
+    # the pool's stored shape: heads merged head-major on the minor axis
+    kq, vq = (x.reshape(pool, page, hkv * d) for x in (kq, vq))
     tables = np.zeros((b, n_pages), np.int32)
     lengths = np.asarray([0, 7, 17, 30], np.int32)
     pid = 1

@@ -95,11 +95,13 @@ def _mixed_requests():
     ]
 
 
-def test_paged_rows_match_dense_engine():
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_paged_rows_match_dense_engine(family):
     """The tier-1 equivalence pin: a busy paged batch (chunked prefill
     trickling in while neighbours decode, mixed sampling) emits exactly
-    the dense engine's tokens for every request."""
-    cfg = _cfg()
+    the dense engine's tokens for every request. llama: Hkv < H, so the
+    GQA repeat reads whole heads out of the pool's merged minor axis."""
+    cfg = _cfg(family)
     params = _params(cfg)
     dense = _dense(cfg)
     paged = _paged(cfg)
@@ -113,6 +115,34 @@ def test_paged_rows_match_dense_engine():
             out_p[rid].tokens, out_d[rid].tokens,
             err_msg=f"request {rid}",
         )
+
+
+def test_tp_pool_shard_holds_whole_heads(eight_devices):
+    """Under tensor=2 every pool leaf shards its last axis: a shard of the
+    head-major merged axis is Hkv/2 WHOLE heads, the ones whose queries the
+    same shard holds, and it reads what the one-device engine's pool holds
+    in those columns."""
+    cfg = _cfg("llama", n_kv_head=4)
+    params = _params(cfg)
+    req = dict(prompt=_prompt(13, 4), max_new_tokens=4)
+    plain = _paged(cfg)
+    tp = _paged(cfg, mesh_cfg=MeshConfig(tensor=2, strategy="no_shard"))
+    np.testing.assert_array_equal(
+        tp.run(params, [req])[0].tokens, plain.run(params, [req])[0].tokens)
+    d, width = cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    for name in ("k", "v"):
+        whole, leaf = np.asarray(plain._cache[name]), tp._cache[name]
+        assert leaf.shape == whole.shape == (2, tp.pool_pages, 8, width)
+        cuts = set()
+        for shard in leaf.addressable_shards:
+            cols = shard.index[-1]
+            assert all(ix == slice(None) for ix in shard.index[:-1])
+            assert shard.data.shape[-1] == (cfg.kv_heads // 2) * d
+            assert cols.start % d == 0 and cols.stop % d == 0
+            cuts.add((cols.start, cols.stop))
+            np.testing.assert_allclose(
+                np.asarray(shard.data), whole[..., cols], atol=1e-5)
+        assert cuts == {(0, width // 2), (width // 2, width)}
 
 
 def test_prefix_sharing_hits_and_page_accounting():
@@ -464,10 +494,13 @@ def test_quarantine_bypasses_prefix_cache():
     np.testing.assert_array_equal(out[rid].tokens, ref)
 
 
-def test_paged_kernel_matches_gather_fallback():
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+def test_paged_kernel_matches_gather_fallback(dtype, tol):
     """The Pallas paged-attention kernel (interpret mode on this rig)
     matches the XLA gather reference over GQA heads, ragged depths, and
-    scratch-page table entries."""
+    scratch-page table entries, on pages of the pool's stored shape
+    [P, page, Hkv*D] (bf16 as cell 3 holds them; the int8 pages'
+    twin is tests/test_quant.py's)."""
     from pytorch_distributed_tpu.ops.paged_kernel import (
         paged_decode_attention,
         paged_decode_attention_reference,
@@ -475,9 +508,9 @@ def test_paged_kernel_matches_gather_fallback():
 
     rng = np.random.default_rng(7)
     b, h, hkv, d, pool, page, n_pages = 4, 8, 2, 16, 11, 8, 4
-    q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(pool, page, hkv, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(pool, page, hkv, d)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(b, h, d)), dtype)
+    k = jnp.asarray(rng.normal(size=(pool, page, hkv * d)), dtype)
+    v = jnp.asarray(rng.normal(size=(pool, page, hkv * d)), dtype)
     tables = np.zeros((b, n_pages), np.int32)
     lengths = np.asarray([0, 7, 17, 30], np.int32)
     # Allocate only the pages each depth needs; the rest stay scratch.
@@ -489,9 +522,13 @@ def test_paged_kernel_matches_gather_fallback():
     out = paged_decode_attention(
         q, k, v, tables, lengths, interpret=True
     )
-    ref = paged_decode_attention_reference(q, k, v, tables, lengths)
+    with jax.default_matmul_precision("highest"):
+        ref = paged_decode_attention_reference(
+            *(x.astype(jnp.float32) for x in (q, k, v)), tables, lengths
+        )
     np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5
+        np.asarray(out.astype(jnp.float32)), np.asarray(ref),
+        rtol=tol, atol=tol,
     )
     # And through the engine's forward: the kernel path emits the same
     # tokens as the gather path for a real request.
