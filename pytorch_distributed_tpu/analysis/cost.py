@@ -479,8 +479,7 @@ def projected_tok_s(
 ) -> float:
     """Tokens/s the roofline projects for a decode-step program that
     advances ``tokens_per_step`` tokens per dispatch (active rows x
-    tokens-per-tick) — the number scripts/decode_bench.py prints next
-    to the measured rate so projection drift stays visible."""
+    tokens-per-tick). Never calibrated against a chip run."""
     proj = project_step_time(cost, spec, overlapped_comm=overlapped_comm)
     step = proj["projected_step_s"]
     return tokens_per_step / step if step > 0 else 0.0

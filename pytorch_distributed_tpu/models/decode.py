@@ -14,8 +14,7 @@ bucketed prompt compilation, and traced sampling scalars. The original
 one-jit monolithic programs survive as ``generate_monolithic`` /
 ``generate_tp_monolithic`` / ``generate_fsdp_monolithic``: the reference
 implementations the engine is pinned bit-equal against
-(tests/test_serving.py), and the "per-call path" leg of
-scripts/decode_bench.py.
+(tests/test_serving.py).
 
 Design (TPU-first):
 - The cache is a pytree of stacked per-layer tensors ``k/v [L, B, S, Hkv, D]``
@@ -894,7 +893,7 @@ def generate_monolithic(
     decode steps inside ONE jit. Returns [B, Tp + max_new_tokens].
 
     Kept as the reference the serving engine is pinned bit-equal against
-    and as decode_bench's "per-call path" leg. Sampling params are traced
+    (tests/test_serving.py). Sampling params are traced
     (a config sweep reuses one compiled program — the compile key is only
     (shapes, cfg, max_new_tokens, max_len, greedy-vs-sampled)); the KV
     cache is jit-internal, re-allocated and re-zeroed every call — the

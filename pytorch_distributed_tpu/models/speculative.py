@@ -23,8 +23,7 @@ routes through that engine path for dense configs. This module keeps:
 
 Speculative decoding amortises the per-step HBM cost of autoregressive
 generation: batched-1 decode is bandwidth-bound (every step streams the
-full parameter set for ONE matmul row — benchmarks/PERF_NOTES.md "Decode
-throughput"), so verifying K draft tokens in one forward costs barely
+full parameter set for ONE matmul row), so verifying K draft tokens in one forward costs barely
 more than generating one token, and every accepted draft is a step's
 worth of weight traffic saved. The classic scheme drafts with a smaller
 model; prompt-lookup drafting (the HF ``prompt_lookup_num_tokens``

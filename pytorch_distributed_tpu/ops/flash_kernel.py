@@ -1,8 +1,8 @@
 """Hand-tiled Pallas TPU flash attention: fwd + fused one-pass backward.
 
 This replaces the library kernel (jax.experimental.pallas.ops.tpu.
-flash_attention) on the hot path. Three structural wins, all measured on
-GPT-2 124M B=8 T=1024 (see benchmarks/PERF_NOTES.md):
+flash_attention) on the hot path. Three structural differences (what the
+kernels cost today in cell 1 is ``flash_roofline.train`` in PERF.md):
 
 - **One-pass backward.** The library runs two backward kernels (dkv, then
   dq), each re-computing the score matrix from scratch — 7 block-level

@@ -10,10 +10,6 @@ two backends behind one entry point:
   (ops/flash_kernel.py) — K/V resident in VMEM, online softmax, compact
   [B, H, T] logsumexp residual, fused one-pass backward producing
   dq/dk/dv together. Used automatically on TPU when shapes are tileable.
-  (The jax library kernel it replaced is kept importable below as
-  ``_pallas_flash_olm`` for A/B measurement; it was ~2x slower in
-  backward — two passes re-computing scores — and its lane-broadcast
-  [B, H, T, 128] l/m stats cost ~100 MB/layer of remat save traffic.)
 - ``blockwise``: a pure-XLA `lax.scan` over key blocks with the same
   online-softmax recurrence — O(T · block) memory, differentiable by
   ordinary AD. The portable fallback (CPU tests, ragged shapes).
@@ -113,61 +109,6 @@ def flash_attention(
     return blockwise_attention(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k
     )
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _pallas_flash_olm(q, k, v, causal, sm_scale, block_sizes):
-    """Flash attention whose PRIMAL returns (o, l, m) — output plus the
-    softmax statistics the backward kernels need.
-
-    Exposing l/m as primal outputs (instead of hiding them inside the
-    library custom_vjp's forward re-run) lets a remat policy save them:
-    with (o, l, m) saved and q/k/v recomputable from the saved qkv
-    projection, the backward pass runs ONLY the dq/dkv kernels — no
-    second forward kernel launch. Measured ~5 ms/step on GPT-2 124M B=8.
-    """
-    import jax.experimental.pallas.ops.tpu.flash_attention as _lib
-
-    o, l, m = _lib._flash_attention_impl(
-        q, k, v, None, None, True, causal, sm_scale,
-        block_sizes.block_b, block_sizes.block_q,
-        block_sizes.block_k_major, block_sizes.block_k, False,
-    )
-    return o, l, m
-
-
-def _pallas_flash_olm_fwd(q, k, v, causal, sm_scale, block_sizes):
-    o, l, m = _pallas_flash_olm(q, k, v, causal, sm_scale, block_sizes)
-    return (o, l, m), (q, k, v, o, l, m)
-
-
-def _pallas_flash_olm_bwd(causal, sm_scale, block_sizes, res, cts):
-    import jax.experimental.pallas.ops.tpu.flash_attention as _lib
-
-    q, k, v, o, l, m = res
-    do = cts[0]  # l/m are consumed by nothing differentiable: zero cotangents
-    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-    dk, dv = _lib._flash_attention_bwd_dkv(
-        q, k, v, None, None, l, m, do, di,
-        block_q_major=block_sizes.block_q_major_dkv,
-        block_k_major=block_sizes.block_k_major_dkv,
-        block_k=block_sizes.block_k_dkv,
-        block_q=block_sizes.block_q_dkv,
-        sm_scale=sm_scale, causal=causal,
-        mask_value=_lib.DEFAULT_MASK_VALUE, debug=False,
-    )
-    dq, _ = _lib._flash_attention_bwd_dq(
-        q, k, v, None, None, l, m, do, di,
-        block_q_major=block_sizes.block_q_dq,
-        block_k_major=block_sizes.block_k_major_dq,
-        block_k=block_sizes.block_k_dq,
-        sm_scale=sm_scale, causal=causal,
-        mask_value=_lib.DEFAULT_MASK_VALUE, debug=False,
-    )
-    return dq, dk, dv
-
-
-_pallas_flash_olm.defvjp(_pallas_flash_olm_fwd, _pallas_flash_olm_bwd)
 
 
 def _pallas_flash(q, k, v, *, causal: bool) -> jax.Array:

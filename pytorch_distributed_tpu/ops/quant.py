@@ -39,7 +39,7 @@ by where its bytes sit:
 
 Quality is CONTRACTUAL, not anecdotal: ``relative_logit_mse`` and
 ``token_match_rate`` are the two pinned metrics (``Q8_QUALITY`` carries
-the budgets the tests and ``decode_bench --kv-quant int8`` assert), and
+the budgets tests/test_serving_quant.py asserts), and
 the dtype-leak audit grows a q8 cast budget
 (analysis/audit.check_q8_casts) so a silent f32 round-trip — an extra
 quantize or dequantize beyond the declared sites — fails the audit
@@ -53,9 +53,9 @@ import jax.numpy as jnp
 import numpy as np
 
 # Pinned quality budgets for the int8 serving path, asserted by
-# tests/test_serving_quant.py and scripts/decode_bench.py --kv-quant
-# int8 (the CI smoke FAILS on breach — the budget is a contract the way
-# the bit-equivalence pins are, not a printed observation).
+# tests/test_serving_quant.py::test_quality_budget_held_teacher_forced
+# (tier-1 FAILS on breach — the budget is a contract the way the
+# bit-equivalence pins are, not a printed observation).
 #
 # The pinned token metric is TEACHER-FORCED greedy agreement
 # (``argmax_agreement`` over both engines' logits for IDENTICAL

@@ -55,7 +55,7 @@ def _contains_pallas_call(jaxpr, depth: int = 0) -> bool:
 
 def _flash_call_policy(prim, *_args, **params) -> bool:
     """Save all outputs of the Pallas flash-attention custom_vjp call —
-    (o, l, m), see ops/pallas_flash._pallas_flash_olm. With those saved (and
+    (o, lse), see ops/flash_kernel.flash_mha. With those saved (and
     q/k/v derivable from the saved qkv projection) the backward pass skips
     the forward kernel re-run entirely. Identified structurally: the only
     custom_vjp whose body is a pallas_call inside our models is flash."""
@@ -74,19 +74,19 @@ _POLICIES = {
     "dots_no_batch": jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
     # Save exactly the tagged projection outputs (recommended: avoids saving
     # the quadratic attention-score dot that "dots" keeps) plus the flash
-    # kernel's (o, l, m) so backward launches only the dq/dkv kernels.
+    # kernel's (o, lse) so backward launches only the fused gradient kernel.
     "names": jax.checkpoint_policies.save_from_both_policies(
         jax.checkpoint_policies.save_only_these_names(
             *SAVED_ACTIVATION_NAMES
         ),
         _flash_call_policy,
     ),
-    # Save ONLY the flash kernel's (o, l, m): removes the O(T^2)
+    # Save ONLY the flash kernel's (o, lse): removes the O(T^2)
     # forward-kernel re-run from backward while keeping every linear-in-T
     # projection save OFF — the long-context policy for regimes where the
     # per-layer gate/up saves are what OOM HBM (llama3-1B T=8192 fits
     # with this or "full"; "names"/"dots" exceed the chip — measured
-    # round 5, benchmarks/PERF_NOTES.md).
+    # in round 5, on older code).
     "flash": _flash_call_policy,
 }
 

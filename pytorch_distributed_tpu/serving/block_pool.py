@@ -127,7 +127,7 @@ class BlockPool:
 
     def pages_in_use(self) -> int:
         """Pages referenced by at least one live row (the working set —
-        what ``decode_bench`` reports as cache HBM actually in use)."""
+        ``cache_hbm_bytes()["peak_in_use"]`` is its high-water mark)."""
         return sum(1 for r in self._ref.values() if r > 0)
 
     def pages_resident(self) -> int:

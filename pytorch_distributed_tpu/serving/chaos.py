@@ -33,7 +33,7 @@ Injection points (the full catalog — docs/ROBUSTNESS.md):
 
 Faults come scripted (``Fault(tick=...)`` — exact, for tests) and/or
 seeded (per-tick Bernoulli draws from one ``numpy`` generator — for the
-soak and the chaos bench leg); both compose. Every firing is counted in
+storm tests); both compose. Every firing is counted in
 ``injector.counts`` so a run can assert its fault schedule actually
 fired (a chaos test that injected nothing is coverage theater).
 """

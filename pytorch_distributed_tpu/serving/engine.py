@@ -1064,9 +1064,9 @@ class BatchedDecodeEngine:
     the ground truth, drafts only change speed. Sampled rows ride the
     same program with zero drafts (their lane-0 draw bit-matches the
     plain step; exact sampled speculation needs rejection-sampling
-    corrections — out of scope). When drafting LOSES — low-repetition
-    streams pay the (K+1)-wide verify for ~0 accepts — see
-    benchmarks/PERF_NOTES.md.
+    corrections — out of scope). Drafting LOSES on low-repetition
+    streams: they pay the (K+1)-wide verify for ~0 accepts (by how much
+    is not measured on the chip).
 
     Not thread-safe (single dispatcher per engine); requests are
     single-sequence (one row each — batch your own beams as separate
@@ -1093,7 +1093,7 @@ class BatchedDecodeEngine:
     ``restore()`` on a rebuilt engine after device loss re-prefills
     every in-flight request and continues token-identically. The
     deterministic fault-injection harness (serving/chaos.py) drives all
-    of these paths in tests and scripts/soak.py.
+    of these paths in tests/test_chaos.py.
     """
 
     # The donated cache's positional index in each program signature.
@@ -1892,8 +1892,9 @@ class BatchedDecodeEngine:
         sharding, but every steady-state dispatch presents the
         donated-OUTPUT sharding instead — which can hash differently,
         so the first shape recompiled once mid-traffic (observed on TP;
-        regression-pinned by the zero-steady-compile assertions in
-        decode_bench --serving-spec and tests). Re-dispatching that one
+        regression-pinned by the zero-steady-compile assertion of
+        tests/test_serving_spec.py::test_spec_tp_matches_plain_tp).
+        Re-dispatching that one
         shape with the laundered cache keys the warm set exactly as
         serving will hit it."""
         if self.mode == "plain":
@@ -3095,9 +3096,8 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
 
     def cache_hbm_bytes(self) -> dict[str, int]:
         """Allocated pool HBM + the peak actually referenced by live
-        rows (pages_in_use x page_size positions) — the numbers
-        ``decode_bench --serving-paged`` reports against the dense
-        engine's slots x max_len."""
+        rows (pages_in_use x page_size positions), to set against the
+        dense engine's slots x max_len."""
         per = self._bytes_per_position()
         return {
             "allocated": self.pool_pages * self.page_size * per,

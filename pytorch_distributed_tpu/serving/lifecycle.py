@@ -24,7 +24,8 @@ The state machine (docs/ROBUSTNESS.md draws it):
 Non-terminal states (QUEUED/ACTIVE) are engine-internal — observable via
 ``queued_rids()`` / ``active_rids()`` — and a request may bounce
 ACTIVE -> QUEUED any number of times through the fault-resume path; the
-invariant the soak asserts is that every rid reaches exactly ONE terminal
+invariant the storm (tests/test_chaos.py::test_storm_invariants_hold)
+asserts is that every rid reaches exactly ONE terminal
 result, and a terminal rid never reappears.
 
 The paged engine (``PagedBatchedDecodeEngine``) adds one more

@@ -140,8 +140,9 @@ class ReplicaRouter:
     - ``parallel_step``: step busy replicas concurrently (one host
       thread per replica) instead of round-robin. With per-replica
       device placement (``MeshConfig.device_ids`` / engine ``device=``)
-      the replicas' XLA dispatches overlap on disjoint device slices —
-      the wall-clock win scripts/loadgen.py measures. Engine ticks stay
+      the replicas' XLA dispatches overlap on disjoint device slices
+      (not measured on the chip; tests/test_router.py's
+      ``paged2_pinned`` storm runs it). Engine ticks stay
       single-threaded per engine; all router bookkeeping (health,
       delivery, failover, handoffs) runs serially after the joins, so
       determinism contracts are untouched. Default False: virtual-clock

@@ -1,16 +1,15 @@
 """Seeded serving workloads: ONE arrival-stream generator for every
 consumer.
 
-Before this module, three near-copies of "seeded Poisson-ish mixed
-traffic" lived in ``scripts/soak.py`` and the ``decode_bench``
-serving legs — and they had already drifted on the details that decide
-whether two runs are comparable: one drew a per-request key as
+Three near-copies of "seeded Poisson-ish mixed traffic" once drifted
+on the details that decide whether two runs are comparable: one drew a per-request key as
 ``jax.random.key(base + i)``, another as ``fold_in(key(base), i)``, a
 third shared ONE key across every sampled request. A robustness claim
 ("DONE outputs bit-equal to a fault-free run of the same schedule") is
 only meaningful when "the same schedule" is a single function of the
-seed, so the generator lives here and the soak, the bench legs, the
-router load generator, and the tests all consume it.
+seed, so the generator lives here and the storms of
+tests/test_chaos.py and tests/test_router.py, the serving tests and
+``perfbench/`` all consume it.
 
 Conventions (the points the copies drifted on, now pinned):
 
@@ -243,7 +242,7 @@ def session_stream(
     """The seeded multi-turn chat schedule: ``n_sessions`` scripts of
     ``turns`` turn dicts each. A turn dict is ``{"tail": [t] int32
     tokens, "max_new_tokens": n, <sampling kwargs>}`` — the driver
-    (bench leg, soak, tests) submits ``concat(recorded transcript,
+    (a test) submits ``concat(recorded transcript,
     tail)`` as the turn's prompt, which is exactly the
     conversation-so-far-plus-new-message shape ``submit(session=)``
     validates. Turn 1's tail draws ``open_len`` tokens, later turns
@@ -296,7 +295,7 @@ def tick_bursts(
     rng: np.random.Generator, max_per_tick: int, length: int = 997
 ) -> list[int]:
     """Seeded per-tick arrival burst sizes (0..max_per_tick inclusive)
-    for tick-driven drivers (the soak): bursty, seed-reproducible churn
+    for tick-driven drivers (the storm tests): bursty, seed-reproducible churn
     without a wall clock. A long prime-length cycle avoids resonating
     with the scheduler's own periodicities."""
     return [int(rng.integers(0, max_per_tick + 1)) for _ in range(length)]

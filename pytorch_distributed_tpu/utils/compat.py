@@ -1,74 +1,41 @@
-"""Version shims for the varying-manual-axes (vma) shard_map surface.
+"""The varying-manual-axes (vma) shard_map surface, under the names the
+codebase imports.
 
-The codebase is written against the typed shard_map of recent jax:
-``jax.typeof`` exposing ``aval.vma``, ``jax.lax.pcast``/``pvary`` to mark
-constants varying, and ``shard_map(..., check_vma=True)`` verifying
-replication invariants at trace time. On older jax (<= 0.4.x) none of
-that exists — the vma TYPE SYSTEM itself is absent — so these shims
-degrade to the untyped semantics those versions ship: ``pcast``/``pvary``
-become identity (there is no varying-ness to record), ``typeof`` falls
-back to ``jax.core.get_aval`` (whose avals carry no ``.vma``, so callers'
-``getattr(..., "vma", frozenset())`` defaults engage), and ``shard_map``
-maps ``check_vma=True`` onto ``check_rep=False`` — the old replication
-CHECKER must be off because it predates the typed-psum patterns this repo
-writes (hand-psums of values it would infer replicated).
-
-On new jax every shim is a straight pass-through, so behavior there is
-identical to calling the real APIs.
+The project's floor is jax 0.9 (pyproject.toml), where the typed shard_map
+is the only one: ``jax.typeof`` exposes ``aval.vma``, ``jax.lax.pcast``
+marks a value varying, and ``jax.shard_map(..., check_vma=True)`` verifies
+replication invariants at trace time. Each name here is a straight call to
+that API; what they add is ``vma_of`` and the empty-axes identity of
+``pcast_varying``.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # stable location since jax 0.6
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
 
 
 def typeof(x):
-    """``jax.typeof`` where available, else the aval (no ``.vma``)."""
-    if hasattr(jax, "typeof"):
-        return jax.typeof(x)
-    return jax.core.get_aval(x)
+    return jax.typeof(x)
 
 
 def vma_of(x) -> frozenset:
-    """Mesh axes ``x`` is typed varying over (empty on untyped jax)."""
-    return frozenset(getattr(typeof(x), "vma", frozenset()))
+    """Mesh axes ``x`` is typed varying over."""
+    return frozenset(typeof(x).vma)
 
 
 def pcast_varying(x, axes):
-    """Cast ``x`` varying over ``axes`` (identity when empty or untyped)."""
+    """Cast ``x`` varying over ``axes`` (identity when empty)."""
     axes = tuple(axes)
     if not axes:
         return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):  # pragma: no cover - mid-era jax
-        return jax.lax.pvary(x, axes)
-    return x
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """shard_map accepting ``check_vma`` on every jax version."""
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        return _shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    return _shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=check_vma,
     )

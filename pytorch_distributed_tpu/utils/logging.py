@@ -35,12 +35,12 @@ def log_event(
     event: str, *, logger: logging.Logger | None = None, **fields
 ) -> None:
     """One structured lifecycle line: ``event=<name> key=value ...`` with
-    keys sorted and Nones dropped, so serving-engine incidents (soak
+    keys sorted and Nones dropped, so serving-engine incidents (storm
     failures, chaos runs) are diagnosable — and greppable — from the log
     alone. Emitted at DEBUG on the ``pdtpu.serving`` child logger:
     lifecycle events are per-request bookkeeping, not operator output;
-    enable with ``get_logger("pdtpu.serving").setLevel(logging.DEBUG)``
-    (scripts/soak.py tees them to a file). Host-side only — never call
+    enable with ``get_logger("pdtpu.serving").setLevel(logging.DEBUG)``.
+    Host-side only — never call
     from traced code (repolint's host-sync rule would flag the formatting
     anyway)."""
     lg = logger or get_logger("pdtpu.serving")

@@ -114,8 +114,8 @@ def add_common_args(p: argparse.ArgumentParser, *, preset: str) -> None:
                    help="activation-checkpoint policy (default names = save "
                         "tagged projection outputs; the measured optimum is "
                         "length-dependent — dots at T=1024 for llama, names "
-                        "at T=4096, flash (only the kernel's o/l/m) at "
-                        "T=8192 — see benchmarks/PERF_NOTES.md)")
+                        "at T=4096, flash (only the kernel's o/lse) at "
+                        "T=8192 — August chip runs on older code)")
     p.add_argument("--no-profiler", action="store_true")
     p.add_argument("--trace-dir", default=None)
     p.add_argument("--cpu-devices", type=int, default=0,

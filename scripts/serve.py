@@ -140,7 +140,7 @@ def main() -> int:
 
     def make_engine(rep_id: int):
         # One process drives every visible chip: replica i lives on
-        # device i mod n (as scripts/loadgen.py places them).
+        # device i mod n.
         device = devices[rep_id % len(devices)]
         if args.dense:
             return BatchedDecodeEngine(

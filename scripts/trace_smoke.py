@@ -6,11 +6,11 @@ only ever tested on synthetic hand-built JSON. This script proves them on the
 real thing: it trains a few GPT-2 steps under the ScheduledProfiler on the
 current accelerator, runs the analysis, asserts the breakdown finds device
 ops with nonzero compute, and writes the result to
-``benchmarks/trace_smoke.json`` (the committed artifact).
+``chiprun_out/trace_smoke.json`` (the directory a chip call brings back).
 
 CPU note: jax's CPU traces carry no device-op tracks at all (verified), so
 this validation is only meaningful on TPU — anywhere else the script fails
-and leaves the committed artifact alone. Run: ``python scripts/trace_smoke.py``.
+and writes nothing. Run: ``python scripts/trace_smoke.py``.
 """
 
 from __future__ import annotations
@@ -49,7 +49,7 @@ def main() -> int:
     )
     from pytorch_distributed_tpu.train.trainer import Trainer
 
-    outpath = REPO / "benchmarks" / "trace_smoke.json"
+    outpath = REPO / "chiprun_out" / "trace_smoke.json"
     outpath.parent.mkdir(exist_ok=True)
 
     cfg = model_config("gpt2", dtype="bfloat16").replace(

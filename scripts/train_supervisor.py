@@ -25,8 +25,7 @@ function of --seed: the storm replays exactly.
 
 Usage:
   python scripts/train_supervisor.py --seed 0                # the storm
-  python scripts/train_supervisor.py --soak --json \\
-      benchmarks/train_chaos_bench.json                      # bench leg
+  python scripts/train_supervisor.py --soak --json OUT.json  # bench leg
   python scripts/train_supervisor.py --soak --dryrun         # CI smoke
 """
 

@@ -27,7 +27,12 @@ REPO = Path(__file__).resolve().parent.parent
 
 
 ENTRY_POINTS = ("chip_smoke.py", "bench.py", "scripts/trace_smoke.py")
-ARTIFACT = REPO / "benchmarks" / "trace_smoke.json"
+ARTIFACT = REPO / "chiprun_out" / "trace_smoke.json"
+
+
+def _artifact_bytes():
+    """The trace artifact's bytes, None where no chip call has written one."""
+    return ARTIFACT.read_bytes() if ARTIFACT.exists() else None
 
 _PLACE_CACHE = (
     f"import sys; sys.path.insert(0, {str(REPO)!r})\n"
@@ -45,9 +50,9 @@ def child_runs(tmp_path_factory):
     jax import): the three chip entry points held to the CPU, and the
     cache helper in two processes with two working directories, neither
     held to a platform nor handed a cache directory. Returns
-    {name: (returncode, stdout, stderr)} and the artifact's bytes from
-    before."""
-    before = ARTIFACT.read_bytes()
+    {name: (returncode, stdout, stderr)} and the trace artifact's bytes
+    from before."""
+    before = _artifact_bytes()
     on_cpu = {**os.environ, "JAX_PLATFORMS": "cpu"}
     bare = {
         k: v for k, v in os.environ.items()
@@ -77,14 +82,14 @@ def child_runs(tmp_path_factory):
 
 @pytest.mark.parametrize("name", ENTRY_POINTS)
 def test_chip_entry_points_fail_on_cpu(child_runs, name):
-    """Exit non-zero under JAX_PLATFORMS=cpu, print no result, and leave
-    the committed trace artifact alone."""
+    """Exit non-zero under JAX_PLATFORMS=cpu, print no result, and write
+    no trace artifact (nor touch one a chip call brought back)."""
     runs, artifact_before = child_runs
     returncode, out, err = runs[name]
     assert returncode != 0, (out, err)
     assert '"ok"' not in out and '"metric"' not in out, out
     assert "cpu" in err, err  # says what it found instead
-    assert ARTIFACT.read_bytes() == artifact_before
+    assert _artifact_bytes() == artifact_before
 
 
 # -- compile cache -----------------------------------------------------------

@@ -1,14 +1,9 @@
-"""utils/compat.py on BOTH jax API eras, via monkeypatch simulation.
+"""utils/compat.py: each name is the jax 0.9 API it stands for.
 
-The shims are the foundation vma-check's results get compared against:
-on pre-vma jax they degrade to untyped semantics (identity pcast, no
-``.vma``, ``check_vma=True`` -> ``check_rep=False``); on post-vma jax
-they are straight pass-throughs. CI only ever runs ONE jax, so each
-test simulates the OTHER era's surface with monkeypatching — both shim
-branches are exercised regardless of the rig's jax version.
+The floor is jax 0.9 (pyproject.toml), so there is one era to test: the
+monkeypatched tests pin WHICH jax call each shim makes and with what
+arguments; the last one runs a real checked program.
 """
-
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +20,7 @@ class _FakeVmaAval:
 # ------------------------------------------------------------- typeof/vma_of
 
 def test_typeof_prefers_jax_typeof_when_present(monkeypatch):
-    """Post-vma surface: jax.typeof exists and wins over get_aval."""
+    """typeof is jax.typeof, and vma_of reads its ``.vma``."""
     calls = []
 
     def fake_typeof(x):
@@ -36,17 +31,6 @@ def test_typeof_prefers_jax_typeof_when_present(monkeypatch):
     t = compat.typeof(jnp.ones(()))
     assert calls and t.vma == {"data"}
     assert compat.vma_of(jnp.ones(())) == frozenset({"data"})
-
-
-def test_typeof_falls_back_to_get_aval_without_jax_typeof(monkeypatch):
-    """Pre-vma surface: no jax.typeof -> aval with no .vma, so vma_of
-    degrades to the empty set callers default on."""
-    monkeypatch.delattr(jax, "typeof", raising=False)
-    x = jnp.ones((2,))
-    aval = compat.typeof(x)
-    assert tuple(aval.shape) == (2,)
-    assert not hasattr(aval, "vma")
-    assert compat.vma_of(x) == frozenset()
 
 
 # ------------------------------------------------------------- pcast_varying
@@ -69,60 +53,16 @@ def test_pcast_varying_uses_pcast_on_new_jax(monkeypatch):
     assert recorded == {"axes": ("data", "fsdp"), "to": "varying"}
 
 
-def test_pcast_varying_uses_pvary_on_mid_era_jax(monkeypatch):
-    """Mid-era jax shipped pvary before pcast; the shim must prefer pcast
-    but fall back to pvary."""
-    recorded = {}
-    monkeypatch.delattr(jax.lax, "pcast", raising=False)
-    monkeypatch.setattr(
-        jax.lax, "pvary",
-        lambda x, axes: recorded.update(axes=axes) or x,
-        raising=False,
-    )
-    assert compat.pcast_varying(jnp.ones(()), ("seq",)) is not None
-    assert recorded == {"axes": ("seq",)}
-
-
-def test_pcast_varying_is_identity_on_pre_vma_jax(monkeypatch):
-    monkeypatch.delattr(jax.lax, "pcast", raising=False)
-    monkeypatch.delattr(jax.lax, "pvary", raising=False)
-    x = jnp.ones((3,))
-    assert compat.pcast_varying(x, ("data",)) is x
-
-
 # ----------------------------------------------------------------- shard_map
 
-def _capture_shard_map(monkeypatch, params):
-    """Install a fake underlying shard_map with the given signature
-    parameters; returns the dict its kwargs are captured into."""
+def test_shard_map_passes_check_vma_through_on_new_jax(monkeypatch):
     captured = {}
-    sig_params = [
-        inspect.Parameter("f", inspect.Parameter.POSITIONAL_OR_KEYWORD)
-    ] + [
-        inspect.Parameter(
-            name, inspect.Parameter.KEYWORD_ONLY, default=None
-        )
-        for name in params
-    ]
 
     def fake(f, **kwargs):
         captured.update(kwargs)
         return f
 
-    fake.__signature__ = inspect.Signature(sig_params)
-    monkeypatch.setattr(compat, "_shard_map", fake)
-    monkeypatch.setattr(
-        compat, "_SHARD_MAP_PARAMS",
-        frozenset(inspect.signature(fake).parameters),
-    )
-    return captured
-
-
-def test_shard_map_passes_check_vma_through_on_new_jax(monkeypatch):
-    captured = _capture_shard_map(
-        monkeypatch,
-        ["mesh", "in_specs", "out_specs", "check_vma"],
-    )
+    monkeypatch.setattr(jax, "shard_map", fake)
     fn = compat.shard_map(
         lambda x: x, mesh="M", in_specs="I", out_specs="O", check_vma=True
     )
@@ -132,25 +72,9 @@ def test_shard_map_passes_check_vma_through_on_new_jax(monkeypatch):
     }
 
 
-def test_shard_map_degrades_check_vma_to_unchecked_on_old_jax(monkeypatch):
-    """Pre-vma surface: check_vma is unknown; the shim must map it onto
-    check_rep=False — the old replication checker predates the typed-psum
-    patterns this repo writes, so it must be OFF (vma-check is the
-    version-independent replacement; analysis/vma_check.py)."""
-    captured = _capture_shard_map(
-        monkeypatch,
-        ["mesh", "in_specs", "out_specs", "check_rep"],
-    )
-    compat.shard_map(
-        lambda x: x, mesh="M", in_specs="I", out_specs="O", check_vma=True
-    )
-    assert captured["check_rep"] is False
-    assert "check_vma" not in captured
-
-
 def test_shard_map_real_rig_builds_a_runnable_program(eight_devices):
-    """End-to-end on whatever jax the rig ships: the shimmed shard_map
-    with check_vma=True must trace AND run a psum program."""
+    """End-to-end: compat.shard_map with check_vma=True must trace AND
+    run a psum program."""
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 

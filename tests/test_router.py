@@ -23,7 +23,7 @@ free to storm it:
 5. the log — a storm run is diagnosable from the router's JSONL event
    vocabulary alone.
 
-The full replica-storm matrix rides the slow tier; the shared workload
+The replica-storm matrix closes the file; the shared workload
 generator (serving/workload.py) is pinned deterministic here because
 every "same schedule" claim in the suite leans on it.
 """
@@ -404,7 +404,6 @@ def test_replica_kill_failover_bit_identity():
     assert router.steady_compiles()[1] == 0
 
 
-@pytest.mark.slow
 def test_dispatch_failure_takes_replica_down():
     """A replica whose engine exhausts dispatch_retries (DispatchFailure
     from step) is replica death at the router tier: survivors adopt the
@@ -436,7 +435,6 @@ def test_dispatch_failure_takes_replica_down():
     assert router.steady_compiles()[1] == 0
 
 
-@pytest.mark.slow
 def test_total_fleet_loss_parks_and_recovers():
     """Killing EVERY replica parks in-flight work as orphans (no data
     loss) and sheds new submissions; one restart re-adopts the orphans
@@ -469,7 +467,6 @@ def test_total_fleet_loss_parks_and_recovers():
 # -- drain / restart -------------------------------------------------------
 
 
-@pytest.mark.slow
 def test_drain_restart_rides_snapshot_restore():
     """Planned drain: the replica's in-flight requests pause as a held
     snapshot, restart restores them, and they finish bit-identically —
@@ -502,7 +499,6 @@ def test_drain_restart_rides_snapshot_restore():
     assert router.counters["drains"] == 1
 
 
-@pytest.mark.slow
 def test_kill_after_drain_neither_loses_nor_duplicates():
     """A DRAINED replica dying before its restart: the held snapshot is
     written off, the still-live host state redistributes — every rid
@@ -541,7 +537,6 @@ def test_kill_after_drain_neither_loses_nor_duplicates():
         assert np.array_equal(np.asarray(res.tokens), ref[idx])
 
 
-@pytest.mark.slow
 def test_abort_on_drained_replica_not_resurrected():
     """Aborting a request parked in a drain snapshot must scrub it from
     the held snapshot too — otherwise restart resurrects (and re-runs)
@@ -571,7 +566,6 @@ def test_abort_on_drained_replica_not_resurrected():
         assert res.state == ("ABORTED" if rid == victim else DONE)
 
 
-@pytest.mark.slow
 def test_drain_migrate_hands_work_to_survivors():
     cfg = _cfg()
     params = _params(cfg)
@@ -643,7 +637,6 @@ def test_slow_replica_degrades_and_recovers():
 # -- the router log --------------------------------------------------------
 
 
-@pytest.mark.slow
 def test_router_log_vocabulary():
     """A storm incident is diagnosable from the JSONL event log alone:
     route/shed/replica_down/failover/drain/replica_up events carry rid
@@ -701,72 +694,127 @@ def test_router_log_vocabulary():
     )
 
 
-# -- slow tier: the replica storm matrix -----------------------------------
+# -- the replica storm matrix ----------------------------------------------
 
 
-@pytest.mark.slow
-def test_router_replica_storm_matrix():
-    """The full storm: seeded kills + restarts + per-replica dispatch
-    faults + bursty arrivals over a 3-replica fleet. Invariants: every
-    rid reaches exactly one terminal state, DONE outputs bit-identical
-    to the fault-free reference, zero steady compiles on never-killed
-    replicas, and the storm actually fired."""
-    cfg = _cfg()
-    params = _params(cfg)
-    n_req = 48
-    reqs = _reqs(n_req, seed=5)
-    ref = _reference_outputs(cfg, params, reqs)
-
-    clock = VirtualClock()
-    factory = _make_engine_factory(cfg, clock, slots=2)
-    router = ReplicaRouter(
-        factory, 3, clock=clock, shed_queue_depth=16,
-    )
-    router.warmup(params)
-    storm = RouterFaultInjector(
-        faults=[RouterFault(tick=4, kind="replica_kill")],
-        seed=9, p_replica_kill=0.02,
-    ).install(router)
-    # Per-replica engine-level faults on one replica: transient dispatch
-    # errors the ENGINE recovers (no replica death) — the router tier
-    # must compose with the engine tier's own resilience.
-    FaultInjector(
-        seed=10, p_dispatch_error=0.05, clock=clock
-    ).install(router._replicas[1].engine)
-
-    rng = np.random.default_rng(123)
-    bursts = tick_bursts(rng, 2)
+def _storm_drive(router, params, reqs, bursts, *, restart_after,
+                 max_ticks=3000):
+    """Tick-driven closed loop: seeded arrival bursts; a shed arrival
+    (``RouterOverloaded``) re-offers on a later tick, FIFO preserved;
+    a DOWN replica restarts ``restart_after`` ticks after it was seen
+    down. Returns ({request index: result}, sheds caught)."""
     rids: dict[int, int] = {}
-    next_req = 0
-    tick = 0
+    next_req = tick = caught = 0
     restart_due: dict[int, int] = {}
-    max_ticks = 3000
-    while (next_req < n_req or router.has_work()) and tick < max_ticks:
+    while (next_req < len(reqs) or router.has_work()) and tick < max_ticks:
         tick += 1
         for rep_id, due in list(restart_due.items()):
             if tick >= due:
                 del restart_due[rep_id]
                 router.restart(rep_id, params)
-        n_new = min(bursts[tick % len(bursts)], n_req - next_req)
+        n_new = min(bursts[tick % len(bursts)], len(reqs) - next_req)
         for _ in range(n_new):
             try:
                 rids[router.submit(**reqs[next_req])] = next_req
                 next_req += 1
-            except RouterOverloaded:
-                break  # re-offer on a later tick (FIFO preserved)
+            except RouterOverloaded as err:
+                assert err.retry_after_s is not None
+                caught += 1
+                break
         if router.has_work():
             router.step(params)
         for rep_id, state in router.replica_states().items():
             if state == DOWN and rep_id not in restart_due:
-                restart_due[rep_id] = tick + 10
+                restart_due[rep_id] = tick + restart_after
     assert tick < max_ticks, "storm did not drain"
-    assert next_req == n_req
-    assert set(router.results) == set(rids)
+    assert next_req == len(reqs), "a shed request was never re-admitted"
+    assert set(router.results) == set(rids), "lost or phantom rids"
+    return {idx: router.pop_result(rid) for rid, idx in rids.items()}, caught
+
+
+@pytest.mark.parametrize("fleet", ["dense3", "paged2_pinned"])
+def test_router_replica_storm_matrix(fleet):
+    """The full storm: seeded kills + restarts + bursty arrivals.
+    Invariants: every rid reaches exactly one terminal state, a shed
+    arrival is re-admitted and counted once per rejection, DONE outputs
+    bit-identical to the fault-free reference, and the storm actually
+    fired.
+
+    - ``dense3``: 3 dense replicas on a ``VirtualClock``, one of them
+      also taking engine-level dispatch faults the ENGINE recovers (no
+      replica death) — the router tier composes with the engine tier's
+      own resilience. Reference: one fault-free engine.
+    - ``paged2_pinned``: 2 paged replicas, each pinned to its own device
+      and stepped on concurrent host threads (``parallel_step``) on the
+      wall clock, page pressure part of admission; two scripted kills
+      plus Bernoulli ones. Reference: the same fleet driven clean, which
+      must finish everything DONE with zero steady compiles.
+    """
+    cfg = _cfg()
+    params = _params(cfg)
+    bursts = tick_bursts(np.random.default_rng(123), 2)
+    if fleet == "dense3":
+        reqs = _reqs(48, seed=5)
+        ref = _reference_outputs(cfg, params, reqs)
+        clock = VirtualClock()
+        router = ReplicaRouter(
+            _make_engine_factory(cfg, clock, slots=2), 3, clock=clock,
+            shed_queue_depth=16,
+        )
+        router.warmup(params)
+        FaultInjector(
+            seed=10, p_dispatch_error=0.05, clock=clock
+        ).install(router._replicas[1].engine)
+        storm = RouterFaultInjector(
+            faults=[RouterFault(tick=4, kind="replica_kill")],
+            seed=9, p_replica_kill=0.02,
+        ).install(router)
+        restart_after = 10
+    else:
+        reqs = _reqs(12, seed=5)
+
+        def make_fleet():
+            def make_engine(rep_id):
+                return PagedBatchedDecodeEngine(
+                    cfg, slots=2, max_len=32, page_size=8,
+                    device=jax.devices()[rep_id],
+                    request_retries=8, retry_backoff_s=0.0,
+                )
+
+            fleet = ReplicaRouter(
+                make_engine, 2, parallel_step=True, shed_queue_depth=1,
+            )
+            fleet.warmup(params)
+            return fleet
+
+        clean = make_fleet()
+        clean_out, _ = _storm_drive(
+            clean, params, reqs, bursts, restart_after=15
+        )
+        assert all(r.state == DONE for r in clean_out.values())
+        assert not any(clean.steady_compiles().values())
+        ref = {i: np.asarray(r.tokens) for i, r in clean_out.items()}
+        router = make_fleet()
+        storm = RouterFaultInjector(
+            faults=[
+                RouterFault(tick=6, kind="replica_kill"),
+                RouterFault(tick=18, kind="replica_kill"),
+            ],
+            seed=31, p_replica_kill=0.03,
+        ).install(router)
+        restart_after = 15
+
+    out, caught = _storm_drive(
+        router, params, reqs, bursts, restart_after=restart_after
+    )
+    assert router.counters["shed"] == caught
+    if fleet == "paged2_pinned":
+        assert caught >= 1, "no arrival was shed: retry path not driven"
     assert storm.counts["replica_kill"] >= 1
-    for rid, idx in rids.items():
-        res = router.pop_result(rid)
-        assert res.state == DONE, (rid, res.state, res.reason)
+    assert router.counters["failovers"] >= 1
+    assert router.counters["restarts"] >= 1
+    for idx, res in out.items():
+        assert res.state == DONE, (idx, res.state, res.reason)
         assert np.array_equal(np.asarray(res.tokens), ref[idx]), (
             f"request {idx} diverged in the storm"
         )
-    assert router.counters["failovers"] >= 1

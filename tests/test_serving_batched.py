@@ -458,7 +458,9 @@ def test_busy_batch_tp_matches_serial(eight_devices, family, sampled):
         )
 
 
-@pytest.mark.slow
+# -- tier-1 again: row-granularity edges -----------------------------------
+
+
 def test_gqa_slot_reuse_no_stale_kv():
     """GQA edge at ROW granularity: a retired row's deep K/V (left dirty)
     must never surface through the head-repeat when a shorter request is
@@ -482,7 +484,6 @@ def test_gqa_slot_reuse_no_stale_kv():
     )
 
 
-@pytest.mark.slow
 def test_tp_collective_count_invariant_to_active_rows(eight_devices):
     """The registry contract, exercised end to end: after serving wildly
     different active-row patterns, the TP engine still holds exactly ONE

@@ -173,8 +173,7 @@ def test_disagg_stream_deterministic_and_index_independent():
 
 def test_stats_role_and_device_ids_uniform():
     """Every engine reports ``role`` and ``device_ids`` — the router's
-    role pins and the loadgen placement report read them without
-    hasattr probing. ``device=`` pinning shows up as the pinned id."""
+    role pins read them without hasattr probing. ``device=`` pinning shows up as the pinned id."""
     cfg = _cfg()
     serial = DecodeEngine(cfg, max_len=24)
     dense = BatchedDecodeEngine(
@@ -448,6 +447,7 @@ def test_role_reassignment_churn_zero_compiles():
     got = [list(np.asarray(router.pop_result(r).tokens)) for r in rids]
     assert got == ref
     assert router.counters["handoffs"] == 0  # colocated: none needed
+    assert all(v == 0 for v in router.steady_compiles().values())
     # Reassign: 0 -> prefill, 1 -> decode.
     roles.update({0: "prefill", 1: "decode"})
     router.kill(0, reason="role reassignment")

@@ -637,7 +637,9 @@ def test_paged_tp_matches_dense_tp(eight_devices, family, sampled):
         )
 
 
-@pytest.mark.slow
+# -- tier-1 again: chunked prefill beside decode ---------------------------
+
+
 def test_long_prompt_chunked_prefill_does_not_stall_neighbours():
     """Chunked prefill interleaves with decode: while a long admission
     trickles in chunk by chunk, an in-flight row keeps generating every

@@ -8,8 +8,7 @@ wrong ask:
 
 1. quality is contractual — teacher-forced greedy agreement and
    relative logit MSE between the quantized and f32 paths hold the
-   pinned ``ops.quant.Q8_QUALITY`` budgets on a seeded stream (the
-   in-process twin of the ``decode_bench --kv-quant int8`` assertion).
+   pinned ``ops.quant.Q8_QUALITY`` budgets on a seeded stream.
 2. zero-recompile churn, strict donation (now FOUR pool leaves — int8
    values + f32 scales), and the kernel-vs-gather token equality all
    survive quantization.
@@ -17,10 +16,8 @@ wrong ask:
    on-append is a pure per-token function, so dispatch-failure resume,
    snapshot/replay and preemption re-prefills reproduce bit-identical
    pages (each pinned against an undisturbed int8 run); NaN quarantine
-   still bypasses the prefix cache. Tier-1 keeps the dispatch-failure
-   case (the one that additionally exercises the pool+prefix-cache
-   reset); the rest of the fault matrix rides the slow tier with the
-   composition matrices (the PR-1 budget split).
+   still bypasses the prefix cache. All four ride tier-1; the llama
+   quality twin and the TP composition ride the slow tier.
 4. router capacity scoring uses EFFECTIVE pages: a quantized replica
    provisioned at byte-equal HBM holds ~3.2x the f32 pages and must
    NOT be starved-excluded while it still has page headroom (the
@@ -138,8 +135,7 @@ def _quality_metrics(family):
 
 def test_quality_budget_held_teacher_forced():
     """The pinned quality contract, engine-shaped: both Q8_QUALITY
-    budgets hold on a seeded served stream. This is the in-process twin
-    of the decode_bench --kv-quant assertion — a lost scale or a
+    budgets hold on a seeded served stream — a lost scale or a
     silently-f32 page moves these metrics by orders of magnitude
     (llama/GQA twin on the slow tier)."""
     agree, mse = _quality_metrics("gpt2")
@@ -154,7 +150,6 @@ def test_quality_budget_held_teacher_forced_llama():
     assert mse <= Q8_QUALITY["max_relative_logit_mse"], mse
 
 
-@pytest.mark.slow
 def test_quantized_stream_serves_done_and_close_to_f32():
     """End-to-end: the quantized engine serves the f32 engine's stream
     to DONE with outputs that stay close (first generated token — one
@@ -240,7 +235,6 @@ def test_quantized_donation_aliases_all_four_pool_leaves(audit):
         )
 
 
-@pytest.mark.slow
 def test_quantized_kernel_matches_gather_through_engine():
     """GQA head grouping of scales through BOTH attention backends: the
     int8 Pallas kernel (interpret) and the int8 gather fallback emit
@@ -258,8 +252,7 @@ def test_quantized_kernel_matches_gather_through_engine():
 
 def test_quant_rejection_diagnostics():
     """The unsupported compositions reject loudly at construction —
-    cheap host-side checks, so they stay tier-1 while the engine-run
-    matrix rides the slow tier."""
+    cheap host-side checks."""
     cfg = _cfg()
     with pytest.raises(ValueError, match="weight_quant"):
         DecodeEngine(cfg, max_len=32, weight_quant="int4")
@@ -280,7 +273,6 @@ def test_quant_rejection_diagnostics():
         decode.init_paged_cache(cfg, 4, 8, kv_quant="fp8")
 
 
-@pytest.mark.slow
 def test_weight_quant_on_serial_and_batched_engines():
     """Weight-only int8 rides every engine (quantized once per params
     tree — the identity memo)."""
@@ -341,7 +333,6 @@ def test_dispatch_failure_resets_pool_and_resumes_token_identical_q8():
         )
 
 
-@pytest.mark.slow
 def test_snapshot_replay_token_identical_q8():
     cfg = _cfg()
     params = _params(cfg)
@@ -366,7 +357,6 @@ def test_snapshot_replay_token_identical_q8():
         )
 
 
-@pytest.mark.slow
 def test_quarantine_bypasses_prefix_cache_q8():
     from pytorch_distributed_tpu.serving.chaos import Fault, FaultInjector
 
@@ -391,7 +381,6 @@ def test_quarantine_bypasses_prefix_cache_q8():
     np.testing.assert_array_equal(out[rid].tokens, ref)
 
 
-@pytest.mark.slow
 def test_preemption_resume_token_identical_q8():
     """Pool exhaustion preempts and the re-prefill re-QUANTIZES the
     prefix into fresh pages bit-identically — preemption under int8 is

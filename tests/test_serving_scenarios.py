@@ -161,7 +161,6 @@ def test_unknown_priority_rejected_at_engine_and_router():
 
 # -- tiered admission -------------------------------------------------------
 
-@pytest.mark.slow
 def test_interactive_bypasses_queue_head_and_batch_waits():
     """One slot, a standard row active, then batch/standard/interactive
     queued in that order: the interactive arrival PREEMPTS the active
@@ -309,15 +308,16 @@ def test_batch_preempted_before_interactive_regardless_of_age():
         )
 
 
-@pytest.mark.slow
 def test_interactive_arrival_preempts_batch_for_its_slot():
     """Admission-side preemption: with every slot busy, an INTERACTIVE
     arrival takes the lowest-priority row's slot immediately (the
     ``preempt_priority`` counter + log event) instead of queueing
-    behind it; the preempted batch row resumes and completes."""
+    behind it; the preempted batch row resumes and completes. Tiered
+    admission and preemption add no compiles to a warmed engine."""
     cfg = _cfg()
     params = _params(cfg)
     eng = _paged(cfg, slots=2, pool_pages=40)
+    n_warm = eng.warmup(params)
     r_b = eng.submit(_prompt(4, 1), 10, priority="batch")
     r_s = eng.submit(_prompt(4, 2), 10)
     eng.step(params)
@@ -343,9 +343,9 @@ def test_interactive_arrival_preempts_batch_for_its_slot():
     assert all(
         out[r].state == "DONE" for r in (r_s2, r_s3, r_s4, r_s5)
     )
+    assert eng.compile_count() == n_warm
 
 
-@pytest.mark.slow
 def test_standard_arrival_does_not_preempt_batch():
     """Only INTERACTIVE preempts at admission (the scheduler.py tier
     contract — STANDARD is exactly PR-8's behaviour): with every slot
@@ -375,7 +375,6 @@ def _run_turn(eng, params, sid, prompt, max_new, **kw):
     return out[rid].tokens
 
 
-@pytest.mark.slow
 def test_session_turns_hit_prefix_cache_and_match_one_shot():
     """Three greedy turns: every turn's full token sequence is
     BIT-EQUAL the same prompt served one-shot on a fresh engine (cached
@@ -603,7 +602,6 @@ def test_session_pins_break_before_allocation_deadlocks():
     assert eng.counters["preemptions"] == 0
 
 
-@pytest.mark.slow
 def test_queued_session_turns_not_stalled_by_unallocatable_head():
     """Anti-livelock pin: a queue head too large for the unpinned pool
     while every pinned session has a QUEUED turn (in-flight pins are
@@ -770,7 +768,6 @@ def test_lora_guards():
         router.submit(_prompt(4, 1), 2, tenant="ghost")
 
 
-@pytest.mark.slow
 def test_tenant_registration_zero_new_compiles():
     """Registering a tenant changes operand VALUES, never shapes: a
     warmed engine serves a brand-new tenant with zero new compiles."""
@@ -843,7 +840,6 @@ def test_stats_schema_has_tier_and_session_fields():
     assert "session_evictions" in snaps[2]["counters"]
 
 
-@pytest.mark.slow
 def test_router_counts_pinned_pages_as_unavailable():
     """The scoring regression pin: two otherwise-idle paged replicas,
     one holding a session's pinned pages — new traffic routes to the
@@ -871,7 +867,6 @@ def test_router_counts_pinned_pages_as_unavailable():
     )
 
 
-@pytest.mark.slow
 def test_session_turns_route_sticky_and_rehome_on_kill():
     """Session stickiness: every turn lands on the replica holding the
     pinned pages; killing that replica re-homes the session to the
@@ -903,7 +898,6 @@ def test_session_turns_route_sticky_and_rehome_on_kill():
         router.close_session(sid)
 
 
-@pytest.mark.slow
 def test_session_survives_replica_restart():
     """restart() replaces the replica's engine, so engine sids recorded
     before the kill are stale; the router re-homes every session still
@@ -937,7 +931,6 @@ def test_session_survives_replica_restart():
     assert router.pop_result(rid3).state == "DONE"
 
 
-@pytest.mark.slow
 def test_session_turns_respect_shed_thresholds():
     """Sticky session turns cannot spill to another replica, but the
     SLO gate still applies: a turn submitted while the holder is past
@@ -971,7 +964,6 @@ def test_session_turns_respect_shed_thresholds():
 
 # -- HTTP surface -----------------------------------------------------------
 
-@pytest.mark.slow
 def test_http_scenario_surface():
     """The wire tier: session open/turn/close, priority + tenant kwargs
     through POST /v1/generate, and every guard as a 4xx with the
@@ -1112,7 +1104,9 @@ def test_tenant_bit_equality_tp(eight_devices, family):
     )
 
 
-@pytest.mark.slow
+# -- tier-1 again: the seeded session stream -------------------------------
+
+
 def test_session_stream_end_to_end_hit_rate():
     """The seeded multi-turn stream (workload.session_stream) driven
     round-robin across concurrent sessions: every turn DONE, aggregate

@@ -8,7 +8,8 @@ Battery:
 
 1. spec-vs-plain token equality on busy mixed batches (greedy +
    sampled rows): dense engine, paged engine (f32 and int8 pages),
-   TP on the slow tier — with accepts asserted > 0 so the pins are
+   TP (the paged gpt2 pair in tier-1, the rest of the matrix on the
+   slow tier) — with accepts asserted > 0 so the pins are
    never vacuous.
 2. tail-page rollback never dirties shared/pinned prefix pages (the
    COW pin extended to speculation): the cached pages' device bytes
@@ -331,7 +332,6 @@ def test_spec_dispatch_failure_resumes_token_identical(cfgp, spec_clean):
     _assert_equal_runs(spec_clean, out)
 
 
-@pytest.mark.slow
 def test_spec_snapshot_replay_token_identical(cfgp, spec_clean):
     """snapshot() mid-speculation + restore() onto a rebuilt engine:
     the continuation re-prefills from committed tokens only (rejected
@@ -399,14 +399,17 @@ def test_spec_stats_schema_uniform_and_sampled_rows_draft_nothing(cfgp):
     assert st["spec_accept_rate"] is None  # no drafts -> no rate
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("family", ["gpt2", "llama"])
-@pytest.mark.parametrize("paged", [False, True])
+@pytest.mark.parametrize("paged,family", [
+    (True, "gpt2"),  # tier-1: the paged gpt2 pair
+    pytest.param(True, "llama", marks=pytest.mark.slow),
+    pytest.param(False, "gpt2", marks=pytest.mark.slow),
+    pytest.param(False, "llama", marks=pytest.mark.slow),
+])
 def test_spec_tp_matches_plain_tp(eight_devices, family, paged):
     """TP speculation: the k+1-wide shard_map verify step (head-sharded
     cache, Megatron psums, all-reduce=2 pinned in the registry) is
-    token-equal to the plain TP engine — both families, dense and
-    paged."""
+    token-equal to the plain TP engine with zero steady-state compiles
+    — both families, dense and paged."""
     cfg = _cfg(family)
     params = _params(cfg)
     # tensor=2: llama's kv_heads=2 bounds the shard count (the same
@@ -416,12 +419,13 @@ def test_spec_tp_matches_plain_tp(eight_devices, family, paged):
     reqs = _mixed_requests()
     out_p = mk(cfg, mesh_cfg=mesh).run(params, reqs)
     spec = mk(cfg, spec=4, mesh_cfg=mesh)
+    warm = spec.warmup(params)
     out_s = spec.run(params, reqs)
     _assert_equal_runs(out_p, out_s)
     assert spec.counters["accepted_tokens"] > 0
+    assert spec.compile_count() == warm
 
 
-@pytest.mark.slow
 def test_spec_matches_serial_speculative_reference():
     """The engine path vs the retired-to-reference monolithic loop
     (models/speculative.py): same greedy output for a single request —
