@@ -16,7 +16,9 @@ bf16) with random weights made from a seed:
            mixed greedy/sampled HTTP requests, one over SSE.
 - kernels: the Pallas paged-attention decode kernel COMPILED (never
            interpreted) for bf16 and int8 pages at the gpt2 and llama3-1b
-           head geometries against the XLA reference, then the serve
+           head geometries against the XLA reference, and the latent
+           family's decode kernel at Kimi-K2.5's widths (64 heads, pages
+           of 64 x 640 bf16) against the gathered window; then the serve
            requests again through an engine built with
            ``paged_attention="auto"``.
 - four_chips (only when the machine shows >= 4 devices):
@@ -486,6 +488,60 @@ def paged_case(rng, sizes: Sizes, h: int, hkv: int, quantized: bool):
     return q, k, v, jnp.asarray(tables), jnp.asarray(lengths), scales
 
 
+def latent_kernel_case(rehearsal: bool):
+    """ops/latent_paged_kernel.py against models/kimi_k2.attend_window on a
+    ragged batch at the kimi-k2.5-ep32 cell's shapes (64 heads, 640 lanes,
+    pages of 64, tables of 64 pages, blocks of 8; toy shapes in rehearsal):
+    depth 0, either side of a page and of a block boundary, mid-depth rows,
+    the deepest the table allows, a free row; layer 1 of a stacked pool,
+    pages scattered. Returns max |kernel - f32 gather|."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_distributed_tpu.models.kimi_k2 import attend_window
+    from pytorch_distributed_tpu.ops.latent_paged_kernel import (
+        latent_paged_decode,
+    )
+
+    h, w, c, page, n_pages, bp = (
+        (4, 128, 64, 8, 8, 2) if rehearsal else (64, 640, 512, 64, 64, 8)
+    )
+    block, max_pos = bp * page, n_pages * page - 1
+    depths = [0, page - 1, page, block - 1, block, max_pos // 3,
+              max_pos // 2, max_pos]
+    rng = np.random.default_rng(SEED)
+    b = len(depths) + 1  # the last row is free: depth 0, table all scratch
+    pool_pages = b * n_pages + 1
+    pool = jnp.asarray(
+        rng.normal(size=(2, pool_pages, page, w)), jnp.bfloat16)
+    q = jnp.asarray(0.3 * rng.normal(size=(b, h, w)), jnp.bfloat16)
+    free = rng.permutation(np.arange(1, pool_pages))
+    tables = np.zeros((b, n_pages), np.int32)
+    pos = np.zeros((b,), np.int32)
+    used = 0
+    for i, depth in enumerate(depths):
+        need = depth // page + 1
+        tables[i, :need] = free[used:used + need]
+        used += need
+        pos[i] = depth
+    tables, pos = jnp.asarray(tables), jnp.asarray(pos)
+    scale = 0.5 * w ** -0.5
+    out = latent_paged_decode(
+        q, pool, 1, tables, pos, scale=scale, out_width=c, block_pages=bp,
+        interpret=rehearsal,  # on the chip: compiled, always
+    )
+    with jax.default_matmul_precision("highest"):
+        ref = attend_window(
+            q[:, None].astype(jnp.float32), pool.astype(jnp.float32), 1,
+            tables, pos, scale,
+        )[:, 0, :, :c]
+    out = np.asarray(out.astype(jnp.float32))
+    assert out.shape == (b, h, c) and np.isfinite(out).all()
+    np.testing.assert_allclose(out, ref, rtol=0, atol=PAGED_KERNEL_ATOL)
+    return float(np.max(np.abs(out - np.asarray(ref))))
+
+
 def phase_kernels(params, cfg, sizes: Sizes, requests, served,
                   rehearsal: bool, device_label: str):
     import jax
@@ -530,6 +586,12 @@ def phase_kernels(params, cfg, sizes: Sizes, requests, served,
                 out, ref, rtol=0, atol=PAGED_KERNEL_ATOL
             )
 
+    err = latent_kernel_case(rehearsal)
+    print(
+        f"kernels: latent_paged_decode on ragged depths: max |kernel - f32 "
+        f"gathered window| = {err:.2e} (bound {PAGED_KERNEL_ATOL:g})"
+    )
+
     # The same requests through an engine that picks its own paged
     # attention: on the chip "auto" must mean the compiled kernel.
     results, (engine,) = serve_requests(
@@ -557,7 +619,7 @@ def phase_kernels(params, cfg, sizes: Sizes, requests, served,
         f"requests (online-softmax reorders the bf16 sum)"
     )
     print(
-        f"kernels: PASS ({2 * len(HEAD_GEOMETRIES)} kernel cases, engine "
+        f"kernels: PASS ({2 * len(HEAD_GEOMETRIES) + 1} kernel cases, engine "
         f"served every request)"
     )
 

@@ -614,7 +614,7 @@ def forward(
 
         logits, cache, aux = kimi_k2.forward(
             params, input_ids, cfg, cache, pos, block_tables,
-            live=live, logits_index=logits_index,
+            live=live, logits_index=logits_index, paged_impl=paged_impl,
         )
         return (logits, cache, aux) if return_aux else (logits, cache)
     if cfg.family == "gpt2":

@@ -39,8 +39,10 @@ whole lanes). It is read two ways, the same function of the same pages:
   positions reach and no deeper.
 - *absorbed* (a one-token call: decode): W_kvb per head = (W_uk, W_uv);
   q~ = q_n W_uk^T lives in the latent's space, score = s (q~ . c_kv +
-  q_r . k_r), o = (P c_kv) W_uv. The pages are read as they lie and never
-  expanded.
+  q_r . k_r), o = (P c_kv) W_uv. The pages are never expanded: on the
+  chip a Pallas kernel reads them where they lie, each row to its own
+  depth (ops/latent_paged_kernel.py); elsewhere every row's whole table
+  is gathered into a window first (``attend_window``).
 
 s = (Dn + Dr)^-1/2 * m^2, m = ``yarn_mscale(factor, mscale_all_dim)``.
 
@@ -253,13 +255,36 @@ def attend_expanded(q, pool, layer, tables, pos, wkv_b, cfg: ModelConfig):
     return jax.lax.map(one_row, (q, tables, pos))
 
 
-def attend_absorbed(q, pool, layer, tables, pos, wkv_b, cfg: ModelConfig):
-    """The same attention with W_kvb ABSORBED into the query and the output:
-    scores and the weighted sum are taken against the latent pages as they
-    lie ([B, S, C+Dr], one gather, shared by all heads). Returns
-    [B, T, H, Dv]."""
+def attend_window(q_lat, pool, layer, tables, pos, scale):
+    """q_lat [B, T, H, W] (queries in the latent's space) against the
+    GATHERED window of layer ``layer``: every row's whole table copied to
+    [B, S, W], whatever the row's depth, read for the scores and again for
+    the weighted sum. Returns the weighted latent [B, T, H, W]. The path
+    off the chip, and what ops/latent_paged_kernel.py is held to."""
     from pytorch_distributed_tpu.models.decode import gather_pages
 
+    t = q_lat.shape[1]
+    lat = gather_pages(pool, layer, tables).astype(q_lat.dtype)  # [B, S, W]
+    s = jnp.einsum(
+        "bthc,bsc->bhts", q_lat, lat, preferred_element_type=jnp.float32
+    ) * scale
+    qpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]  # [B, T]
+    kpos = jnp.arange(lat.shape[1], dtype=jnp.int32)
+    s = jnp.where(
+        kpos[None, None, None, :] <= qpos[:, None, :, None], s, -1e30
+    )
+    p = jax.nn.softmax(s, axis=-1).astype(q_lat.dtype)
+    return jnp.einsum("bhts,bsc->bthc", p, lat)
+
+
+def attend_absorbed(q, pool, layer, tables, pos, wkv_b, cfg: ModelConfig,
+                    paged_impl: str = "gather"):
+    """The same attention with W_kvb ABSORBED into the query and the output:
+    scores and the weighted sum are taken against the latent pages as they
+    lie, shared by all heads. ``paged_impl`` "gather" reads them through
+    ``attend_window``; "kernel" / "kernel_interpret" (one token a row) read
+    them in place, each row to its depth, through the Pallas kernel of
+    ops/latent_paged_kernel.py. Returns [B, T, H, Dv]."""
     b, t, h, _ = q.shape
     c, dn, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
     w = wkv_b.reshape(c, h, dn + dv).astype(q.dtype)
@@ -267,23 +292,27 @@ def attend_absorbed(q, pool, layer, tables, pos, wkv_b, cfg: ModelConfig):
         jnp.einsum("bthd,chd->bthc", q[..., :dn], w[..., :dn]), q[..., dn:],
         jnp.zeros((b, t, h, pool.shape[-1] - c - q.shape[-1] + dn), q.dtype),
     ], axis=-1)  # [B, T, H, page width]: the query in the latent's space
-    lat = gather_pages(pool, layer, tables).astype(q.dtype)  # [B, S, width]
-    s = jnp.einsum(
-        "bthc,bsc->bhts", q_lat, lat, preferred_element_type=jnp.float32
-    ) * softmax_scale(cfg)
-    qpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]  # [B, T]
-    kpos = jnp.arange(lat.shape[1], dtype=jnp.int32)
-    s = jnp.where(
-        kpos[None, None, None, :] <= qpos[:, None, :, None], s, -1e30
-    )
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    # the weighted latent, its rope tail unused: cheaper than a slice of
-    # the gathered pages
-    o_lat = jnp.einsum("bhts,bsc->bthc", p, lat)[..., :c]
+    if paged_impl == "gather" or t != 1:
+        # the weighted latent, its rope tail unused: cheaper than a slice
+        # of the gathered pages
+        o_lat = attend_window(
+            q_lat, pool, layer, tables, pos, softmax_scale(cfg))[..., :c]
+    else:
+        from pytorch_distributed_tpu.ops.latent_paged_kernel import (
+            latent_paged_decode,
+        )
+
+        o_lat = latent_paged_decode(
+            q_lat[:, 0], pool, layer, tables, pos,
+            scale=softmax_scale(cfg), out_width=c,
+            block_pages=_key_block_pages(tables.shape[1], pool.shape[2]),
+            interpret=paged_impl == "kernel_interpret",
+        )[:, None]
     return jnp.einsum("bthc,chd->bthd", o_lat, w[..., dn:])
 
 
-def _attention(x, ap, cache, layer, pos, tables, cfg: ModelConfig):
+def _attention(x, ap, cache, layer, pos, tables, cfg: ModelConfig,
+               paged_impl="gather"):
     from pytorch_distributed_tpu.models.decode import _write
 
     eps = cfg.layer_norm_epsilon
@@ -320,7 +349,8 @@ def _attention(x, ap, cache, layer, pos, tables, cfg: ModelConfig):
     pool = _write(cache[LATENT], layer, lat, pos, tables)
     if t == 1:
         with jax.named_scope("mla_decode"):
-            o = attend_absorbed(q, pool, layer, tables, pos, ap["wkv_b"], cfg)
+            o = attend_absorbed(
+                q, pool, layer, tables, pos, ap["wkv_b"], cfg, paged_impl)
     else:
         with jax.named_scope("mla_prefill"):
             o = attend_expanded(q, pool, layer, tables, pos, ap["wkv_b"], cfg)
@@ -338,14 +368,14 @@ AUX_COUNTS = ("moe_pairs_here", "moe_rows_computed", "moe_experts_hit")
 
 
 def _block(x, bp, cache, layer, pos, tables, live, cfg: ModelConfig,
-           experts=None):
+           experts=None, paged_impl="gather"):
     """One layer over rows x [g, T, E]: returns (x, cache, counts [3]);
     the counts are ``moe_dropless``'s, zero in a dense layer. ``experts``
     = (the expert stacks whole, this layer's index into them)."""
     eps = cfg.layer_norm_epsilon
     a, cache = _attention(
         rms_norm(x, bp["ln_attn"], eps=eps), bp["attn"], cache, layer, pos,
-        tables, cfg,
+        tables, cfg, paged_impl,
     )
     x = x + a
     m = rms_norm(x, bp["ln_mlp"], eps=eps)
@@ -387,14 +417,16 @@ def _rows_in_blocks(block, x, cache, pos, tables, live):
 
 
 def forward(params: Params, input_ids, cfg: ModelConfig, cache: dict, pos,
-            block_tables, *, live=None, logits_index=None):
+            block_tables, *, live=None, logits_index=None,
+            paged_impl="gather"):
     """T tokens a row at positions pos[b]..pos[b]+T-1 through both stacks
     against the paged latent pool. Returns (logits [B, T, V] — [B, 1, V],
     of position ``logits_index[b]`` of each row, where that is given —,
     cache, counts [3] int32 summed over the expert layers: pairs routed to
     experts held here, rows the expert products ran over, held experts
     hit). ``live`` [B, T] bool marks the entries that are tokens (padding
-    and free rows route nowhere and count nothing)."""
+    and free rows route nowhere and count nothing). ``paged_impl``: how a
+    one-token call reads the pool (``attend_absorbed``)."""
     b, t = input_ids.shape
     pos = jnp.asarray(pos, jnp.int32)
     if live is None:
@@ -418,7 +450,7 @@ def forward(params: Params, input_ids, cfg: ModelConfig, cache: dict, pos,
             x, cache, c = _rows_in_blocks(
                 lambda *rows: _block(
                     rows[0], bp, rows[1], first + local, *rows[2:], cfg,
-                    experts=(stacks, local),
+                    experts=(stacks, local), paged_impl=paged_impl,
                 ),
                 x, cache, pos, block_tables, live,
             )

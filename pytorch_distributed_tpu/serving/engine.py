@@ -2857,13 +2857,16 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
     model (quarantine, dispatch recovery, snapshot/replay) carry over
     unchanged — a failed dispatch consumed the donated POOL, so recovery
     additionally resets the block pool and prefix cache (the content the
-    cache keys pointed at is gone). Attention defaults to the pure-XLA
-    ``gather_pages`` fallback (bit-identical math to the dense engine —
-    the paged-vs-dense token-equality pins in
+    cache keys pointed at is gone). For the dense families attention
+    defaults to the pure-XLA ``gather_pages`` fallback (bit-identical
+    math to the dense engine — the paged-vs-dense token-equality pins in
     tests/test_serving_paged.py rely on it); on TPU,
     ``paged_attention="kernel"`` dispatches the Pallas paged-attention
     decode kernel (ops/paged_kernel.py), whose per-row cost scales with
-    the row's page count.
+    the row's page count. A family whose pool is a LATENT pool
+    (kimi_k2) defaults to ``"auto"``: its decode step reads the pool
+    through ops/latent_paged_kernel.py on a TPU and through the gathered
+    window elsewhere (``stats()["latent_decode_impl"]`` says which).
 
     Knobs: ``page_size`` (tokens per KV page; must divide ``max_len``),
     ``pool_pages`` (pool capacity incl. the reserved scratch page 0;
@@ -2909,7 +2912,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         page_size: int = 16,
         pool_pages: int | None = None,
         prefill_chunk: int | None = None,
-        paged_attention: str = "gather",
+        paged_attention: str | None = None,
         kv_quant: str = "none",
         mesh_cfg: MeshConfig | None = None,
         session_pin_budget_pages: int | None = None,
@@ -2970,6 +2973,15 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         from pytorch_distributed_tpu.serving.block_pool import BlockPool
 
         self.pool = BlockPool(self.pool_pages, self.page_size, self.chunk)
+        if paged_attention is None:
+            # Left unset, a LATENT pool is read by its kernel wherever one
+            # can run (models/kimi_k2.attend_absorbed: the gather copies
+            # every row's whole table a layer, whatever its depth); the
+            # dense families keep the gather, which is bit-identical to
+            # the dense engine's math.
+            paged_attention = (
+                "gather" if decode.has_dense_cache(cfg) else "auto"
+            )
         if paged_attention == "auto":
             paged_attention = (
                 "kernel" if jax.devices()[0].platform == "tpu"
@@ -2993,23 +3005,26 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         # back between the tokens and the sentinel; they accumulate here
         # per program kind beside the tokens each kind processed, and the
         # cache positions the decode program's rows reached. Only the two
-        # plain programs carry them: one device, unquantized pages, the
-        # gather path.
+        # plain programs carry them: one device, unquantized pages.
         self._aux_counts = decode.aux_counts(cfg)
         if self._aux_counts:
             if (self.mode != "plain" or self.kv_quant != "none"
                     or self.weight_quant != "none" or self.adapters
-                    or self.speculative_k or self._paged_impl != "gather"):
+                    or self.speculative_k):
                 raise NotImplementedError(
                     f"the {cfg.family} family is served on one device "
-                    "from unquantized latent pages by the gather path: no "
-                    "mesh, kv_quant, weight_quant, adapters, "
-                    "speculative_k or paged_attention kernel"
+                    "from unquantized latent pages: no mesh, kv_quant, "
+                    "weight_quant, adapters or speculative_k"
                 )
             for kind in ("prefill", "decode_step"):
                 for name in (*self._aux_counts, "moe_tokens"):
                     self.counters[f"{name}.{kind}"] = 0
+            # positions the decode program's rows reached, beside those a
+            # gathered window holds whatever their depth (every row's
+            # whole table): their ratio is the share of the window the
+            # kernel path does not touch
             self.counters["latent_positions_read"] = 0
+            self.counters["latent_positions_window"] = 0
         self.counters["preemptions"] = 0
         self.counters["preempt_priority"] = 0
         self.counters["batch_yield_ticks"] = 0
@@ -3143,6 +3158,9 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             evictions=ps["evictions"],
             kv_quant=self.kv_quant,
         )
+        if self._aux_counts:
+            # what the decode program reads the latent pool through
+            out["latent_decode_impl"] = self._paged_impl
         out["counters"]["session_evictions"] = self._sessions.evictions
         return out
 
@@ -3870,6 +3888,9 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 # each row's token at pos attends positions 0..pos
                 self.counters["latent_positions_read"] += sum(
                     s.pos + 1 for _, s in ready
+                )
+                self.counters["latent_positions_window"] += (
+                    self.slots * self.max_len
                 )
             for i, s in ready:
                 if bad[i]:

@@ -117,12 +117,21 @@ def test_chunked_prefill_then_decode_equals_reference_logits(params, expert_path
         jnp.concatenate(got, axis=1), want, atol=2e-6, rtol=0)
 
 
-def test_paged_engine_serves_the_reference_greedy_tokens(params, expert_path):
+@pytest.mark.parametrize("paged_attention", [None, "kernel_interpret"])
+def test_paged_engine_serves_the_reference_greedy_tokens(
+        params, expert_path, paged_attention, monkeypatch):
     """Through PagedBatchedDecodeEngine (admission, block pool, chunked
     prefill, sampler): more requests than rows, every reply the reference's
-    greedy continuation; the expert counters add up."""
+    greedy continuation; the expert counters add up. Left unset, off the
+    chip, ``paged_attention`` is the gathered window; "kernel_interpret"
+    reads the pool through ops/latent_paged_kernel.py (blocks of two pages,
+    so the deeper rows take several)."""
+    if paged_attention:
+        monkeypatch.setattr(kimi_k2, "KEY_BLOCK", 2 * PAGE)
     eng = PagedBatchedDecodeEngine(
-        CFG, slots=4, max_len=MAX_LEN, page_size=PAGE, prefill_chunk=8)
+        CFG, slots=4, max_len=MAX_LEN, page_size=PAGE, prefill_chunk=8,
+        paged_attention=paged_attention)
+    assert eng.stats()["latent_decode_impl"] == (paged_attention or "gather")
     eng.warmup(params)
     compiled = eng.compile_count()
     rng = np.random.default_rng(0)
@@ -148,15 +157,21 @@ def test_paged_engine_serves_the_reference_greedy_tokens(params, expert_path):
         # two expert layers, at most four picks a token
         assert c[f"moe_pairs_here.{kind}"] <= 2 * 4 * c[f"moe_tokens.{kind}"]
         assert 0 < c[f"moe_experts_hit.{kind}"]
-    assert c["latent_positions_read"] > 0
+    # a decode dispatch's window is every row's whole table, whatever is
+    # in it; what the rows reach is a part of it
+    assert 0 < c["latent_positions_read"] < c["latent_positions_window"]
+    assert c["latent_positions_window"] % (4 * MAX_LEN) == 0
     # the page: 16 + 8 numbers, stored in whole lanes, 4 bytes, 3 layers
     assert st["kv_bytes_per_position"] == 3 * 128 * 4
     assert eng.cache_hbm_bytes()["allocated"] == (
         eng.pool_pages * PAGE * st["kv_bytes_per_position"])
 
 
-def test_absorbed_equals_expanded_on_the_same_cache(params):
-    """One query token against 21 cached positions, both readings."""
+def test_absorbed_equals_expanded_on_the_same_cache(params, monkeypatch):
+    """One query token against 21 cached positions, all three readings:
+    expanded, absorbed through the gathered window, absorbed through the
+    kernel (blocks of two pages: three of them)."""
+    monkeypatch.setattr(kimi_k2, "KEY_BLOCK", 2 * PAGE)
     ids = prompts(2, 22, seed=3)
     tables = tables_for(2)
     pool = decode.init_paged_cache(CFG, 2 * (MAX_LEN // PAGE) + 1, PAGE)
@@ -168,8 +183,11 @@ def test_absorbed_equals_expanded_on_the_same_cache(params):
     pos = jnp.full((2,), 20)
     a = kimi_k2.attend_absorbed(q, pool["latent"], 1, tables, pos, wkv_b, CFG)
     e = kimi_k2.attend_expanded(q, pool["latent"], 1, tables, pos, wkv_b, CFG)
-    assert a.shape == e.shape == (2, 1, h, MODEL["v_head_dim"])
+    k = kimi_k2.attend_absorbed(
+        q, pool["latent"], 1, tables, pos, wkv_b, CFG, "kernel_interpret")
+    assert a.shape == e.shape == k.shape == (2, 1, h, MODEL["v_head_dim"])
     np.testing.assert_allclose(a, e, atol=2e-6, rtol=0)
+    np.testing.assert_allclose(k, e, atol=2e-6, rtol=0)
 
 
 def expert_layer_inputs(params, tokens=40):
@@ -321,5 +339,21 @@ def test_engines_refuse_what_they_cannot_serve(params):
     with pytest.raises(NotImplementedError, match="kv_quant"):
         PagedBatchedDecodeEngine(
             CFG, slots=2, max_len=MAX_LEN, page_size=PAGE, kv_quant="int8")
+    with pytest.raises(NotImplementedError, match="speculative_k"):
+        PagedBatchedDecodeEngine(
+            CFG, slots=2, max_len=MAX_LEN, page_size=PAGE, speculative_k=2)
+    # the kernel is served (it was refused until PR 33); unset, the option
+    # is the kernel on a TPU and the gathered window here, and the dense
+    # families' stays the gather everywhere
+    for asked, built in (("kernel", "kernel"), ("auto", "gather"),
+                         (None, "gather")):
+        eng = PagedBatchedDecodeEngine(
+            CFG, slots=2, max_len=MAX_LEN, page_size=PAGE,
+            paged_attention=asked)
+        assert eng.stats()["latent_decode_impl"] == built
+    dense = PagedBatchedDecodeEngine(
+        model_config("tiny"), slots=2, max_len=64, page_size=16)
+    assert dense._paged_impl == "gather"
+    assert "latent_decode_impl" not in dense.stats()
     with pytest.raises(KeyError, match="kimi-k2.5-ep32"):
         model_config("no-such-preset")
