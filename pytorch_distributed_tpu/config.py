@@ -28,7 +28,11 @@ class ModelConfig:
     # BASELINE.md configs 4-5 — or "kimi_k2" (the DeepSeek-V3 block as
     # Kimi-K2.5 publishes it: latent attention, leading dense layers, then
     # sigmoid-routed dropless experts beside a shared one, YaRN; served
-    # only — models/kimi_k2.py; its fields are at the end of this class).
+    # only — models/kimi_k2.py; its fields are at the end of this class) —
+    # or "granitemoehybrid" (Granite 4.0-H: Mamba-2 layers and a few
+    # attention layers in a repeating pattern, no position encoding, the
+    # four Granite multipliers; served only —
+    # models/granitemoehybrid.py; fields after kimi_k2's).
     family: str = "gpt2"
 
     vocab_size: int = 50257
@@ -156,13 +160,59 @@ class ModelConfig:
     experts_held: int = 0
     expert_offset: int = 0
 
+    # -- family "granitemoehybrid": the published config.json keys ---------
+    # (hidden_size, num_hidden_layers, num_attention_heads,
+    # num_key_value_heads, rms_norm_eps and vocab_size are n_embd, n_layer,
+    # n_head, n_kv_head, layer_norm_epsilon and vocab_size above.) Layer i
+    # mixes tokens by layer_types[i], "mamba" or "attention"; the pattern is
+    # one period repeated (models/granitemoehybrid.layer_period). A Mamba-2
+    # mixer has mamba_n_heads heads of mamba_d_head with a state of
+    # mamba_d_state per head entry, B and C shared by the heads of one of
+    # mamba_n_groups groups, a depthwise causal convolution of mamba_d_conv
+    # taps over [x | B | C], and is computed mamba_chunk_size tokens at a
+    # time. Every layer's feed-forward is one SwiGLU of
+    # shared_intermediate_size (num_local_experts is 0 where this is
+    # served). position_embedding_type "nope": no position encoding at all.
+    layer_types: tuple[str, ...] = ()
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    shared_intermediate_size: int = 0
+    position_embedding_type: str = "nope"
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+
     def __post_init__(self) -> None:
         if self.n_embd % self.n_head != 0:
             raise ValueError(
                 f"n_embd={self.n_embd} not divisible by n_head={self.n_head}"
             )
-        if self.family not in ("gpt2", "llama", "kimi_k2"):
+        if self.family not in ("gpt2", "llama", "kimi_k2",
+                               "granitemoehybrid"):
             raise ValueError(f"unknown model family: {self.family!r}")
+        if self.family == "granitemoehybrid":
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if not (
+                len(self.layer_types) == self.n_layer
+                and set(self.layer_types) <= {"mamba", "attention"}
+                and self.mamba_expand * self.n_embd
+                == self.mamba_n_heads * self.mamba_d_head
+                and self.mamba_n_heads % self.mamba_n_groups == 0
+                and self.position_embedding_type == "nope"
+            ):
+                raise ValueError(
+                    "granitemoehybrid: need one of 'mamba' / 'attention' "
+                    "for each of the n_layer layers, mamba_expand * n_embd "
+                    "== mamba_n_heads * mamba_d_head, whole groups of "
+                    "heads, and position_embedding_type 'nope' (rotary "
+                    "attention layers are not built)"
+                )
         if self.family == "kimi_k2":
             held = self.experts_held or self.n_routed_experts
             if not (
@@ -237,6 +287,12 @@ class ModelConfig:
             return ((8 * self.n_embd // 3) + 255) // 256 * 256
         return 4 * self.n_embd
 
+    @property
+    def layer_types_list(self) -> list[str]:
+        """``layer_types`` as the list a config.json holds (the field is a
+        tuple because the config is hashed)."""
+        return list(self.layer_types)
+
     def replace(self, **kw: Any) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
@@ -291,6 +347,25 @@ _KIMI_K2_PRESETS: dict[str, dict[str, Any]] = {
 }
 
 
+_GRANITEMOEHYBRID_PRESETS: dict[str, dict[str, Any]] = {
+    # granite-4.0-h-micro whole (https://huggingface.co/ibm-granite/
+    # granite-4.0-h-micro/blob/main/config.json): 40 layers, the period
+    # [mamba x5, attention, mamba x4] four times; nothing is cut
+    # (perfbench/configs/granite-4.0-h-micro.json).
+    "granite-4.0-h-micro": dict(
+        vocab_size=100352, n_ctx=131072, n_embd=2048, n_layer=40,
+        n_head=32, n_kv_head=8,
+        layer_types=(("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4,
+        mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+        mamba_n_groups=1, mamba_d_conv=4, mamba_expand=2,
+        mamba_chunk_size=256, shared_intermediate_size=8192,
+        position_embedding_type="nope", embedding_multiplier=12.0,
+        attention_multiplier=0.015625, residual_multiplier=0.22,
+        logits_scaling=8.0,
+    ),
+}
+
+
 def model_config(name: str, **overrides: Any) -> ModelConfig:
     """Look up a preset by name (the TPU-native analogue of
     ``AutoConfig.from_pretrained`` in reference train_baseline.py:24)."""
@@ -316,11 +391,22 @@ def model_config(name: str, **overrides: Any) -> ModelConfig:
             resid_pdrop=0.0,
             **_KIMI_K2_PRESETS[name],
         )
-    else:
-        raise KeyError(
-            f"unknown model preset {name!r}; known: "
-            f"{sorted(_GPT2_PRESETS) + sorted(_LLAMA_PRESETS) + sorted(_KIMI_K2_PRESETS)}"
+    elif name in _GRANITEMOEHYBRID_PRESETS:
+        base = dict(
+            family="granitemoehybrid",
+            activation_function="silu",
+            layer_norm_epsilon=1e-5,
+            embd_pdrop=0.0,
+            attn_pdrop=0.0,
+            resid_pdrop=0.0,
+            **_GRANITEMOEHYBRID_PRESETS[name],
         )
+    else:
+        known = [
+            *sorted(_GPT2_PRESETS), *sorted(_LLAMA_PRESETS),
+            *sorted(_KIMI_K2_PRESETS), *sorted(_GRANITEMOEHYBRID_PRESETS),
+        ]
+        raise KeyError(f"unknown model preset {name!r}; known: {known}")
     base.update(overrides)
     return ModelConfig(**base)
 

@@ -53,4 +53,12 @@ def get_model(cfg: ModelConfig) -> ModelApi:
             lambda params: (params["lm_head"], "ev"),
             kimi_k2.final_norm,
         )
+    if cfg.family == "granitemoehybrid":
+        from pytorch_distributed_tpu.models import granitemoehybrid as gmh
+
+        return ModelApi(
+            gmh.init, gmh.apply, gmh.embed, gmh.run_blocks, gmh.head,
+            lambda params: (params["wte"], "ve"),
+            gmh.final_norm,
+        )
     raise KeyError(f"unknown model family {cfg.family!r}")

@@ -51,8 +51,9 @@ No dropout (inference), no remat (nothing to save).
 from __future__ import annotations
 
 import functools
+import importlib
 from functools import partial
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -71,10 +72,46 @@ Params = dict[str, Any]
 Cache = dict[str, jax.Array]
 
 
-def has_dense_cache(cfg: ModelConfig) -> bool:
-    """False for a family that caches PAGES only (kimi_k2's latent pool):
-    ``init_cache`` has no [B, max_len] layout for it."""
-    return cfg.family != "kimi_k2"
+class Serving(NamedTuple):
+    """What a family asks of an engine, so that no engine reads a family's
+    name. A family that asks for more than the defaults (the dense
+    families': a cache of pages or of [B, max_len] rows, nothing else)
+    declares it in its own module's ``serving(cfg)``, these fields as a
+    dict; ``serving`` below looks it up."""
+
+    # ``init_cache`` has a [B, max_len] layout; False: the PAGED pool only
+    dense_cache: bool = True
+    # a page holds ONE latent all heads share, which a decode step reads
+    # through its own kernel on a TPU (models/kimi_k2.py)
+    latent_pool: bool = False
+    # Bytes of recurrent state a ROW holds whatever its depth, beside its
+    # pages. Non-zero means: ``init_paged_cache`` takes ``rows`` and returns
+    # the state leaves [L, rows + 1, ...] beside the pool; a prefill call
+    # names each batch row's state row (``forward(state_rows=)``); a row that
+    # starts at position 0 starts from zero state; a prefix cached by another
+    # row cannot be taken over (nobody holds the state at its end).
+    state_bytes_per_row: int = 0
+    # names, in order, of the int32 counts ``forward(return_aux=True)``
+    # hands back beside the logits, counted per program kind
+    aux_counts: tuple[str, ...] = ()
+    # the counters the family's readers ask for of what the ENGINE knows of
+    # a dispatch, by the counter's name
+    # (``PagedBatchedDecodeEngine._count_dispatch`` lists what it can count)
+    counters: tuple[str, ...] = ()
+    # what a paged engine cannot serve the family with, {feature: why}:
+    # "mesh", "kv_quant", "weight_quant", "adapters", "speculative_k",
+    # "handoff" (a prefill or decode worker's role, or a call to export or
+    # import a row), "paged_kernel" (``paged_attention`` other than the
+    # gather)
+    unserved: dict[str, str] = {}
+
+
+def serving(cfg: ModelConfig) -> Serving:
+    if cfg.family in ("kimi_k2", "granitemoehybrid"):
+        family = importlib.import_module(
+            f"pytorch_distributed_tpu.models.{cfg.family}")
+        return Serving(**family.serving(cfg))
+    return Serving()
 
 
 def kv_bytes_per_position(cfg: ModelConfig, kv_quant: str = "none") -> int:
@@ -93,17 +130,10 @@ def kv_bytes_per_position(cfg: ModelConfig, kv_quant: str = "none") -> int:
         from pytorch_distributed_tpu.models.kimi_k2 import page_width
 
         return cfg.n_layer * page_width(cfg) * itemsize
-    return cfg.n_layer * 2 * cfg.kv_heads * cfg.head_dim * itemsize
-
-
-def aux_counts(cfg: ModelConfig) -> tuple[str, ...]:
-    """Names, in order, of the int32 counts ``forward(return_aux=True)``
-    hands back beside the logits; () for a family that counts nothing."""
-    if cfg.family == "kimi_k2":
-        from pytorch_distributed_tpu.models.kimi_k2 import AUX_COUNTS
-
-        return AUX_COUNTS
-    return ()
+    layers = cfg.n_layer
+    if cfg.family == "granitemoehybrid":  # its attention layers only
+        layers = cfg.layer_types.count("attention")
+    return layers * 2 * cfg.kv_heads * cfg.head_dim * itemsize
 
 
 def init_cache(
@@ -115,12 +145,19 @@ def init_cache(
     each shard caches only its LOCAL kv heads (1/tp of the HBM)."""
     if max_len > cfg.n_ctx:
         raise ValueError(f"max_len {max_len} exceeds n_ctx {cfg.n_ctx}")
-    if not has_dense_cache(cfg):
+    if cfg.family == "kimi_k2":
         raise NotImplementedError(
             "the kimi_k2 family caches a LATENT page pool "
             "(init_paged_cache -> models/kimi_k2.init_latent_pool): serve "
             "it through PagedBatchedDecodeEngine; there is no dense "
             "[B, max_len] cache layout for it"
+        )
+    if not serving(cfg).dense_cache:
+        raise NotImplementedError(
+            f"the {cfg.family} family keeps per-row recurrent state beside "
+            "a paged pool (init_paged_cache(rows=)): serve it through "
+            "PagedBatchedDecodeEngine; there is no dense [B, max_len] "
+            "cache layout for it"
         )
     dtype = jnp.dtype(dtype or cfg.dtype)
     shape = (
@@ -132,6 +169,7 @@ def init_cache(
 def init_paged_cache(
     cfg: ModelConfig, pool_pages: int, page_size: int, dtype=None,
     n_kv: int | None = None, kv_quant: str = "none",
+    rows: int | None = None,
 ) -> Cache:
     """Preallocate a PAGED [L, pool_pages, page_size, Hkv*D] key/value
     pool pair (serving/block_pool.py owns the host-side allocation; page
@@ -145,7 +183,13 @@ def init_paged_cache(
     ride alongside — one symmetric scale per written token per KV head
     (ops/quant.py: per-token granularity is what keeps incremental page
     writes sound), cutting a page's bytes to ~(D + 4)/(4D) of the f32
-    pool."""
+    pool.
+
+    ``rows``: for a family that keeps recurrent state a row
+    (``serving(cfg).state_bytes_per_row``), how many rows the engine runs;
+    the state
+    leaves [L, rows + 1, ...] come back beside the pool (row ``rows`` is
+    the scratch row, as page 0 is the scratch page)."""
     if kv_quant not in ("none", "int8"):
         raise ValueError(
             f"kv_quant must be 'none' or 'int8', got {kv_quant!r}"
@@ -161,6 +205,17 @@ def init_paged_cache(
         from pytorch_distributed_tpu.models.kimi_k2 import init_latent_pool
 
         return init_latent_pool(cfg, pool_pages, page_size, dtype)
+    if cfg.family == "granitemoehybrid":
+        if kv_quant != "none" or n_kv is not None or rows is None:
+            raise NotImplementedError(
+                "granitemoehybrid's cache is an unquantized, unsharded pool "
+                "over its attention layers beside per-row state: say how "
+                "many rows (rows=), and neither kv_quant nor n_kv"
+            )
+        from pytorch_distributed_tpu.models import granitemoehybrid
+
+        return granitemoehybrid.init_cache(
+            cfg, pool_pages, page_size, rows, dtype)
     dtype = jnp.dtype(dtype or cfg.dtype)
     hkv = n_kv or cfg.kv_heads
     shape = (cfg.n_layer, pool_pages, page_size, hkv * cfg.head_dim)
@@ -196,7 +251,7 @@ def gather_pages(pool: jax.Array, layer, block_tables: jax.Array):
 
 
 def _cached_attention(q, cache, layer, pos, block_tables=None,
-                      paged_impl="gather", kv_quant="none"):
+                      paged_impl="gather", kv_quant="none", scale=None):
     """q [B, T, H, D] against layer ``layer`` of the stacked cache
     ({"k", "v"} leaves [L, B, S, Hkv, D]); queries sit at
     global positions pos..pos+T-1, keys j are valid iff j <= pos + i.
@@ -219,7 +274,10 @@ def _cached_attention(q, cache, layer, pos, block_tables=None,
     gathered view (one int8->f32 convert per K and V — the audit's q8
     cast budget counts them) and runs the identical masked math, the
     kernel path dequantizes page blocks in VMEM (dequant-in-kernel —
-    HBM only ever moves int8 pages + scales)."""
+    HBM only ever moves int8 pages + scales).
+
+    ``scale`` multiplies the scores in place of D^-1/2 (a family whose
+    attention is scaled by a published multiplier; gather path only)."""
     if block_tables is not None and q.shape[1] == 1 and (
         paged_impl in ("kernel", "kernel_interpret")
     ):
@@ -257,21 +315,29 @@ def _cached_attention(q, cache, layer, pos, block_tables=None,
     else:
         ck, cv = cache["k"][layer], cache["v"][layer]
     s, hkv = ck.shape[1], ck.shape[2]
-    if hkv != h:
-        rep = h // hkv
-        ck = jnp.repeat(ck, rep, axis=2)
-        cv = jnp.repeat(cv, rep, axis=2)
+    # Grouped-query heads: query head i reads K/V head i // (H/Hkv). The
+    # queries are grouped under their K/V head instead of K and V being
+    # repeated per query head: a repeated [B, S, H, D] view is written out
+    # whole (in float32, for K) and read back in every layer.
+    grouped = hkv != h
+    if grouped:
+        q = q.reshape(b, t, hkv, h // hkv, d)
     scores = jnp.einsum(
-        "bthd,bshd->bhts", q, ck, preferred_element_type=jnp.float32
-    ) / (d**0.5)
+        "btgrd,bsgd->bgrts" if grouped else "bthd,bshd->bhts", q, ck,
+        preferred_element_type=jnp.float32,
+    )
+    scores = scores / (d**0.5) if scale is None else scores * scale
     qpos = jax.lax.broadcasted_iota(jnp.int32, (t, s), 0)
     kpos = jax.lax.broadcasted_iota(jnp.int32, (t, s), 1)
     if getattr(pos, "ndim", 0):  # per-row positions -> [B, 1, T, S] mask
         valid = kpos[None] <= pos[:, None, None] + qpos[None]
-        scores = jnp.where(valid[:, None], scores, -1e30)
+        valid = valid[:, None, None] if grouped else valid[:, None]
+        scores = jnp.where(valid, scores, -1e30)
     else:
         scores = jnp.where(kpos <= pos + qpos, scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
+    if grouped:
+        return jnp.einsum("bgrts,bsgd->btgrd", w, cv).reshape(b, t, h, d)
     return jnp.einsum("bhts,bshd->bthd", w, cv)
 
 
@@ -519,6 +585,7 @@ def forward(
     live: jax.Array | None = None,
     logits_index: jax.Array | None = None,
     return_aux: bool = False,
+    state_rows: jax.Array | None = None,
 ) -> tuple[jax.Array, Cache]:
     """Run T tokens at positions pos..pos+T-1. Returns ([B, T, V] logits,
     updated cache). MoE configs route each token through the expert MLPs
@@ -568,7 +635,11 @@ def forward(
     tokens, for a family whose layers count their work (padding and free
     rows then route nowhere and count nothing); the others ignore it.
     ``return_aux``: return (logits, cache, aux) with aux the [n] int32
-    counts ``aux_counts(cfg)`` names, summed over the layers.
+    counts ``serving(cfg).aux_counts`` names, summed over the layers.
+    ``state_rows`` [B]: for a family with per-row recurrent state
+    (``serving(cfg).state_bytes_per_row``), the state row of each batch row;
+    left out,
+    batch row b is state row b (the decode step's lanes).
     """
     b, t = input_ids.shape
     dtype = jnp.dtype(cfg.dtype)
@@ -615,6 +686,23 @@ def forward(
         logits, cache, aux = kimi_k2.forward(
             params, input_ids, cfg, cache, pos, block_tables,
             live=live, logits_index=logits_index, paged_impl=paged_impl,
+        )
+        return (logits, cache, aux) if return_aux else (logits, cache)
+    if cfg.family == "granitemoehybrid":
+        if (block_tables is None or tensor_axis is not None
+                or block_transform is not None or lora is not None
+                or kv_quant != "none" or paged_impl != "gather"):
+            raise NotImplementedError(
+                "the granitemoehybrid family runs on the paged pool and "
+                "the per-row state of one device (block_tables given; no "
+                "tensor axis, ZeRO-3 transform, LoRA, quantized pages or "
+                "paged-attention kernel): models/granitemoehybrid.forward"
+            )
+        from pytorch_distributed_tpu.models import granitemoehybrid
+
+        logits, cache, aux = granitemoehybrid.forward(
+            params, input_ids, cfg, cache, pos, block_tables,
+            state_rows=state_rows, live=live, logits_index=logits_index,
         )
         return (logits, cache, aux) if return_aux else (logits, cache)
     if cfg.family == "gpt2":

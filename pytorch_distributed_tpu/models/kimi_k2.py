@@ -367,6 +367,29 @@ EXPERT_STACKS = ("w_gate", "w_in", "w_out")  # [Le, held, ...], never sliced
 AUX_COUNTS = ("moe_pairs_here", "moe_rows_computed", "moe_experts_hit")
 
 
+def serving(cfg: ModelConfig) -> dict:
+    """What an engine has to know of the family (``decode.Serving``)."""
+    why = (
+        "the kimi_k2 family is served on one device from unquantized "
+        "latent pages: no mesh, kv_quant, weight_quant, adapters or "
+        "speculative_k"
+    )
+    return dict(
+        dense_cache=False,
+        latent_pool=True,
+        aux_counts=AUX_COUNTS,
+        # the tokens each program kind processed; the positions a decode
+        # dispatch's rows reach, beside those a gathered window holds
+        # whatever their depth (their ratio is the share of the window the
+        # kernel path touches)
+        counters=("moe_tokens.prefill", "moe_tokens.decode_step",
+                  "latent_positions_read", "latent_positions_window"),
+        unserved=dict.fromkeys(
+            ("mesh", "kv_quant", "weight_quant", "adapters",
+             "speculative_k"), why),
+    )
+
+
 def _block(x, bp, cache, layer, pos, tables, live, cfg: ModelConfig,
            experts=None, paged_impl="gather"):
     """One layer over rows x [g, T, E]: returns (x, cache, counts [3]);

@@ -425,6 +425,8 @@ class DecodeEngine:
             "evictions": None,
             "kv_quant": "none",
             "kv_bytes_per_position": _kv_bytes_per_position(self.cfg),
+            "state_bytes_per_row": decode.serving(
+                self.cfg).state_bytes_per_row,
             "speculative_k": 0,
             "spec_accept_rate": _spec_accept_rate(self.counters),
             "counters": dict(self.counters),
@@ -1136,12 +1138,13 @@ class BatchedDecodeEngine:
                 "row's output would depend on its neighbours — use the "
                 "serial DecodeEngine for MoE decode"
             )
-        if not decode.has_dense_cache(cfg) and not isinstance(
+        if not decode.serving(cfg).dense_cache and not isinstance(
             self, PagedBatchedDecodeEngine
         ):
             raise NotImplementedError(
-                f"the {cfg.family} family caches pages only (a latent "
-                "pool): serve it through PagedBatchedDecodeEngine "
+                f"the {cfg.family} family is served from a paged pool "
+                "only (a latent pool, or pages beside per-row state): "
+                "serve it through PagedBatchedDecodeEngine "
                 "(decode.init_cache has no dense layout for it)"
             )
         self.cfg = cfg
@@ -2655,8 +2658,11 @@ class BatchedDecodeEngine:
             "evictions": None,
             "kv_quant": "none",
             # what one cache position costs across all layers, in the
-            # family's own page layout (per-head K and V, or one latent)
+            # family's own page layout (per-head K and V, or one latent),
+            # and what a ROW costs whatever its depth (recurrent state)
             "kv_bytes_per_position": self._bytes_per_position(),
+            "state_bytes_per_row": decode.serving(
+                self.cfg).state_bytes_per_row,
             "speculative_k": self.speculative_k,
             "spec_accept_rate": _spec_accept_rate(self.counters),
             "counters": dict(self.counters),
@@ -2929,6 +2935,15 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 f"{max_len % page_size if page_size >= 1 else 0} cache "
                 "positions — pick page_size from the divisors of max_len"
             )
+        # What the family asks of an engine (``decode.Serving``: the
+        # engine reads no family's name). What it cannot be served with is
+        # refused with the family's own reason; a mesh here, before the
+        # base class tries to place the family's parameters on it.
+        self._family = decode.serving(cfg)
+        self._unserved = self._family.unserved
+        if ("mesh" in self._unserved and mesh_cfg is not None
+                and mesh_cfg.num_devices > 1):
+            raise NotImplementedError(self._unserved["mesh"])
         super().__init__(
             cfg, slots=slots, max_len=max_len, buckets=None,
             mesh_cfg=mesh_cfg, **kw,
@@ -2980,7 +2995,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             # dense families keep the gather, which is bit-identical to
             # the dense engine's math.
             paged_attention = (
-                "gather" if decode.has_dense_cache(cfg) else "auto"
+                "auto" if self._family.latent_pool else "gather"
             )
         if paged_attention == "auto":
             paged_attention = (
@@ -3000,31 +3015,33 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         # ``export_handoff``; "decode" accepts rows only via
         # ``import_handoff``/``adopt`` and never prefills fresh prompts.
         self.role = _check_role(role)
-        # A model that counts its work (``decode.aux_counts``: the
-        # kimi_k2 family's expert layers) has its programs hand the counts
+        asked = {
+            "kv_quant": self.kv_quant != "none",
+            "weight_quant": self.weight_quant != "none",
+            "adapters": bool(self.adapters),
+            "speculative_k": bool(self.speculative_k),
+            "handoff": self.role != "colocated",
+            "paged_kernel": self._paged_impl != "gather",
+        }
+        for feature, why in self._unserved.items():
+            if asked.get(feature):
+                raise NotImplementedError(why)
+        # Recurrent state a row, beside its pages (0: the cache is pages
+        # alone): the state leaves are [L, slots + 1, ...], lane i of the
+        # decode step is state row i, a prefill dispatch names its rows'
+        # slots, and row ``slots`` is the scratch row.
+        self._row_state = self._family.state_bytes_per_row
+        # A model that counts its work has its programs hand the counts
         # back between the tokens and the sentinel; they accumulate here
-        # per program kind beside the tokens each kind processed, and the
-        # cache positions the decode program's rows reached. Only the two
-        # plain programs carry them: one device, unquantized pages.
-        self._aux_counts = decode.aux_counts(cfg)
-        if self._aux_counts:
-            if (self.mode != "plain" or self.kv_quant != "none"
-                    or self.weight_quant != "none" or self.adapters
-                    or self.speculative_k):
-                raise NotImplementedError(
-                    f"the {cfg.family} family is served on one device "
-                    "from unquantized latent pages: no mesh, kv_quant, "
-                    "weight_quant, adapters or speculative_k"
-                )
-            for kind in ("prefill", "decode_step"):
-                for name in (*self._aux_counts, "moe_tokens"):
-                    self.counters[f"{name}.{kind}"] = 0
-            # positions the decode program's rows reached, beside those a
-            # gathered window holds whatever their depth (every row's
-            # whole table): their ratio is the share of the window the
-            # kernel path does not touch
-            self.counters["latent_positions_read"] = 0
-            self.counters["latent_positions_window"] = 0
+        # per program kind, beside what the engine itself knows of a
+        # dispatch under the names the family's readers ask for
+        # (``_count_dispatch``). Only the two plain programs carry counts.
+        self._aux_counts = self._family.aux_counts
+        for kind in ("prefill", "decode_step"):
+            for name in self._aux_counts:
+                self.counters[f"{name}.{kind}"] = 0
+        for name in self._family.counters:
+            self.counters[name] = 0
         self.counters["preemptions"] = 0
         self.counters["preempt_priority"] = 0
         self.counters["batch_yield_ticks"] = 0
@@ -3099,6 +3116,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         cache = decode.init_paged_cache(
             self.cfg, self.pool_pages, self.page_size, n_kv=self._n_kv,
             kv_quant=self.kv_quant,
+            rows=self.slots if self._row_state else None,
         )
         if self.device is not None:
             # Committed inputs pin every jitted program's outputs to the
@@ -3158,7 +3176,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             evictions=ps["evictions"],
             kv_quant=self.kv_quant,
         )
-        if self._aux_counts:
+        if self._family.latent_pool:
             # what the decode program reads the latent pool through
             out["latent_decode_impl"] = self._paged_impl
         out["counters"]["session_evictions"] = self._sessions.evictions
@@ -3193,7 +3211,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         shared with the dense bodies so they can never drift."""
 
         def prefill(params, chunks, valid, start, tables, cache,
-                    greedy, t, k, p, keydata, *lora):
+                    greedy, t, k, p, keydata, *extra):
             # One CHUNK per row: tokens chunks[:, :valid] run at
             # positions start..start+valid-1 (pad positions write
             # garbage past the write point into the row's own padded
@@ -3203,9 +3221,18 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             # are those of valid-1; a model that counts its work counts
             # the chunk's real tokens only.
             lanes = jnp.arange(chunks.shape[1], dtype=jnp.int32)
+            live = lanes[None] < valid[:, None]
+            lora, rows = extra, {}
+            if self._row_state:
+                # one more operand: the SLOT of each row of the group (the
+                # state leaves are per slot); the group's padding points at
+                # the scratch row and holds no token
+                (slot_rows,), lora = extra, ()
+                live &= (slot_rows < self.slots)[:, None]
+                rows = {"state_rows": slot_rows}
             logits, cache, *aux = self._forward_paged(
                 params, chunks, cache, start, tables, lora,
-                live=lanes[None] < valid[:, None], logits_index=valid - 1,
+                live=live, logits_index=valid - 1, **rows,
             )
             last = logits[:, 0]
             keys = jax.random.wrap_key_data(keydata)
@@ -3374,6 +3401,10 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         pages release so retention sees them resident), then hand the
         tracker the new transcript + the full chain to pin."""
         toks = self._partial_tokens(s.prompt, s.generated)
+        if self._row_state:
+            # nothing published, nothing to pin: the transcript alone
+            self._sessions.on_turn_done(s.session, toks, [])
+            return
         cp = self.chunk // self.page_size
         key = s.chain_key  # chain at the last prefill-published boundary
         for st in range(
@@ -3552,7 +3583,10 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         rounded up to the chunk the padded final prefill writes."""
         prefix = self._partial_tokens(req.prompt, req.gen)
         plen = prefix.shape[0]
-        if req.nan_retried:
+        if req.nan_retried or self._row_state:
+            # (nor where rows hold recurrent state: a cached prefix's pages
+            # come without the state at its end, so nothing is matched,
+            # published or pinned, and ``prefix_queries`` stays 0)
             cached, shared, chain_key = 0, [], ""
         else:
             cached, shared, chain_key = self.pool.match_prefix(
@@ -3565,7 +3599,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             # the hit counters are concerned — a head-of-line request
             # retrying every tick must not inflate the committed stats.
             # (A quarantine retry never queried, so nothing to cancel.)
-            if not req.nan_retried:
+            if not (req.nan_retried or self._row_state):
                 self.pool.cancel_match(cached, shared)
             return None
         if cached:
@@ -3653,11 +3687,22 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 t[j], k[j], p[j] = s.t, s.k, s.p
                 keydata[j] = s.prefill_keydata
                 tenants[j] = s.tenant_slot
+            # The slot each row of the group sits in. The padding repeats
+            # row 0: in a family whose K and V are a function of the tokens
+            # alone it rewrites row 0's pages with the same values; where
+            # rows carry state it is given the scratch row AND the scratch
+            # page, since no state is carried in for it and its K and V
+            # differ from row 0's.
+            slot_rows = np.full((npad,), self.slots, np.int32)
+            slot_rows[:n] = [i for i, _ in rows]
+            if self._row_state:
+                tables[n:] = 0
             args = (
                 jnp.asarray(chunks), jnp.asarray(valid),
                 jnp.asarray(start), jnp.asarray(tables), None,
                 jnp.asarray(greedy), jnp.asarray(t), jnp.asarray(k),
                 jnp.asarray(p), jnp.asarray(keydata),
+                *((jnp.asarray(slot_rows),) if self._row_state else ()),
                 *self._lora_dispatch_args(tenants),
             )
         res = self._dispatch("prefill", params, [], finished, *args)
@@ -3665,8 +3710,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             return  # recovery converted every in-flight row already
         toks, *aux, bad = res
         with self.timers.span("engine.settle.prefill"):
-            if aux:
-                self._count_aux("prefill", aux[0], int(valid[:n].sum()))
+            self._count_dispatch("prefill", aux, int(valid[:n].sum()))
             for j in range(n):
                 row, s = rows[j]
                 if bad[j]:
@@ -3677,7 +3721,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                     )
                     continue
                 v = min(self.chunk, s.prefill_len - s.pos)
-                if v == self.chunk:
+                if v == self.chunk and not self._row_state:
                     # A full chunk lies entirely inside the prefix:
                     # publish its pages for prefix sharing (clean chunks
                     # only — a flagged row never contaminates the cache).
@@ -3883,15 +3927,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             return
         out, *aux, bad = res
         with self.timers.span("engine.settle.decode"):
-            if aux:
-                self._count_aux("decode_step", aux[0], len(ready))
-                # each row's token at pos attends positions 0..pos
-                self.counters["latent_positions_read"] += sum(
-                    s.pos + 1 for _, s in ready
-                )
-                self.counters["latent_positions_window"] += (
-                    self.slots * self.max_len
-                )
+            self._count_dispatch("decode_step", aux, len(ready), ready)
             for i, s in ready:
                 if bad[i]:
                     self._slots[i] = None
@@ -3903,13 +3939,27 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 s.fold += 1
                 self._maybe_retire(i, finished)
 
-    def _count_aux(self, kind: str, counts, tokens: int) -> None:
-        """One dispatch's counts (``decode.aux_counts``, summed over the
-        layers by the program) and the tokens it processed, onto the
-        counters of its program kind."""
-        for name, n in zip(self._aux_counts, counts):
+    def _count_dispatch(self, kind: str, aux, tokens: int, ready=()) -> None:
+        """One dispatch onto the counters of its program kind: the counts
+        the program handed back (``aux``: none, or one array of them), and
+        of what the engine itself knows those the family asks for by name
+        (``decode.Serving.counters``)."""
+        for name, n in zip(self._aux_counts, aux[0] if aux else ()):
             self.counters[f"{name}.{kind}"] += int(n)
-        self.counters[f"moe_tokens.{kind}"] += tokens
+        if not self._family.counters:
+            return
+        did = {f"moe_tokens.{kind}": tokens}
+        if kind == "decode_step":
+            # each ready row's token at pos attends positions 0..pos; a
+            # gathered window holds every row's whole table
+            reach = sum(s.pos + 1 for _, s in ready)
+            did.update(
+                state_rows_advanced=len(ready), kv_positions_read=reach,
+                latent_positions_read=reach,
+                latent_positions_window=self.slots * self.max_len)
+        for name in self._family.counters:
+            if name in did:
+                self.counters[name] += did[name]
 
     def _ensure_decode_pages(
         self, finished: list[int], skip_batch: bool = False
@@ -4101,6 +4151,8 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 jnp.full((npad,), self.cfg.vocab_size, jnp.int32),
                 jnp.full((npad,), 2.0, jnp.float32),
                 jnp.zeros((npad, self._key_words), jnp.uint32),
+            ) + ((jnp.full((npad,), self.slots, jnp.int32),)
+                 if self._row_state else ()
             ) + self._lora_dispatch_args(np.zeros((npad,), np.int32))
         if kind == "decode_step":
             b = self.slots
@@ -4177,6 +4229,8 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         the row stays live (pages held, fault model intact) until
         ``complete_handoff`` confirms the import landed — a destination
         dying mid-handoff costs nothing but the gather."""
+        if "handoff" in self._unserved:
+            raise NotImplementedError(self._unserved["handoff"])
         s = next(
             (x for x in self._slots if x is not None and x.rid == rid),
             None,
@@ -4231,6 +4285,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         they are reclaimable, not pressure)."""
         return (
             self.role != "prefill"
+            and "handoff" not in self._unserved
             and any(s is None for s in self._slots)
             and self.pool.allocatable_pages() >= h.n_pages
             and h.page_size == self.page_size
@@ -4269,6 +4324,8 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         failed dispatch; terminal rids from that recovery land in
         ``finished``). The source row is untouched either way until
         ``complete_handoff``."""
+        if "handoff" in self._unserved:
+            raise NotImplementedError(self._unserved["handoff"])
         if self.role == "prefill":
             raise ValueError(
                 "a PREFILL worker cannot import handoffs — it only "

@@ -1,0 +1,112 @@
+"""The granite-4.0-h-micro configuration's serving programs, compiled at the
+published widths for a described TPU v5e (no chip is attached, nothing runs):
+the decode step over the cell's rows and the prefill of one 256-token chunk
+(one SSD block with a carried-in state), as ``PagedBatchedDecodeEngine``
+builds them for the benchmark's cell. They must compile; fit a chip beside
+the weights; update the state leaves and the pool where they lie (their
+bytes aliased, no ``copy`` of a whole ``ssm``, ``conv``, ``k`` or ``v``
+leaf, nor of a weight stack: a period's slice of the stacks handed to the
+layer scan was copied out whole, 1.4 GB an iteration, and an in-projection
+of 8512 columns, not whole lanes, was copied into another layout, 1.25 GB a
+dispatch); and the prefill program holds no per-position state
+``[256, 64, 64, 128]`` among its temporaries (the chunked form never has
+one).
+
+One file, the topology described inside a fixture: only the worker that is
+given this file loads the TPU's library (on-chip-measurement guide, 2).
+"""
+
+import json
+import re
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+
+from pytorch_distributed_tpu.config import model_config
+from pytorch_distributed_tpu.models import decode, get_model
+from pytorch_distributed_tpu.serving.engine import PagedBatchedDecodeEngine
+
+# the cell's own engine arguments
+ENGINE = json.loads((Path(__file__).resolve().parents[1] / "perfbench"
+                     / "traffic" / "chat-backlog.json").read_text())["engine"]
+HBM = 16e9
+# one position's state over a whole chunk, float32: what a scan that kept
+# every position's state would hold
+PER_POSITION_STATE_BYTES = ENGINE["prefill_chunk"] * 64 * 64 * 128 * 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as err:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {err}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def described(one_chip):
+    """(engine, abstract, abstract params, abstract cache) on the chip."""
+    cfg = model_config(
+        "granite-4.0-h-micro", dtype="bfloat16", param_dtype="bfloat16",
+        n_ctx=ENGINE["max_len"])
+    eng = PagedBatchedDecodeEngine(cfg, **ENGINE)
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = abstract(jax.eval_shape(
+        lambda: get_model(cfg).init(jax.random.key(0), cfg)))
+    cache = abstract(jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, eng.pool_pages, eng.page_size, rows=eng.slots)))
+    return eng, abstract, params, cache
+
+
+@pytest.mark.parametrize("kind", ["decode_step", "prefill"])
+def test_program_compiles_for_v5e_and_updates_its_cache_in_place(
+        kind, described):
+    eng, abstract, params, cache = described
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (4, 2049, 64, 512), "v": (4, 2049, 64, 512),
+        "ssm": (36, 33, 64, 64, 128), "conv": (36, 3, 33, 4352)}
+    args = [abstract(a) for a in jax.eval_shape(
+        lambda: eng.example_args(kind, None, group=1, cache=0))[1:]]
+    args[eng.CACHE_ARGNUM[kind] - 1] = cache
+    # no persistent cache: an entry written by a compile-only client cannot
+    # be read back, and warns
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = eng.program(kind).lower(params, *args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # 6.38 GB weights + 2.49 GB state + 1.07 GB pool + temporaries
+    assert 9.9e9 < memory.argument_size_in_bytes < 10.1e9
+    assert held < 0.8 * HBM, held
+    # the cache is updated where it lies: its bytes are aliased, not output
+    cache_bytes = sum(
+        int(np.prod(v.shape)) * v.dtype.itemsize for v in cache.values())
+    assert memory.alias_size_in_bytes >= cache_bytes
+    leaves = {",".join(map(str, v.shape)) for v in cache.values()}
+    smallest_stack = 36 * 4096 * 2048  # the Mamba layers' out-projections
+    copies = []
+    for shape in re.findall(r"= \w+\[([\d,]+)\][^ ]* copy\(",
+                            compiled.as_text()):
+        elements = int(np.prod([int(d) for d in shape.split(",")]))
+        if shape in leaves or elements >= smallest_stack:
+            copies.append(shape)
+    assert not copies, copies
+    if kind == "prefill":
+        assert memory.temp_size_in_bytes < PER_POSITION_STATE_BYTES
+    else:
+        # the gathered window of four attention layers' K and V, nothing
+        # that grows with the weights
+        assert memory.temp_size_in_bytes < 1.0e9

@@ -8,7 +8,10 @@ the whole of it takes minutes); the two files that prove what no test under
   family goes through both drivers as files and entries, no edit;
 - ``perfbench/tests/test_kimi_k2.py``: the kimi_k2 cell's comparison (the
   float8 control reads not correct, the program correct, each planted fault
-  not correct), its two readers, and its configuration file.
+  not correct), its two readers, and its configuration file;
+- ``perfbench/tests/test_granitemoehybrid.py``: the same for the
+  granitemoehybrid cell (per-row recurrent state beside the pages), with its
+  count module held to its issue's arithmetic.
 
 The cases run where they are defined; this module only names them.
 """
@@ -18,9 +21,13 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from perfbench.tests import test_kimi_k2, test_other_family  # noqa: E402
+from perfbench.tests import (  # noqa: E402
+    test_granitemoehybrid,
+    test_kimi_k2,
+    test_other_family,
+)
 
-for _module in (test_other_family, test_kimi_k2):
+for _module in (test_other_family, test_kimi_k2, test_granitemoehybrid):
     for _name, _thing in vars(_module).items():
         # its tests, and the fixture its tests ask for by name
         if _name.startswith("test_") or _name == "family":
