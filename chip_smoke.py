@@ -46,6 +46,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import dataclasses
+import functools
 import gc
 import importlib.metadata
 import json
@@ -100,26 +101,32 @@ REHEARSAL = Sizes(
     kernel_batch=4, kernel_pages=4, head_dim=16,
 )
 
-# (name, query heads, kv heads) at head_dim 64, page 16.
-HEAD_GEOMETRIES = (("gpt2", 12, 12), ("llama3-1b", 32, 8))
+# (name, query heads, kv heads, page, scale: None is D^-1/2) at head_dim
+# 64 and a table of 64 pages; granite's multiplier is its published one.
+HEAD_GEOMETRIES = (
+    ("gpt2-large", 20, 20, 16, None),
+    ("llama3-1b", 32, 8, 16, None),
+    ("granite-4.0-h-micro", 32, 8, 64, 0.015625),
+)
 
 # Compiled paged kernel vs the exact-f32 reference. The CPU tests hold the
 # INTERPRETED kernel to 1e-5 (tests/test_serving_paged.py, test_quant.py);
 # on the chip its f32 dots run on the MXU at default precision, which
 # rounds operands to bf16 (8 mantissa bits), and the output is rounded to
 # the bf16 query dtype: measured 3.4e-3..7.8e-3 on outputs of magnitude
-# <= 4 (my chip run, PR 21), f32 queries no better than bf16 ones. The
-# bound is two bf16 ulps at that magnitude; a wrong page, head or mask is
-# an O(1) error.
+# <= 4 (my chip run, PR 21), f32 queries no better than bf16 ones; the
+# kernel as rebuilt by PR 35 reads 1.3e-3..4.3e-3 on bf16 pages and
+# 7.5e-3..7.8e-3 on int8 pages (my chip run, PR 35). The bound is two bf16
+# ulps at that magnitude; a wrong page, head or mask is an O(1) error.
 PAGED_KERNEL_ATOL = 2e-2
 
 # The granitemoehybrid period in bfloat16 against the float32 reference of
 # the same weights: bf16 activations through ten layers move a logit by
-# under a tenth of the logits' spread (my chip run, PR 34: 0.086 x std with
-# that PR's first draws of the weights; not read again on the chip with the
-# draws the reference has since its review, under which the state's term is
-# most of a mixer's output; the bound is relative); a state not reset, a tail
-# dropped or a padded tail run on is an error of the spread itself.
+# under a tenth of the logits' spread (my chip run, PR 35: 0.064 x std
+# through the gather and 0.065 through the paged kernel, with the draws the
+# reference has since PR 34's review; 0.086 with that PR's first draws; the
+# bound is relative); a state not reset, a tail dropped or a padded tail run
+# on is an error of the spread itself.
 HYBRID_STATE_RTOL = 0.25
 
 # One-chip vs four-chip per-step loss: the same data, weights and f32
@@ -461,7 +468,8 @@ def phase_serve(params, cfg, sizes: Sizes, requests, device_label: str):
 # --------------------------------------------------------------------------
 
 
-def paged_case(rng, sizes: Sizes, h: int, hkv: int, quantized: bool):
+def paged_case(rng, sizes: Sizes, h: int, hkv: int, page: int,
+               quantized: bool):
     """bf16 queries (what the engine passes), random pages and a ragged
     batch: depth 0, a page boundary on either side, a mid-depth row and
     the deepest row the table allows."""
@@ -472,10 +480,10 @@ def paged_case(rng, sizes: Sizes, h: int, hkv: int, quantized: bool):
 
     b, n_pages, d = sizes.kernel_batch, sizes.kernel_pages, sizes.head_dim
     pool = b * n_pages + 1  # the engine's default pool incl. scratch page 0
-    max_pos = n_pages * PAGE_SIZE - 1
+    max_pos = n_pages * page - 1
     lengths = np.resize(
         np.asarray(
-            [0, PAGE_SIZE - 1, PAGE_SIZE, max_pos // 2, max_pos], np.int32
+            [0, page - 1, page, max_pos // 2, max_pos], np.int32
         ),
         b,
     )
@@ -483,19 +491,19 @@ def paged_case(rng, sizes: Sizes, h: int, hkv: int, quantized: bool):
     free = rng.permutation(np.arange(1, pool))  # pages scattered in the pool
     used = 0
     for i, ln in enumerate(lengths):
-        need = int(ln) // PAGE_SIZE + 1
+        need = int(ln) // page + 1
         tables[i, :need] = free[used:used + need]
         used += need
     q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.bfloat16)
-    kf = jnp.asarray(rng.normal(size=(pool, PAGE_SIZE, hkv, d)), jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(pool, PAGE_SIZE, hkv, d)), jnp.float32)
+    kf = jnp.asarray(rng.normal(size=(pool, page, hkv, d)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(pool, page, hkv, d)), jnp.float32)
     if quantized:
         (k, ks), (v, vs) = quantize_kv(kf), quantize_kv(vf)
         scales = dict(k_scales=ks, v_scales=vs)
     else:
         k, v, scales = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16), {}
     # the pool's stored shape: the heads merged head-major on the minor axis
-    k, v = (x.reshape(pool, PAGE_SIZE, hkv * d) for x in (k, v))
+    k, v = (x.reshape(pool, page, hkv * d) for x in (k, v))
     return q, k, v, jnp.asarray(tables), jnp.asarray(lengths), scales
 
 
@@ -558,12 +566,15 @@ def hybrid_state_case(rehearsal: bool):
     granite-4.0-h-micro's published widths (a vocabulary of 8192; toy sizes
     in rehearsal), chunked prefill then three decode steps through
     ``decode.forward`` on the state rows of ONE cache, against the float32
-    reference's full forward of the same bfloat16 weights. The rows: 0 free
+    reference's full forward of the same bfloat16 weights; the decode steps
+    twice over, their attention layer reading its pages through the gathered
+    window and through ops/paged_kernel.py. The rows: 0 free
     throughout; 1 a prompt shorter than a chunk (from depth 0, a padded
     chunk); 2 and 3 either side of a chunk boundary (chunk - 1 and chunk + 1
     tokens: the second chunk holds ONE token); 4 a REUSED row, which another
-    prompt ran through first. Returns (max |program - reference| over the
-    logits that choose a token, the reference logits' std)."""
+    prompt ran through first. Returns ({paged_impl: max |program -
+    reference| over the logits that choose a token}, the reference logits'
+    std)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -608,14 +619,15 @@ def hybrid_state_case(rehearsal: bool):
     tables[1:] = 1 + np.arange((rows - 1) * n_pages).reshape(rows - 1, -1)
     tables = jnp.asarray(tables)
 
-    @jax.jit
-    def forward(params, cache, toks, pos, live, state_rows):
+    @functools.partial(jax.jit, static_argnames="paged_impl")
+    def forward(params, cache, toks, pos, live, state_rows,
+                paged_impl="gather"):
         return decode.forward(
             params, toks, cfg, cache, pos, block_tables=tables[state_rows],
-            live=live, state_rows=state_rows)
+            live=live, state_rows=state_rows, paged_impl=paged_impl)
 
-    def call(*args):  # the weights an ARGUMENT: closed over, they are
-        return forward(params, *args)  # compiled in as 1.6 GB of constants
+    def call(*args, **kw):  # the weights an ARGUMENT: closed over, they are
+        return forward(params, *args, **kw)  # 1.6 GB of constants
 
     def prefill(cache, wanted):
         """Every row of ``wanted`` {row: tokens} chunk by chunk, all rows in
@@ -644,27 +656,30 @@ def hybrid_state_case(rehearsal: bool):
         0, cfg.vocab_size, chunk + 5).astype(np.int32)})
     cache, got = prefill(
         cache, {r: ids[r][:n] for r, n in lengths.items()})
-    got = {r: [lg] for r, lg in got.items()}
-    for step in range(steps):  # lane = row; lane 0 holds no token
-        toks = np.zeros((rows, 1), np.int32)
-        pos = np.zeros((rows,), np.int32)
-        for r, n in lengths.items():
-            toks[r, 0], pos[r] = ids[r][n + step], n + step
-        lg, cache = call(
-            cache, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(np.arange(rows)[:, None] > 0), jnp.arange(rows))
-        for r in lengths:
-            got[r].append(np.asarray(lg[r, 0], np.float32))
-    assert not np.asarray(cache["ssm"][:, 0]).any()  # the free row
-    assert not np.asarray(cache["conv"][:, :, 0], np.float32).any()
-    err, stds = 0.0, []
-    for r, n in lengths.items():
-        want = np.asarray(ref.logits_at(
-            params, jnp.asarray(ids[r][None]), n - 1, steps + 1, model))
-        assert np.isfinite(np.stack(got[r])).all()
-        err = max(err, float(np.abs(np.stack(got[r]) - want).max()))
-        stds.append(float(want.std()))
-    return err, float(np.mean(stds))
+    prefilled, first = cache, got
+    want = {r: np.asarray(ref.logits_at(
+        params, jnp.asarray(ids[r][None]), n - 1, steps + 1, model))
+        for r, n in lengths.items()}
+    errs = {}
+    for impl in ("gather", "kernel_interpret" if rehearsal else "kernel"):
+        cache, got = prefilled, {r: [lg] for r, lg in first.items()}
+        for step in range(steps):  # lane = row; lane 0 holds no token
+            toks = np.zeros((rows, 1), np.int32)
+            pos = np.zeros((rows,), np.int32)
+            for r, n in lengths.items():
+                toks[r, 0], pos[r] = ids[r][n + step], n + step
+            lg, cache = call(
+                cache, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(np.arange(rows)[:, None] > 0), jnp.arange(rows),
+                paged_impl=impl)
+            for r in lengths:
+                got[r].append(np.asarray(lg[r, 0], np.float32))
+        assert not np.asarray(cache["ssm"][:, 0]).any()  # the free row
+        assert not np.asarray(cache["conv"][:, :, 0], np.float32).any()
+        assert all(np.isfinite(np.stack(got[r])).all() for r in lengths)
+        errs[impl] = max(float(np.abs(np.stack(got[r]) - want[r]).max())
+                         for r in lengths)
+    return errs, float(np.mean([w.std() for w in want.values()]))
 
 
 def phase_kernels(params, cfg, sizes: Sizes, requests, served,
@@ -679,13 +694,13 @@ def phase_kernels(params, cfg, sizes: Sizes, requests, served,
     )
 
     rng = np.random.default_rng(SEED)
-    for name, h, hkv in HEAD_GEOMETRIES:
+    for name, h, hkv, page, scale in HEAD_GEOMETRIES:
         for quantized in (False, True):
             q, k, v, tables, lengths, scales = paged_case(
-                rng, sizes, h, hkv, quantized
+                rng, sizes, h, hkv, page, quantized
             )
             out = paged_decode_attention(
-                q, k, v, tables, lengths, **scales,
+                q, k, v, tables, lengths, **scales, scale=scale,
                 interpret=rehearsal,  # on the chip: compiled, always
             )
             # The reference in f32 at full matmul precision over the very
@@ -695,7 +710,7 @@ def phase_kernels(params, cfg, sizes: Sizes, requests, served,
                     q.astype(jnp.float32),
                     k if quantized else k.astype(jnp.float32),
                     v if quantized else v.astype(jnp.float32),
-                    tables, lengths, *scales.values(),
+                    tables, lengths, *scales.values(), scale=scale,
                 )
             out = np.asarray(out.astype(jnp.float32))
             assert out.shape == (sizes.kernel_batch, h, sizes.head_dim)
@@ -704,8 +719,8 @@ def phase_kernels(params, cfg, sizes: Sizes, requests, served,
             pages = "int8" if quantized else "bf16"
             print(
                 f"kernels: paged_decode_attention {name} H={h} Hkv={hkv} "
-                f"pages={pages}: max |kernel - f32 reference| = {err:.2e} "
-                f"(bound {PAGED_KERNEL_ATOL:g})"
+                f"page={page} pages={pages}: max |kernel - f32 reference| "
+                f"= {err:.2e} (bound {PAGED_KERNEL_ATOL:g})"
             )
             np.testing.assert_allclose(
                 out, ref, rtol=0, atol=PAGED_KERNEL_ATOL
@@ -717,14 +732,16 @@ def phase_kernels(params, cfg, sizes: Sizes, requests, served,
         f"gathered window| = {err:.2e} (bound {PAGED_KERNEL_ATOL:g})"
     )
 
-    err, std = hybrid_state_case(rehearsal)
-    print(
-        f"kernels: granitemoehybrid period on a ragged batch (a free row, "
-        f"a padded chunk, either side of a chunk boundary, a reused row): "
-        f"max |program - f32 reference| = {err:.2e} on logits of std "
-        f"{std:.2e} (bound {HYBRID_STATE_RTOL:g} x std)"
-    )
-    assert err <= HYBRID_STATE_RTOL * std, (err, std)
+    errs, std = hybrid_state_case(rehearsal)
+    for impl, err in errs.items():
+        print(
+            f"kernels: granitemoehybrid period on a ragged batch (a free "
+            f"row, a padded chunk, either side of a chunk boundary, a "
+            f"reused row), decode steps through the {impl}: max |program - "
+            f"f32 reference| = {err:.2e} on logits of std {std:.2e} (bound "
+            f"{HYBRID_STATE_RTOL:g} x std)"
+        )
+        assert err <= HYBRID_STATE_RTOL * std, (impl, err, std)
 
     # The same requests through an engine that picks its own paged
     # attention: on the chip "auto" must mean the compiled kernel.

@@ -79,11 +79,11 @@ class Serving(NamedTuple):
     declares it in its own module's ``serving(cfg)``, these fields as a
     dict; ``serving`` below looks it up."""
 
-    # ``init_cache`` has a [B, max_len] layout; False: the PAGED pool only
+    # ``init_cache`` has a [B, max_len] layout; False: the PAGED pool only,
+    # which a decode step reads through a kernel on a TPU where
+    # ``paged_attention`` is left unset (there is no dense engine for the
+    # gather to be bit-identical to)
     dense_cache: bool = True
-    # a page holds ONE latent all heads share, which a decode step reads
-    # through its own kernel on a TPU (models/kimi_k2.py)
-    latent_pool: bool = False
     # Bytes of recurrent state a ROW holds whatever its depth, beside its
     # pages. Non-zero means: ``init_paged_cache`` takes ``rows`` and returns
     # the state leaves [L, rows + 1, ...] beside the pool; a prefill call
@@ -101,8 +101,7 @@ class Serving(NamedTuple):
     # what a paged engine cannot serve the family with, {feature: why}:
     # "mesh", "kv_quant", "weight_quant", "adapters", "speculative_k",
     # "handoff" (a prefill or decode worker's role, or a call to export or
-    # import a row), "paged_kernel" (``paged_attention`` other than the
-    # gather)
+    # import a row)
     unserved: dict[str, str] = {}
 
 
@@ -267,17 +266,17 @@ def _cached_attention(q, cache, layer, pos, block_tables=None,
     dense path wherever the valid positions hold the same values); for
     single-token decode, ``paged_impl`` of "kernel"/"kernel_interpret"
     dispatches the Pallas paged-attention kernel instead, which reads
-    pages in place and skips pages past each row's depth.
+    the pages in place, each row's to its depth.
 
     ``kv_quant="int8"`` (paged only): ``cache`` additionally carries
     ``k_scale``/``v_scale`` pools; the gather path dequantizes the
     gathered view (one int8->f32 convert per K and V — the audit's q8
     cast budget counts them) and runs the identical masked math, the
-    kernel path dequantizes page blocks in VMEM (dequant-in-kernel —
-    HBM only ever moves int8 pages + scales).
+    kernel path meets the scales in VMEM (HBM only ever moves int8 pages
+    + scales).
 
     ``scale`` multiplies the scores in place of D^-1/2 (a family whose
-    attention is scaled by a published multiplier; gather path only)."""
+    attention is scaled by a published multiplier), on either path."""
     if block_tables is not None and q.shape[1] == 1 and (
         paged_impl in ("kernel", "kernel_interpret")
     ):
@@ -288,7 +287,7 @@ def _cached_attention(q, cache, layer, pos, block_tables=None,
         out = paged_decode_attention(
             q[:, 0], cache["k"], cache["v"], block_tables, pos,
             k_scales=cache.get("k_scale"), v_scales=cache.get("v_scale"),
-            layer=layer,
+            layer=layer, scale=scale,
             interpret=paged_impl == "kernel_interpret",
         )
         return out[:, None]
@@ -691,18 +690,19 @@ def forward(
     if cfg.family == "granitemoehybrid":
         if (block_tables is None or tensor_axis is not None
                 or block_transform is not None or lora is not None
-                or kv_quant != "none" or paged_impl != "gather"):
+                or kv_quant != "none"):
             raise NotImplementedError(
                 "the granitemoehybrid family runs on the paged pool and "
                 "the per-row state of one device (block_tables given; no "
-                "tensor axis, ZeRO-3 transform, LoRA, quantized pages or "
-                "paged-attention kernel): models/granitemoehybrid.forward"
+                "tensor axis, ZeRO-3 transform, LoRA or quantized pages): "
+                "models/granitemoehybrid.forward"
             )
         from pytorch_distributed_tpu.models import granitemoehybrid
 
         logits, cache, aux = granitemoehybrid.forward(
             params, input_ids, cfg, cache, pos, block_tables,
             state_rows=state_rows, live=live, logits_index=logits_index,
+            paged_impl=paged_impl,
         )
         return (logits, cache, aux) if return_aux else (logits, cache)
     if cfg.family == "gpt2":

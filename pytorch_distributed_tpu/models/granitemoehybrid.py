@@ -130,8 +130,10 @@ def serving(cfg: ModelConfig) -> dict:
         dense_cache=False,
         state_bytes_per_row=state_bytes_per_row(cfg),
         aux_counts=AUX_COUNTS,
-        # a decode dispatch's ready rows, and the cache positions they reach
-        counters=("state_rows_advanced", "kv_positions_read"),
+        # a decode dispatch's ready rows, the cache positions they reach, and
+        # those of every row's whole table (what a gathered window holds)
+        counters=("state_rows_advanced", "kv_positions_read",
+                  "kv_positions_window"),
         unserved={
             "mesh": f"{fam} is served on one device: its state leaves and "
                     "its two parameter stacks have no mesh placement",
@@ -148,10 +150,6 @@ def serving(cfg: ModelConfig) -> dict:
                        "(export_handoff / import_handoff, role prefill or "
                        "decode): a handoff ships pages, and the row's "
                        "recurrent state is not among them",
-            "paged_kernel": f"{fam}'s attention layers read their pages "
-                            "through the gather: the paged-attention "
-                            "kernel scales by D^-1/2, not by "
-                            "attention_multiplier",
         },
     )
 
@@ -320,7 +318,8 @@ def _mamba(h, mp, cache, layer, pos, rows, live, cfg: ModelConfig):
     return y @ mp["out_proj"].astype(h.dtype), cache
 
 
-def _attention(h, ap, cache, layer, pos, tables, cfg: ModelConfig):
+def _attention(h, ap, cache, layer, pos, tables, cfg: ModelConfig,
+               paged_impl="gather"):
     from pytorch_distributed_tpu.models.decode import (
         _cached_attention,
         _write_kv,
@@ -335,18 +334,20 @@ def _attention(h, ap, cache, layer, pos, tables, cfg: ModelConfig):
         {"k": cache["k"], "v": cache["v"]}, layer, k, v, pos, tables)
     with jax.named_scope("hybrid_attn"):
         o = _cached_attention(
-            q, kv, layer, pos, tables, scale=cfg.attention_multiplier)
+            q, kv, layer, pos, tables, paged_impl,
+            scale=cfg.attention_multiplier)
     return o.reshape(b, t, -1) @ ap["wo"].astype(h.dtype), {**cache, **kv}
 
 
 def _layer(x, bp, kind, cache, layer, pos, tables, rows, live,
-           cfg: ModelConfig):
+           cfg: ModelConfig, paged_impl="gather"):
     eps, r = cfg.layer_norm_epsilon, cfg.residual_multiplier
     h = rms_norm(x, bp["ln_mix"], eps=eps)
     if kind == "mamba":
         m, cache = _mamba(h, bp["mixer"], cache, layer, pos, rows, live, cfg)
     else:
-        m, cache = _attention(h, bp["attn"], cache, layer, pos, tables, cfg)
+        m, cache = _attention(
+            h, bp["attn"], cache, layer, pos, tables, cfg, paged_impl)
     x = x + r * m
     h = rms_norm(x, bp["ln_mlp"], eps=eps)
     with jax.named_scope("shared_mlp"):
@@ -380,7 +381,8 @@ def _rows_in_blocks(layer_fn, x, cache, per_row):
 
 
 def forward(params: Params, input_ids, cfg: ModelConfig, cache: dict, pos,
-            block_tables, *, state_rows=None, live=None, logits_index=None):
+            block_tables, *, state_rows=None, live=None, logits_index=None,
+            paged_impl="gather"):
     """T tokens a row at positions pos[b]..pos[b]+T-1 through every period
     of the pattern against the paged KV pool and the rows' recurrent state.
     ``state_rows`` [B]: the state row of each batch row (None: row b is
@@ -388,7 +390,9 @@ def forward(params: Params, input_ids, cfg: ModelConfig, cache: dict, pos,
     each row they are a PREFIX (a padded final chunk, a free lane). Returns
     (logits [B, T, V] — [B, 1, V], of position ``logits_index[b]``, where
     that is given —, cache, counts [2] int32: the entries that were tokens,
-    the entries computed over)."""
+    the entries computed over). ``paged_impl``: how a call of one token a
+    row reads its attention layers' pages (``decode._cached_attention``:
+    "gather" / "kernel" / "kernel_interpret")."""
     b, t = input_ids.shape
     pos = jnp.asarray(pos, jnp.int32)
     if live is None:
@@ -421,7 +425,7 @@ def forward(params: Params, input_ids, cfg: ModelConfig, cache: dict, pos,
             def layer_fn(xg, cache, pos, tables, live, rows=None, bp=bp,
                          kind=kind, layer=layer):
                 return _layer(xg, bp, kind, cache, layer, pos, tables,
-                              rows, live, cfg)
+                              rows, live, cfg, paged_impl)
 
             x, cache = _rows_in_blocks(layer_fn, x, cache, per_row)
         return x, cache

@@ -72,6 +72,7 @@ from pytorch_distributed_tpu.config import ModelConfig
 from pytorch_distributed_tpu.ops.layer_scan import scan_layers
 from pytorch_distributed_tpu.ops.layers import rms_norm
 from pytorch_distributed_tpu.ops.moe import moe_dropless
+from pytorch_distributed_tpu.ops.paged_kernel import key_block_pages
 from pytorch_distributed_tpu.ops.rope import (
     apply_rope,
     rope_angles,
@@ -83,7 +84,6 @@ Params = dict[str, Any]
 
 LATENT = "latent"  # the cache's one leaf
 TOKEN_BLOCK = 2048  # tokens a layer processes at once
-KEY_BLOCK = 512  # cache positions the expanded path reads at once
 
 
 LANES = 128  # the chip's tiles are (8, 128): a minor axis fills them or pads
@@ -190,14 +190,6 @@ def init_latent_pool(cfg: ModelConfig, pool_pages: int, page_size: int,
     )}
 
 
-def _key_block_pages(n_pages: int, page: int) -> int:
-    """Pages the expanded path reads at once: the largest divisor of a
-    row's table that spans at most KEY_BLOCK positions."""
-    want = max(1, KEY_BLOCK // page)
-    return max(k for k in range(1, min(want, n_pages) + 1)
-               if n_pages % k == 0)
-
-
 def attend_expanded(q, pool, layer, tables, pos, wkv_b, cfg: ModelConfig):
     """q [B, T, H, Dn+Dr] at positions pos[b]..pos[b]+T-1 against layer
     ``layer`` of the latent pool, keys and values EXPANDED per head from
@@ -208,7 +200,7 @@ def attend_expanded(q, pool, layer, tables, pos, wkv_b, cfg: ModelConfig):
     c, dn, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
     dr = cfg.qk_rope_head_dim
     page = pool.shape[2]
-    kb_pages = _key_block_pages(tables.shape[1], page)
+    kb_pages = key_block_pages(tables.shape[1], page)
     kb = kb_pages * page
     w = wkv_b.reshape(c, h, dn + dv).astype(q.dtype)
     scale = softmax_scale(cfg)
@@ -305,7 +297,7 @@ def attend_absorbed(q, pool, layer, tables, pos, wkv_b, cfg: ModelConfig,
         o_lat = latent_paged_decode(
             q_lat[:, 0], pool, layer, tables, pos,
             scale=softmax_scale(cfg), out_width=c,
-            block_pages=_key_block_pages(tables.shape[1], pool.shape[2]),
+            block_pages=key_block_pages(tables.shape[1], pool.shape[2]),
             interpret=paged_impl == "kernel_interpret",
         )[:, None]
     return jnp.einsum("bthc,chd->bthd", o_lat, w[..., dn:])
@@ -376,7 +368,6 @@ def serving(cfg: ModelConfig) -> dict:
     )
     return dict(
         dense_cache=False,
-        latent_pool=True,
         aux_counts=AUX_COUNTS,
         # the tokens each program kind processed; the positions a decode
         # dispatch's rows reach, beside those a gathered window holds

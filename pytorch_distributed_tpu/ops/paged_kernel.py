@@ -9,30 +9,55 @@ through a per-row block table — the vLLM cache layout, which is what
 lets ``slots`` scale with the pool instead of ``slots x max_len``
 (ROADMAP direction 1; serving practice surveyed in PAPERS.md #1).
 
-The kernel is the piece that makes per-row attention cost scale with the
-row's DEPTH instead of ``max_len``:
+``models/decode._cached_attention``'s gather path copies
+``pool[layer, tables]`` into a ``[B, max_len, Hkv*D]`` window twice (K and
+V), whatever the rows' depth, lays both out again with the heads split and
+multiplies over all ``max_len`` positions. The kernel reads the pages where
+they lie, each row to its own depth, on ``ops/latent_paged_kernel.py``'s
+plan:
 
-- grid ``(B, n_pages)`` with the page dimension innermost and
-  sequential (online-softmax accumulator state lives in VMEM scratch
-  across it);
-- the layer index, the block tables and per-row lengths ride
-  ``PrefetchScalarGridSpec`` scalar prefetch, so the K/V BlockSpec *index
-  maps* resolve ``(layer, tables[b, i])`` before the body runs — the page
-  "gather" is just the kernel's own DMA picking its source block out of
-  the STACKED ``[L, P, page, Hkv*D]`` pool, never a materialised
-  [B, max_len] copy nor a per-layer slice of the pool;
-- pages past a row's depth are skipped with ``pl.when`` (no MXU work,
-  and their DMA re-reads the row's last useful page id — the host fills
-  unallocated table entries with the scratch page 0, so the skipped
-  fetch is bounded and harmless);
-- one grid step holds ALL heads of one page. Mosaic tiles the last two
-  block dims, so a block must cover them whole (or in (8, 128)
-  multiples): ``(1, page, Hkv*D)`` of one layer of the pool, ``(1, H, D)``
-  over the queries, ``(1, page, Hkv)`` of the int8 scale pool. The body walks
-  the KV heads in a static loop, reading head ``g`` of the page as the
-  static lane slice ``k_ref[0, :, g*D:(g+1)*D]`` and computing the whole
-  ``group = H // Hkv`` query-head block against that [page, D] key block,
-  so grouped-query heads share their KV head inside the kernel.
+- grid ``(B,)``, sequential: one row a step, and inside the step a loop
+  over the row's blocks of ``block_pages`` pages (``key_block_pages``: at
+  most KEY_BLOCK positions), ``pos[b] // block + 1`` of them and no more (a
+  free row, depth 0, costs one block). A block and not a page is the unit
+  of work because a page streams in a fraction of the time a step of any
+  kind costs;
+- the stacked pools stay in HBM (``memory_space=pl.ANY``) and are never
+  sliced: the layer index, the block tables and the depths ride scalar
+  prefetch, and the body copies page ``tables[b, i]`` of layer ``layer``
+  of every pool into one of two VMEM buffers a pool with
+  ``pltpu.make_async_copy`` while the block before is computed. The copy of
+  a row's first block is started by the row before it (its last block's
+  turn), so only the very first block of the call is waited for with
+  nothing to do;
+- per block TWO MXU products for all heads at once, operands as stored: the
+  caller-side ``_spread_heads`` lays the queries out as ``[H, Hkv*D]``,
+  head h's D numbers in the lanes of ITS kv head and zeros elsewhere, so
+  the scores of all heads are ``q_wide . K_block^T`` ([H, Hkv*D] x
+  [Hkv*D, block], float32 out) and the weighted sum ``p . V_block``
+  ([H, block] x [block, Hkv*D]), of which ``_own_lanes`` keeps each head's
+  own kv head's D lanes. That spends Hkv times the FLOPs the heads need to
+  fill the array (a dot a kv head is [group, D] x [D, block]: a few rows;
+  at granite's shapes on the chip it read the same time, 0.36 against 0.37
+  ms for four layers, the copies bound both: PERF.md section 6, PR 35);
+  between the products the online softmax in float32, the probabilities
+  rounded to the operands' dtype for the second product: the gather path's
+  rounding points, reassociated block by block;
+- int8 pages (``k_scales``/``v_scales`` [.., page, Hkv] float32) go through
+  the same body: int8 moves to the queries' dtype exactly, and a position's
+  scale multiplies its score (K) or its probability (V) instead of its D
+  numbers (the scaled probabilities then stay float32 for the second
+  product). The scales do not ride the async copies: Mosaic slices no page
+  out of a pool whose minor axis is Hkv numbers wide (it pads that axis to
+  128 lanes and takes only whole tiles of it). They are gathered outside,
+  ``[B, blocks, Hkv, block]`` (4/D of the bytes of the int8 window the
+  gather path copies), arrive a row a grid step, and reach the heads'
+  [H, block] through one small product with the heads' 0/1 membership.
+
+Every page of a started block is copied whole, also the pages past the
+row's depth (table entries past a row's pages point at the scratch page):
+the mask gives them probability 0, and what they hold is the pool's, never
+uninitialised VMEM.
 
 GQA + per-row depth masking match ``models/decode._cached_attention``'s
 masked-softmax math up to online-softmax reassociation (floating-point
@@ -41,9 +66,12 @@ token equality is pinned separately on the gather path).
 
 ``interpret`` is the caller's decision: the compiled kernel needs a TPU,
 and a caller off the chip says ``interpret=True`` itself (the CPU tests
-do; the engine's ``paged_attention="kernel_interpret"`` does). The
-serving engine's default paged attention is the pure-XLA ``gather_pages``
-fallback in models/decode.py, which is bit-identical to the dense
+do; the engine's ``paged_attention="kernel_interpret"`` does) and gets
+Pallas's plain interpreter, where a copy lands as it is started and a wait
+does nothing (see ops/latent_paged_kernel.py on why not the TPU
+interpreter). Left unset, an engine's paged attention is this kernel on a
+TPU for a family with no dense cache, and the pure-XLA ``gather_pages``
+fallback in models/decode.py otherwise, which is bit-identical to the dense
 engine's math (the property the paged-vs-dense token-equality pins rely
 on). Read /opt/skills/guides/pallas_guide.md before touching the kernel
 body.
@@ -61,144 +89,230 @@ from jax.experimental.pallas import tpu as pltpu
 from pytorch_distributed_tpu.ops.flash_kernel import out_struct
 
 NEG_INF = -1e30  # finite mask (matches ops/attention.py): -inf NaNs softmax
+KEY_BLOCK = 512  # cache positions a paged reader takes at once
+KERNEL_NAME = "paged_decode_attention"
+
+
+def key_block_pages(n_pages: int, page: int) -> int:
+    """Pages a paged reader takes at once: the largest divisor of a row's
+    table that spans at most KEY_BLOCK positions."""
+    want = max(1, KEY_BLOCK // page)
+    return max(k for k in range(1, min(want, n_pages) + 1)
+               if n_pages % k == 0)
+
+
+def _membership(h: int, hkv: int, dtype) -> jax.Array:
+    """[H, Hkv] 0/1: query head h reads kv head h // (H / Hkv)."""
+    group = h // hkv
+    head = jax.lax.broadcasted_iota(jnp.int32, (h, hkv), 0)
+    first = jax.lax.broadcasted_iota(jnp.int32, (h, hkv), 1) * group
+    return ((head >= first) & (head < first + group)).astype(dtype)
+
+
+def _spread_heads(q: jax.Array, hkv: int) -> jax.Array:
+    """[B, H, D] -> [B, H, Hkv*D]: head h's numbers in the lanes of its kv
+    head, zeros in the others', so one product with a page's merged minor
+    axis scores every head against its own kv head."""
+    b, h, d = q.shape
+    member = _membership(h, hkv, q.dtype)
+    return (q[:, :, None, :] * member[None, :, :, None]).reshape(
+        b, h, hkv * d)
+
+
+def _own_lanes(o_wide: jax.Array, hkv: int) -> jax.Array:
+    """[B, H, Hkv*D] -> [B, H, D]: of the weighted sum over every kv head's
+    lanes, each head's own kv head's."""
+    b, h, w = o_wide.shape
+    member = _membership(h, hkv, jnp.bool_)
+    return jnp.sum(
+        jnp.where(member[None, :, :, None],
+                  o_wide.reshape(b, h, hkv, w // hkv), 0), axis=2)
 
 
 def _paged_kernel(
-    layer_ref,  # [1] int32 (scalar prefetch): read by the index maps only
+    layer_ref,  # [1] int32 (scalar prefetch)
     tables_ref,  # [B, n_pages] int32 (scalar prefetch)
-    lens_ref,  # [B] int32 (scalar prefetch): row's query position
-    q_ref,  # [1, H, D]
-    k_ref,  # [1, page, Hkv*D] — the page tables_ref[b, i], all heads
-    v_ref,  # [1, page, Hkv*D]
-    *rest,  # int8 pages: ks_ref, vs_ref [1, page, Hkv] f32; then o_ref
-    # [1, H, D] and the f32 scratch acc [H, D], m [H, 1], l [H, 1]
+    pos_ref,  # [B] int32 (scalar prefetch): the row's query position
+    q_ref,  # [1, H, Hkv*D]: the row's queries, spread (``_spread_heads``)
+    k_ref,  # [L, P, page, Hkv*D], in HBM: read by the copies below only
+    v_ref,
+    *rest,  # int8 pages: the row's scales ks_ref, vs_ref [1, blocks, Hkv,
+    # block] f32; then o_ref [1, H, Hkv*D]; then the scratch: kbuf, vbuf
+    # [2, block, Hkv*D] (the block being computed and the one arriving), a
+    # DMA semaphore a pool and buffer [2, 2], slot [1] int32 in SMEM (the
+    # buffer this row's first block is in), acc [H, Hkv*D], m, l [H, 1] f32
     page: int,
-    n_pages: int,
+    block_pages: int,
     scale: float,
     quantized: bool,
 ):
-    """Online-softmax over one row's pages. With ``quantized`` the page
-    DMA moves INT8 K/V blocks plus their per-token f32 scales and
-    dequantization happens in VMEM right before the dot — HBM traffic
-    for a page drops to (D + 4)/(4D) of the f32 kernel's. Numerics past
-    the dequant are the full-precision kernel's exactly (same
-    accumulator dtypes, same masking), so quantized-vs-gather
-    equivalence is pinned the same way (tests/test_quant.py)."""
+    """Online softmax over one row's blocks of pages. With ``quantized``
+    the copies move INT8 K/V pages — HBM traffic for a page drops to a
+    quarter of the f32 kernel's — and their per-token f32 scales meet the
+    scores and the probabilities in VMEM. Numerics past that are the
+    full-precision kernel's exactly (same accumulator dtypes, same
+    masking), so quantized-vs-gather equivalence is pinned the same way
+    (tests/test_quant.py)."""
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_sc, m_sc, l_sc = rest
-    else:
-        o_ref, acc_sc, m_sc, l_sc = rest
+        ks_ref, vs_ref, *rest = rest
+    o_ref, kbuf, vbuf, sems, slot_ref, acc_sc, m_sc, l_sc = rest
     b = pl.program_id(0)
-    i = pl.program_id(1)
-    d = q_ref.shape[2]
-    hkv = k_ref.shape[2] // d
-    group = q_ref.shape[1] // hkv
+    rows = pl.num_programs(0)
+    block = page * block_pages
+    layer = layer_ref[0]
+    depth = pos_ref[b]  # keys 0..depth (inclusive) are valid
+    # never past the table, whatever the caller's depth says
+    n_blocks = jnp.clip(
+        depth // block + 1, 1, tables_ref.shape[1] // block_pages)
 
-    @pl.when(i == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc[:])
-        m_sc[:] = jnp.full_like(m_sc[:], NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc[:])
+    def pages_of(row, i, slot, do: str):
+        """``do`` ("start" or "wait") the copy of every page of block i of
+        ``row`` into buffers ``slot``: a page a copy a pool, in a loop and
+        not unrolled (the body's trace is set-up time of every engine)."""
+        def one_page(j, carry):
+            page_id = tables_ref[row, i * block_pages + j]
+            rows_j = pl.ds(pl.multiple_of(j * page, page), page)
+            for n, (pool, buf) in enumerate(((k_ref, kbuf), (v_ref, vbuf))):
+                getattr(pltpu.make_async_copy(
+                    pool.at[layer, page_id], buf.at[slot, rows_j],
+                    sems.at[n, slot]), do)()
+            return carry
 
-    length = lens_ref[b]  # keys 0..length (inclusive) are valid
+        jax.lax.fori_loop(0, block_pages, one_page, None)
 
-    # Pages wholly past the row's depth do no work: the decode cost of a
-    # short row is its own page count, not max_len.
-    @pl.when(i * page <= length)
-    def _compute():
-        for g in range(hkv):
-            rows = slice(g * group, (g + 1) * group)
-            q = q_ref[0, rows, :].astype(jnp.float32)  # [group, D]
-            lanes = slice(g * d, (g + 1) * d)  # head g of the merged axis
-            kb = k_ref[0, :, lanes].astype(jnp.float32)  # [page, D]
-            vb = v_ref[0, :, lanes].astype(jnp.float32)
-            if quantized:
-                # Dequant-in-kernel: int8 block * per-token scale column.
-                kb = kb * ks_ref[0, :, g:g + 1]
-                vb = vb * vs_ref[0, :, g:g + 1]
-            s = jax.lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [group, page]
-            kpos = i * page + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1
-            )
-            s = jnp.where(kpos <= length, s, NEG_INF)
-            m_prev = m_sc[rows, :]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_sc[rows, :] = l_sc[rows, :] * corr + jnp.sum(
-                p, axis=-1, keepdims=True
-            )
-            acc_sc[rows, :] = acc_sc[rows, :] * corr + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_sc[rows, :] = m_new
+    @pl.when(b == 0)
+    def _first_block_of_the_call():
+        slot_ref[0] = 0
+        pages_of(0, 0, 0, "start")
 
-    @pl.when(i == n_pages - 1)
-    def _emit():
-        o_ref[0] = (
-            acc_sc[:] / jnp.maximum(l_sc[:], 1e-30)
-        ).astype(o_ref.dtype)
+    slot0 = slot_ref[0]
+    acc_sc[:] = jnp.zeros_like(acc_sc[:])
+    m_sc[:] = jnp.full_like(m_sc[:], NEG_INF)
+    l_sc[:] = jnp.zeros_like(l_sc[:])
+
+    def per_head(scales):
+        """[Hkv, block] a kv head and position -> [H, block] a query head."""
+        return jnp.dot(
+            member, scales, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+
+    if quantized:
+        member = _membership(acc_sc.shape[0], ks_ref.shape[2], jnp.float32)
+
+    def one_block(i, carry):
+        slot = (slot0 + i) % 2
+
+        # the next block — this row's, or the next row's first — arrives
+        # in the other buffer while this one is computed
+        @pl.when(i + 1 < n_blocks)
+        def _next_block():
+            pages_of(b, i + 1, 1 - slot, "start")
+
+        @pl.when(jnp.logical_and(i + 1 == n_blocks, b + 1 < rows))
+        def _next_row():
+            pages_of(b + 1, 0, 1 - slot, "start")
+
+        pages_of(b, i, slot, "wait")
+        q = q_ref[0]  # [H, Hkv*D]
+        s = jax.lax.dot_general(
+            q, kbuf[slot].astype(q.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [H, block]
+        if quantized:
+            s = s * per_head(ks_ref[0, i])
+        kpos = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos <= depth, s, NEG_INF)
+        m_prev = m_sc[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        fix = jnp.exp(m_prev - m_new)
+        l_sc[:] = l_sc[:] * fix + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            # float32 operands, all of their bits: a scaled probability
+            # rounded to the queries' dtype and then the sum at the output
+            # would round a sharp row's value twice (an int8 value is exact)
+            weights, values, bits = (
+                p * per_head(vs_ref[0, i]), vbuf[slot].astype(jnp.float32),
+                jax.lax.Precision.HIGHEST)
+        else:
+            weights, values, bits = (
+                p.astype(q.dtype), vbuf[slot].astype(q.dtype), None)
+        acc_sc[:] = acc_sc[:] * fix + jax.lax.dot_general(
+            weights, values, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=bits,
+        )
+        m_sc[:] = m_new
+        return carry
+
+    # block 0 holds position 0, which every row may see: the running
+    # maximum is finite from the first block on
+    jax.lax.fori_loop(0, n_blocks, one_block, None)
+    slot_ref[0] = (slot0 + n_blocks) % 2
+    o_ref[0] = (acc_sc[:] / l_sc[:]).astype(o_ref.dtype)
 
 
 # repolint: allow(jit-donation-decision) — functional attention op: the
 # K/V pages belong to the serving engine's donated cache (aliased at the
 # PROGRAM boundary, not here) and q is read by the caller's residual.
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(
+    jax.jit, static_argnames=("scale", "block_pages", "interpret"))
 def _paged_call(q, k_pages, v_pages, scales, layer, block_tables, lengths,
-                interpret):
+                *, scale, block_pages, interpret):
     """``k_pages``/``v_pages`` are STACKED [L, P, page, Hkv*D] pools and
     ``layer`` [1] picks the layer; ``scales`` is ``()`` for full-precision
     pages or the ``(k_scales, v_scales)`` [L, P, page, Hkv] pools for
     int8 pages."""
     b, h, d = q.shape
     n_pages = block_tables.shape[1]
-    page, hkv = k_pages.shape[2], k_pages.shape[3] // d
+    page, w = k_pages.shape[2:]
+    hkv = w // d
+    block = block_pages * page
+    max_blocks = n_pages // block_pages
     kernel = functools.partial(
         _paged_kernel,
-        page=page, n_pages=n_pages, scale=1.0 / (d**0.5),
+        page=page, block_pages=block_pages, scale=scale,
         quantized=bool(scales),
     )
-    row_spec = pl.BlockSpec(
-        (1, h, d), lambda bi, i, layer, tables, lens: (bi, 0, 0)
+    # the rows' scales, a block a leading index: [B, blocks, Hkv, block]
+    scales = tuple(
+        sc[layer[0], block_tables].reshape(b, max_blocks, block, hkv)
+        .swapaxes(2, 3) for sc in scales
     )
-    page_spec = pl.BlockSpec(
-        (None, 1, page, hkv * d),
-        lambda bi, i, layer, tables, lens: (layer[0], tables[bi, i], 0, 0),
-    )
+    row_spec = pl.BlockSpec((1, h, w), lambda bi, *_: (bi, 0, 0))
     scale_spec = pl.BlockSpec(
-        (None, 1, page, hkv),
-        lambda bi, i, layer, tables, lens: (layer[0], tables[bi, i], 0, 0),
-    )
+        (1, max_blocks, hkv, block), lambda bi, *_: (bi, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, n_pages),
-        in_specs=[row_spec, page_spec, page_spec]
+        grid=(b,),
+        in_specs=[row_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
         + [scale_spec] * len(scales),
         out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((2, block, w), k_pages.dtype),
+            pltpu.VMEM((2, block, w), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((h, w), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    o_wide = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_struct(
-            (b, h, d), q.dtype, q, k_pages, v_pages, *scales
-        ),
+            (b, h, w), q.dtype, q, k_pages, v_pages, *scales),
         interpret=interpret,
-        # Rows are independent; the page dim carries the online-softmax
-        # state.
+        # a row's last block starts the next row's first copy, and the
+        # buffer in turn is carried from row to row: the rows run in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("arbitrary",)
         ),
-        name="paged_decode_attention",
-    )(layer, block_tables, lengths, q, k_pages, v_pages, *scales)
+        name=KERNEL_NAME,
+    )(layer, block_tables, lengths, _spread_heads(q, hkv), k_pages, v_pages,
+      *scales)
+    return _own_lanes(o_wide, hkv)
 
 
 def paged_decode_attention(
@@ -211,6 +325,7 @@ def paged_decode_attention(
     k_scales: jax.Array | None = None,  # [P, page, Hkv] f32 (int8 pages)
     v_scales: jax.Array | None = None,
     layer: jax.Array | int | None = None,
+    scale: float | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Paged single-query attention, [B, H, D] -> [B, H, D]. ``lengths``
@@ -224,9 +339,12 @@ def paged_decode_attention(
     cache and the kernel reads layer ``layer`` of them in place. Without
     it the pools are one layer's, as in the signature.
 
-    ``k_scales``/``v_scales`` switch to the int8 kernel: pages are int8
-    with per-token/per-head f32 scales and dequantization happens in
-    VMEM (the bandwidth-bound read moves quarter-width pages)."""
+    ``scale`` multiplies the scores; left out it is D^-1/2 (a family whose
+    attention is scaled by a published multiplier passes its own).
+
+    ``k_scales``/``v_scales`` switch to int8 pages with per-token/per-head
+    f32 scales, met in VMEM (the bandwidth-bound read moves quarter-width
+    pages)."""
     if interpret is None:
         platform = jax.devices()[0].platform
         if platform != "tpu":
@@ -260,13 +378,15 @@ def paged_decode_attention(
         jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(lengths, jnp.int32),
-        bool(interpret),
+        scale=float(d**-0.5 if scale is None else scale),
+        block_pages=key_block_pages(block_tables.shape[1], k_pages.shape[2]),
+        interpret=bool(interpret),
     )
 
 
 def paged_decode_attention_reference(
     q, k_pages, v_pages, block_tables, lengths,
-    k_scales=None, v_scales=None,
+    k_scales=None, v_scales=None, scale=None,
 ) -> jax.Array:
     """Pure-XLA reference: gather the per-row page view (dequantizing it
     when scale pools are given) and run the dense masked-softmax math
@@ -297,7 +417,8 @@ def paged_decode_attention_reference(
         cv = jnp.repeat(cv, rep, axis=2)
     scores = jnp.einsum(
         "bhd,bshd->bhs", q, ck, preferred_element_type=jnp.float32
-    ) / (d**0.5)
+    )
+    scores = scores / (d**0.5) if scale is None else scores * scale
     kpos = jnp.arange(s, dtype=jnp.int32)
     valid = kpos[None, None, :] <= jnp.asarray(lengths, jnp.int32)[
         :, None, None

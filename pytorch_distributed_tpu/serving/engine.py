@@ -424,6 +424,7 @@ class DecodeEngine:
             "prefix_hits": None,
             "evictions": None,
             "kv_quant": "none",
+            "paged_decode_impl": None,
             "kv_bytes_per_position": _kv_bytes_per_position(self.cfg),
             "state_bytes_per_row": decode.serving(
                 self.cfg).state_bytes_per_row,
@@ -2657,6 +2658,7 @@ class BatchedDecodeEngine:
             "prefix_hits": None,
             "evictions": None,
             "kv_quant": "none",
+            "paged_decode_impl": None,
             # what one cache position costs across all layers, in the
             # family's own page layout (per-head K and V, or one latent),
             # and what a ROW costs whatever its depth (recurrent state)
@@ -2863,16 +2865,18 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
     model (quarantine, dispatch recovery, snapshot/replay) carry over
     unchanged — a failed dispatch consumed the donated POOL, so recovery
     additionally resets the block pool and prefix cache (the content the
-    cache keys pointed at is gone). For the dense families attention
-    defaults to the pure-XLA ``gather_pages`` fallback (bit-identical
-    math to the dense engine — the paged-vs-dense token-equality pins in
+    cache keys pointed at is gone). For a family that has a dense
+    [B, max_len] cache too (gpt2, llama) attention defaults to the
+    pure-XLA ``gather_pages`` fallback (bit-identical math to the dense
+    engine — the paged-vs-dense token-equality pins in
     tests/test_serving_paged.py rely on it); on TPU,
     ``paged_attention="kernel"`` dispatches the Pallas paged-attention
     decode kernel (ops/paged_kernel.py), whose per-row cost scales with
-    the row's page count. A family whose pool is a LATENT pool
-    (kimi_k2) defaults to ``"auto"``: its decode step reads the pool
-    through ops/latent_paged_kernel.py on a TPU and through the gathered
-    window elsewhere (``stats()["latent_decode_impl"]`` says which).
+    the row's depth. A family that has none (kimi_k2, granitemoehybrid)
+    defaults to ``"auto"``: its decode step reads the pool through its
+    kernel (ops/latent_paged_kernel.py, ops/paged_kernel.py) on a TPU and
+    through the gathered window elsewhere (``stats()["paged_decode_impl"]``
+    says which).
 
     Knobs: ``page_size`` (tokens per KV page; must divide ``max_len``),
     ``pool_pages`` (pool capacity incl. the reserved scratch page 0;
@@ -2989,13 +2993,15 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
 
         self.pool = BlockPool(self.pool_pages, self.page_size, self.chunk)
         if paged_attention is None:
-            # Left unset, a LATENT pool is read by its kernel wherever one
-            # can run (models/kimi_k2.attend_absorbed: the gather copies
-            # every row's whole table a layer, whatever its depth); the
-            # dense families keep the gather, which is bit-identical to
-            # the dense engine's math.
+            # Left unset, the pages are read by a kernel wherever one can
+            # run (the gather copies every row's whole table a layer,
+            # whatever its depth) by a family that has no dense
+            # [B, max_len] cache; one that has keeps the gather, which is
+            # bit-identical to the dense engine's math (the paged-vs-dense
+            # token equality of tests/test_serving_paged.py rests on it): a
+            # family with no dense engine has nothing to be identical to.
             paged_attention = (
-                "auto" if self._family.latent_pool else "gather"
+                "gather" if self._family.dense_cache else "auto"
             )
         if paged_attention == "auto":
             paged_attention = (
@@ -3021,7 +3027,6 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             "adapters": bool(self.adapters),
             "speculative_k": bool(self.speculative_k),
             "handoff": self.role != "colocated",
-            "paged_kernel": self._paged_impl != "gather",
         }
         for feature, why in self._unserved.items():
             if asked.get(feature):
@@ -3175,10 +3180,9 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             prefix_hits=ps["prefix_hits"],
             evictions=ps["evictions"],
             kv_quant=self.kv_quant,
+            # what the decode program reads the pages through
+            paged_decode_impl=self._paged_impl,
         )
-        if self._family.latent_pool:
-            # what the decode program reads the latent pool through
-            out["latent_decode_impl"] = self._paged_impl
         out["counters"]["session_evictions"] = self._sessions.evictions
         return out
 
@@ -3956,6 +3960,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             did.update(
                 state_rows_advanced=len(ready), kv_positions_read=reach,
                 latent_positions_read=reach,
+                kv_positions_window=self.slots * self.max_len,
                 latent_positions_window=self.slots * self.max_len)
         for name in self._family.counters:
             if name in did:

@@ -26,7 +26,7 @@ from pytorch_distributed_tpu.config import (  # noqa: E402
 )
 from pytorch_distributed_tpu.models import decode  # noqa: E402
 from pytorch_distributed_tpu.models import granitemoehybrid as gmh  # noqa: E402
-from pytorch_distributed_tpu.ops import ssm  # noqa: E402
+from pytorch_distributed_tpu.ops import paged_kernel, ssm  # noqa: E402
 from pytorch_distributed_tpu.serving.engine import (  # noqa: E402
     BatchedDecodeEngine,
     PagedBatchedDecodeEngine,
@@ -344,9 +344,10 @@ def test_the_published_preset_and_what_it_declares():
     asks = decode.serving(cfg)
     assert asks.state_bytes_per_row == 36 * (
         64 * 64 * 128 * 4 + 3 * 4352 * 2)
-    assert not asks.dense_cache and not asks.latent_pool
+    assert not asks.dense_cache
     assert asks.aux_counts == ("ssm_tokens_live", "ssm_tokens_computed")
-    assert asks.counters == ("state_rows_advanced", "kv_positions_read")
+    assert asks.counters == (
+        "state_rows_advanced", "kv_positions_read", "kv_positions_window")
     cache = jax.eval_shape(lambda: decode.init_paged_cache(
         cfg, 2049, 64, rows=32))
     assert {k: (v.shape, v.dtype.name) for k, v in cache.items()} == {
@@ -420,11 +421,85 @@ def test_paged_engine_serves_the_reference_greedy_tokens(params, warm):
     # the first token is the prefill's; each later one a decode lane's
     assert c["ssm_tokens_live.decode_step"] == c["state_rows_advanced"] == 7 * 5
     assert c["ssm_tokens_computed.decode_step"] % 4 == 0
-    assert c["kv_positions_read"] > 7 * 5
+    # a decode dispatch's window is every row's whole table, whatever is
+    # in it; what the rows reach is a part of it
+    assert 7 * 5 < c["kv_positions_read"] < c["kv_positions_window"]
+    assert c["kv_positions_window"] % (4 * MAX_LEN) == 0
     assert st["prefix_queries"] == 0 and st["prefix_hits"] == 0
     assert st["state_bytes_per_row"] == 18 * (8 * 8 * 16 * 4 + 3 * 128 * 4)
     assert st["kv_bytes_per_position"] == 2 * 2 * 2 * 8 * 4
-    assert "latent_decode_impl" not in st
+    assert st["paged_decode_impl"] == "gather"
+
+
+def test_the_kernel_engine_serves_the_gather_engines_tokens(
+        params, warm, monkeypatch):
+    """``paged_attention="kernel_interpret"``: the decode step reads the
+    attention layers' pages through ops/paged_kernel.py (blocks of two
+    pages, so the deeper rows take several), scaled by
+    ``attention_multiplier``: the gather engine's tokens, the reference's
+    greedy continuations. The same engine over a configuration whose
+    multiplier is D^-1/2 (what the kernel scales by when nobody says) does
+    not serve them: the scale reaches the kernel."""
+    monkeypatch.setattr(paged_kernel, "KEY_BLOCK", 2 * PAGE)
+    rng = np.random.default_rng(0)
+    sent = [rng.integers(0, MODEL["vocab_size"], n).tolist()
+            for n in (5, 19, 8, 30, 11, 3, 17)]
+    eng = engine(paged_attention="kernel_interpret")
+    assert eng.stats()["paged_decode_impl"] == "kernel_interpret"
+    got = serve(eng, params, sent)
+    assert got == serve(warm, params, sent)
+    assert all(is_greedy_reference(params, p, g) for p, g in zip(sent, got))
+    c = eng.stats()["counters"]
+    assert 0 < c["kv_positions_read"] < c["kv_positions_window"]
+    wrong = PagedBatchedDecodeEngine(
+        program_config(attention_multiplier=CFG.head_dim ** -0.5), slots=4,
+        max_len=MAX_LEN, page_size=PAGE, prefill_chunk=CHUNK,
+        paged_attention="kernel_interpret")
+    assert not all(is_greedy_reference(params, p, g)
+                   for p, g in zip(sent, serve(wrong, params, sent)))
+
+
+def test_one_decode_step_through_the_kernel_gives_the_gathers_logits(
+        params, monkeypatch):
+    """``forward(paged_impl="kernel_interpret")`` on rows at depths inside a
+    block, on its last position and on the next one's first, beside a free
+    lane: the gather path's logits. ATOL: the softmax's sums block by
+    block."""
+    monkeypatch.setattr(paged_kernel, "KEY_BLOCK", 2 * PAGE)
+    depths = [5, 2 * PAGE - 1, 2 * PAGE, 0]
+    ids, tables = prompts(4, 2 * PAGE + 1, seed=8), tables_for(4)
+    cache = fresh_cache(4)
+    for row, depth in enumerate(depths[:3]):  # row 3 stays free
+        _, cache, _ = forward(
+            params, ids[row:row + 1, :depth], cache, [0],
+            tables[row:row + 1], rows=jnp.asarray([row]))
+    last = jnp.stack([ids[r, d] for r, d in enumerate(depths)])[:, None]
+    live = jnp.asarray([True, True, True, False])[:, None]
+    tables = tables.at[3].set(0)
+    got, want = (
+        gmh.forward(params, last, CFG, cache, jnp.asarray(depths), tables,
+                    live=live, paged_impl=impl)[0]
+        for impl in ("kernel_interpret", "gather"))
+    assert float(want[:3].std()) > 0.1
+    np.testing.assert_allclose(got[:3], want[:3], atol=ATOL, rtol=0)
+
+
+@pytest.mark.parametrize("preset,dense_cache", [
+    ("gpt2-large", True), ("llama3-1b", True), ("kimi-k2.5-ep32", False),
+    ("granite-4.0-h-micro", False)])
+def test_left_unset_every_family_builds_the_gather_off_the_chip(
+        preset, dense_cache):
+    """``paged_attention`` unset is "auto" (the kernel on a TPU, the gather
+    here) for a family with no dense cache and "gather" for the others;
+    ``stats()`` names what the decode program was built with. (An engine
+    allocates and compiles nothing until it is warmed.)"""
+    cfg = model_config(preset, dtype="bfloat16")
+    assert decode.serving(cfg).dense_cache == dense_cache
+    for asked, built in ((None, "gather"), ("auto", "gather"),
+                         ("kernel", "kernel")):
+        eng = PagedBatchedDecodeEngine(
+            cfg, slots=2, max_len=128, page_size=64, paged_attention=asked)
+        assert eng.stats()["paged_decode_impl"] == built
 
 
 def test_a_padded_prefill_group_leaves_the_real_rows_pages_alone(
@@ -559,7 +634,6 @@ def test_snapshot_and_restore_rebuild_the_state_from_the_tokens(
     (dict(speculative_k=2), "roll a recurrent state back"),
     (dict(role="prefill"), "ships pages"),
     (dict(role="decode"), "ships pages"),
-    (dict(paged_attention="kernel"), "attention_multiplier"),
     ("export_handoff", "ships pages"),
     ("import_handoff", "ships pages"),
     ("dense engine", "per-row state"),

@@ -10,7 +10,11 @@ layer scan was copied out whole, 1.4 GB an iteration, and an in-projection
 of 8512 columns, not whole lanes, was copied into another layout, 1.25 GB a
 dispatch); and the prefill program holds no per-position state
 ``[256, 64, 64, 128]`` among its temporaries (the chunked form never has
-one).
+one). The decode step is compiled both ways: as the chip builds it, its four
+attention layers reading their pages through ops/paged_kernel.py (one custom
+call in the period's body, and no ``[32, 4096, 512]`` window of K or V, nor
+its ``[32, 4096, 8, 64]`` relayout, anywhere in the program), and with the
+gathered window, the path off the chip.
 
 One file, the topology described inside a fixture: only the worker that is
 given this file loads the TPU's library (on-chip-measurement guide, 2).
@@ -26,6 +30,7 @@ import pytest
 
 from pytorch_distributed_tpu.config import model_config
 from pytorch_distributed_tpu.models import decode, get_model
+from pytorch_distributed_tpu.ops.paged_kernel import KERNEL_NAME
 from pytorch_distributed_tpu.serving.engine import PagedBatchedDecodeEngine
 
 # the cell's own engine arguments
@@ -35,6 +40,9 @@ HBM = 16e9
 # one position's state over a whole chunk, float32: what a scan that kept
 # every position's state would hold
 PER_POSITION_STATE_BYTES = ENGINE["prefill_chunk"] * 64 * 64 * 128 * 4
+# one [32, 4096, 512] bf16 window: what the gather path copies the rows'
+# tables into, for K and again for V, in every attention layer
+WINDOW_BYTES = ENGINE["slots"] * ENGINE["max_len"] * 512 * 2
 
 
 @pytest.fixture(scope="module")
@@ -52,11 +60,14 @@ def one_chip():
 
 @pytest.fixture(scope="module")
 def described(one_chip):
-    """(engine, abstract, abstract params, abstract cache) on the chip."""
+    """(engine by paged_attention, abstract, abstract params, abstract
+    cache) on the chip."""
     cfg = model_config(
         "granite-4.0-h-micro", dtype="bfloat16", param_dtype="bfloat16",
         n_ctx=ENGINE["max_len"])
-    eng = PagedBatchedDecodeEngine(cfg, **ENGINE)
+    engines = {impl: PagedBatchedDecodeEngine(
+        cfg, paged_attention=impl, **ENGINE) for impl in ("kernel", "gather")}
+    eng = engines["gather"]
 
     def abstract(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -66,13 +77,16 @@ def described(one_chip):
         lambda: get_model(cfg).init(jax.random.key(0), cfg)))
     cache = abstract(jax.eval_shape(lambda: decode.init_paged_cache(
         cfg, eng.pool_pages, eng.page_size, rows=eng.slots)))
-    return eng, abstract, params, cache
+    return engines, abstract, params, cache
 
 
-@pytest.mark.parametrize("kind", ["decode_step", "prefill"])
+@pytest.mark.parametrize("kind,impl", [
+    ("decode_step", "kernel"), ("decode_step", "gather"),
+    ("prefill", "kernel")])
 def test_program_compiles_for_v5e_and_updates_its_cache_in_place(
-        kind, described):
-    eng, abstract, params, cache = described
+        kind, impl, described):
+    engines, abstract, params, cache = described
+    eng = engines[impl]
     assert {k: v.shape for k, v in cache.items()} == {
         "k": (4, 2049, 64, 512), "v": (4, 2049, 64, 512),
         "ssm": (36, 33, 64, 64, 128), "conv": (36, 3, 33, 4352)}
@@ -98,15 +112,27 @@ def test_program_compiles_for_v5e_and_updates_its_cache_in_place(
     leaves = {",".join(map(str, v.shape)) for v in cache.values()}
     smallest_stack = 36 * 4096 * 2048  # the Mamba layers' out-projections
     copies = []
-    for shape in re.findall(r"= \w+\[([\d,]+)\][^ ]* copy\(",
-                            compiled.as_text()):
+    text = compiled.as_text()
+    for shape in re.findall(r"= \w+\[([\d,]+)\][^ ]* copy\(", text):
         elements = int(np.prod([int(d) for d in shape.split(",")]))
         if shape in leaves or elements >= smallest_stack:
             copies.append(shape)
     assert not copies, copies
+    # the kernel: one custom call in the period's body (its one attention
+    # layer), none in the prefill program (a chunk is many queries a row)
+    # nor on the gather path, which alone holds the window and its relayout
+    calls = len(re.findall(
+        rf'custom-call\(.*custom_call_target="tpu_custom_call".*'
+        rf'{KERNEL_NAME}', text))
+    windows = re.findall(r"bf16\[32,4096,(?:512|8,64)\]", text)
     if kind == "prefill":
+        assert calls == 0
         assert memory.temp_size_in_bytes < PER_POSITION_STATE_BYTES
+    elif impl == "kernel":
+        assert calls == 1 and not windows
+        assert memory.temp_size_in_bytes < WINDOW_BYTES
     else:
         # the gathered window of four attention layers' K and V, nothing
         # that grows with the weights
-        assert memory.temp_size_in_bytes < 1.0e9
+        assert calls == 0 and windows
+        assert WINDOW_BYTES < memory.temp_size_in_bytes < 1.0e9

@@ -18,7 +18,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from perfbench.reference import kimi_k2 as ref  # noqa: E402
 from pytorch_distributed_tpu.config import ModelConfig, model_config  # noqa: E402
 from pytorch_distributed_tpu.models import decode, kimi_k2  # noqa: E402
-from pytorch_distributed_tpu.ops import moe, rope  # noqa: E402
+from pytorch_distributed_tpu.ops import moe, paged_kernel, rope  # noqa: E402
 from pytorch_distributed_tpu.serving.engine import (  # noqa: E402
     BatchedDecodeEngine,
     PagedBatchedDecodeEngine,
@@ -127,11 +127,11 @@ def test_paged_engine_serves_the_reference_greedy_tokens(
     reads the pool through ops/latent_paged_kernel.py (blocks of two pages,
     so the deeper rows take several)."""
     if paged_attention:
-        monkeypatch.setattr(kimi_k2, "KEY_BLOCK", 2 * PAGE)
+        monkeypatch.setattr(paged_kernel, "KEY_BLOCK", 2 * PAGE)
     eng = PagedBatchedDecodeEngine(
         CFG, slots=4, max_len=MAX_LEN, page_size=PAGE, prefill_chunk=8,
         paged_attention=paged_attention)
-    assert eng.stats()["latent_decode_impl"] == (paged_attention or "gather")
+    assert eng.stats()["paged_decode_impl"] == (paged_attention or "gather")
     eng.warmup(params)
     compiled = eng.compile_count()
     rng = np.random.default_rng(0)
@@ -171,7 +171,7 @@ def test_absorbed_equals_expanded_on_the_same_cache(params, monkeypatch):
     """One query token against 21 cached positions, all three readings:
     expanded, absorbed through the gathered window, absorbed through the
     kernel (blocks of two pages: three of them)."""
-    monkeypatch.setattr(kimi_k2, "KEY_BLOCK", 2 * PAGE)
+    monkeypatch.setattr(paged_kernel, "KEY_BLOCK", 2 * PAGE)
     ids = prompts(2, 22, seed=3)
     tables = tables_for(2)
     pool = decode.init_paged_cache(CFG, 2 * (MAX_LEN // PAGE) + 1, PAGE)
@@ -350,10 +350,9 @@ def test_engines_refuse_what_they_cannot_serve(params):
         eng = PagedBatchedDecodeEngine(
             CFG, slots=2, max_len=MAX_LEN, page_size=PAGE,
             paged_attention=asked)
-        assert eng.stats()["latent_decode_impl"] == built
+        assert eng.stats()["paged_decode_impl"] == built
     dense = PagedBatchedDecodeEngine(
         model_config("tiny"), slots=2, max_len=64, page_size=16)
-    assert dense._paged_impl == "gather"
-    assert "latent_decode_impl" not in dense.stats()
+    assert dense.stats()["paged_decode_impl"] == "gather"
     with pytest.raises(KeyError, match="kimi-k2.5-ep32"):
         model_config("no-such-preset")

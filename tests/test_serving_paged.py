@@ -494,6 +494,68 @@ def test_quarantine_bypasses_prefix_cache():
     np.testing.assert_array_equal(out[rid].tokens, ref)
 
 
+# (H, Hkv, D, page, KEY_BLOCK): a table of 16 pages is two blocks of 8
+_KERNEL_SHAPES = {
+    "grouped": (32, 8, 64, 64, 512),  # granite-4.0-h-micro's attention
+    "ungrouped": (4, 4, 16, 16, 128),
+}
+# rows' depths in blocks and positions: a free lane (its table all scratch),
+# inside the first page, a block's last position, the next block's first,
+# the table's last
+_KERNEL_DEPTHS = {
+    "block_edges": [(1, -1), (1, 0), (2, -1)],
+    "free_and_shallow": [None, (0, 3), None, (1, 70)],
+}
+
+
+@pytest.mark.parametrize("scale", [None, 0.015625])
+@pytest.mark.parametrize("depths", list(_KERNEL_DEPTHS))
+@pytest.mark.parametrize("shape", list(_KERNEL_SHAPES))
+def test_paged_kernel_reads_blocks_of_pages_to_each_rows_depth(
+        shape, depths, scale, monkeypatch):
+    """ops/paged_kernel.py (blocks of pages by async copies, two products a
+    block for all heads) against the gathered reference: rows that end
+    inside the first page, on a block's last position and on the next
+    block's first, a free lane at depth 0; D^-1/2 and a published
+    multiplier; table entries past a row's pages pointing at a scratch page
+    of large numbers, which the mask must give weight 0."""
+    from pytorch_distributed_tpu.ops import paged_kernel as pk
+
+    h, hkv, d, page, block = _KERNEL_SHAPES[shape]
+    monkeypatch.setattr(pk, "KEY_BLOCK", block)
+    n_pages = 16
+    assert pk.key_block_pages(n_pages, page) == 8
+    depths = [at and at[0] * block + at[1] for at in _KERNEL_DEPTHS[depths]]
+    rng = np.random.default_rng(11)
+    b, pool = len(depths), len(depths) * n_pages + 1
+    q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
+    k, v = (rng.normal(size=(pool, page, hkv * d)) for _ in range(2))
+    k[0], v[0] = 3e4 * rng.normal(size=(2,) + k[0].shape)  # the scratch page
+    free = list(rng.permutation(np.arange(1, pool)))
+    tables = np.zeros((b, n_pages), np.int32)
+    for i, depth in enumerate(depths):
+        if depth is not None:
+            need = depth // page + 1
+            tables[i, :need] = [free.pop() for _ in range(need)]
+    lengths = np.asarray([depth or 0 for depth in depths], np.int32)
+    k, v = jnp.asarray(k, jnp.float32), jnp.asarray(v, jnp.float32)
+    kw = {} if scale is None else {"scale": scale}
+    out = pk.paged_decode_attention(
+        q, k, v, tables, lengths, interpret=True, **kw)
+    with jax.default_matmul_precision("highest"):
+        ref = pk.paged_decode_attention_reference(
+            q, k, v, tables, lengths, **kw)
+    assert np.isfinite(np.asarray(out)).all()
+    live = [i for i, depth in enumerate(depths) if depth is not None]
+    np.testing.assert_allclose(
+        np.asarray(out)[live], np.asarray(ref)[live], rtol=1e-5, atol=1e-5)
+    # and the two scales are two answers
+    if scale is not None:
+        other = pk.paged_decode_attention(
+            q, k, v, tables, lengths, interpret=True)
+        assert np.abs(np.asarray(other - out)[live]).max() > 1e-3
+
+
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
 def test_paged_kernel_matches_gather_fallback(dtype, tol):
     """The Pallas paged-attention kernel (interpret mode on this rig)
