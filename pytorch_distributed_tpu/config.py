@@ -32,7 +32,11 @@ class ModelConfig:
     # or "granitemoehybrid" (Granite 4.0-H: Mamba-2 layers and a few
     # attention layers in a repeating pattern, no position encoding, the
     # four Granite multipliers; served only —
-    # models/granitemoehybrid.py; fields after kimi_k2's).
+    # models/granitemoehybrid.py; fields after kimi_k2's) — or "mellum"
+    # (Mellum 2: grouped-query attention whose layers are sliding-window
+    # or full by ``layer_types``, each kind with its own rotary table, then
+    # softmax-routed dropless experts with no shared one; served only —
+    # models/mellum.py; fields last).
     family: str = "gpt2"
 
     vocab_size: int = 50257
@@ -188,14 +192,54 @@ class ModelConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
 
+    # -- family "mellum": the published config.json keys --------------------
+    # (hidden_size, num_hidden_layers, num_attention_heads,
+    # num_key_value_heads, rms_norm_eps, vocab_size, num_experts,
+    # num_experts_per_tok and moe_intermediate_size are n_embd, n_layer,
+    # n_head, n_kv_head, layer_norm_epsilon, vocab_size, n_routed_experts,
+    # num_experts_per_tok and moe_intermediate_size above; layer_types is
+    # granitemoehybrid's field with the kinds "sliding_attention" /
+    # "full_attention".) A head is attn_head_dim wide whatever n_embd /
+    # n_head says (0: that quotient). A sliding layer's query at position i
+    # attends keys i - sliding_window + 1 .. i and rotates by plain
+    # rope_theta; a full layer attends all keys <= i and rotates by YaRN
+    # (rope_factor, rope_original_max_position, rope_beta_fast / _slow
+    # above) with cos and sin times rope_attention_factor. The router is a
+    # softmax over all n_routed_experts; the num_experts_per_tok largest are
+    # chosen and, with norm_topk_prob, renormalised to sum to 1.
+    attn_head_dim: int = 0
+    sliding_window: int = 0
+    rope_attention_factor: float = 1.0
+    norm_topk_prob: bool = True
+
     def __post_init__(self) -> None:
         if self.n_embd % self.n_head != 0:
             raise ValueError(
                 f"n_embd={self.n_embd} not divisible by n_head={self.n_head}"
             )
         if self.family not in ("gpt2", "llama", "kimi_k2",
-                               "granitemoehybrid"):
+                               "granitemoehybrid", "mellum"):
             raise ValueError(f"unknown model family: {self.family!r}")
+        if self.family == "mellum":
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if not (
+                len(self.layer_types) == self.n_layer
+                and set(self.layer_types)
+                <= {"sliding_attention", "full_attention"}
+                and ("sliding_attention" not in self.layer_types
+                     or self.sliding_window > 0)
+                and 0 < self.num_experts_per_tok <= self.n_routed_experts
+                and self.n_head % self.kv_heads == 0
+                and not self.n_experts
+            ):
+                raise ValueError(
+                    "mellum: need one of 'sliding_attention' / "
+                    "'full_attention' for each of the n_layer layers, a "
+                    "sliding_window where a layer slides, 0 < "
+                    "num_experts_per_tok <= n_routed_experts, whole groups "
+                    "of query heads a kv head, and n_experts 0 (that "
+                    "selects the capacity-routed layer)"
+                )
         if self.family == "granitemoehybrid":
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if not (
@@ -272,7 +316,7 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.n_embd // self.n_head
+        return self.attn_head_dim or self.n_embd // self.n_head
 
     @property
     def kv_heads(self) -> int:
@@ -292,6 +336,25 @@ class ModelConfig:
         """``layer_types`` as the list a config.json holds (the field is a
         tuple because the config is hashed)."""
         return list(self.layer_types)
+
+    @property
+    def rope_parameters(self) -> dict[str, dict[str, Any]]:
+        """The rotary fields as a mellum config.json nests them under
+        ``rope_parameters``, a section a layer kind (for ``serve_holds``)."""
+        return {
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": self.rope_theta,
+                "factor": self.rope_factor,
+                "original_max_position_embeddings":
+                    self.rope_original_max_position,
+                "beta_fast": self.rope_beta_fast,
+                "beta_slow": self.rope_beta_slow,
+                "attention_factor": self.rope_attention_factor,
+            },
+            "sliding_attention": {
+                "rope_type": "default", "rope_theta": self.rope_theta,
+            },
+        }
 
     def replace(self, **kw: Any) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -366,6 +429,25 @@ _GRANITEMOEHYBRID_PRESETS: dict[str, dict[str, Any]] = {
 }
 
 
+_MELLUM_PRESETS: dict[str, dict[str, Any]] = {
+    # Stage 1 of Mellum2-12B-A2.5B-Instruct cut over depth
+    # (https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/blob/
+    # main/config.json): the first 12 of 28 layers, three whole periods
+    # [sliding x3, full]; every expert, head, width and vocabulary row as
+    # published (perfbench/configs/mellum2-12b-a2.5b-l12.json states the cut).
+    "mellum2-12b-a2.5b-l12": dict(
+        vocab_size=98304, n_ctx=131072, n_embd=2304, n_layer=12, n_head=32,
+        n_kv_head=4, attn_head_dim=128, n_inner=7168,
+        layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 3,
+        sliding_window=1024, n_routed_experts=64, num_experts_per_tok=8,
+        moe_intermediate_size=896, norm_topk_prob=True,
+        rope_theta=500000.0, rope_factor=16.0,
+        rope_original_max_position=8192, rope_beta_fast=32.0,
+        rope_beta_slow=1.0, rope_attention_factor=1.2772588722239782,
+    ),
+}
+
+
 def model_config(name: str, **overrides: Any) -> ModelConfig:
     """Look up a preset by name (the TPU-native analogue of
     ``AutoConfig.from_pretrained`` in reference train_baseline.py:24)."""
@@ -401,10 +483,21 @@ def model_config(name: str, **overrides: Any) -> ModelConfig:
             resid_pdrop=0.0,
             **_GRANITEMOEHYBRID_PRESETS[name],
         )
+    elif name in _MELLUM_PRESETS:
+        base = dict(
+            family="mellum",
+            activation_function="silu",
+            layer_norm_epsilon=1e-6,
+            embd_pdrop=0.0,
+            attn_pdrop=0.0,
+            resid_pdrop=0.0,
+            **_MELLUM_PRESETS[name],
+        )
     else:
         known = [
             *sorted(_GPT2_PRESETS), *sorted(_LLAMA_PRESETS),
             *sorted(_KIMI_K2_PRESETS), *sorted(_GRANITEMOEHYBRID_PRESETS),
+            *sorted(_MELLUM_PRESETS),
         ]
         raise KeyError(f"unknown model preset {name!r}; known: {known}")
     base.update(overrides)

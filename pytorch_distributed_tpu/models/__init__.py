@@ -61,4 +61,13 @@ def get_model(cfg: ModelConfig) -> ModelApi:
             lambda params: (params["wte"], "ve"),
             gmh.final_norm,
         )
+    if cfg.family == "mellum":
+        from pytorch_distributed_tpu.models import mellum
+
+        return ModelApi(
+            mellum.init, mellum.apply, mellum.embed, mellum.run_blocks,
+            mellum.head,
+            lambda params: (params["lm_head"], "ev"),
+            mellum.final_norm,
+        )
     raise KeyError(f"unknown model family {cfg.family!r}")

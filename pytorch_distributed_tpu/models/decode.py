@@ -91,6 +91,18 @@ class Serving(NamedTuple):
     # starts at position 0 starts from zero state; a prefix cached by another
     # row cannot be taken over (nobody holds the state at its end).
     state_bytes_per_row: int = 0
+    # Positions a row keeps in a layer of the family's WINDOW group of
+    # pages (0: one group, every layer keeps every position). Non-zero
+    # means: the cache holds a second pool ``k_w``/``v_w`` over the layers
+    # that attend a sliding window, beside ``k``/``v`` over those that
+    # attend everything (``init_paged_cache(window_pool_pages=)``); a row
+    # has two block tables, handed to ``forward`` side by side ([B, 2 n]:
+    # the full group's, then the window group's, both indexed by absolute
+    # page number); the engine releases a window-group page once no query
+    # still to come can see it; a prefix cached by another row cannot be
+    # taken over (its chunk would have to keep the window group's last
+    # ``window`` positions).
+    window: int = 0
     # names, in order, of the int32 counts ``forward(return_aux=True)``
     # hands back beside the logits, counted per program kind
     aux_counts: tuple[str, ...] = ()
@@ -101,20 +113,24 @@ class Serving(NamedTuple):
     # what a paged engine cannot serve the family with, {feature: why}:
     # "mesh", "kv_quant", "weight_quant", "adapters", "speculative_k",
     # "handoff" (a prefill or decode worker's role, or a call to export or
-    # import a row)
+    # import a row); "prefix" says why the family takes no prefix hit
+    # (nothing asks for one: it is taken or not)
     unserved: dict[str, str] = {}
 
 
 def serving(cfg: ModelConfig) -> Serving:
-    if cfg.family in ("kimi_k2", "granitemoehybrid"):
+    if cfg.family in ("kimi_k2", "granitemoehybrid", "mellum"):
         family = importlib.import_module(
             f"pytorch_distributed_tpu.models.{cfg.family}")
         return Serving(**family.serving(cfg))
     return Serving()
 
 
-def kv_bytes_per_position(cfg: ModelConfig, kv_quant: str = "none") -> int:
-    """Bytes one GLOBAL cache position costs across all layers, in the
+def kv_bytes_per_position(cfg: ModelConfig, kv_quant: str = "none",
+                          group: str = "full") -> int:
+    """Bytes one GLOBAL cache position costs across all layers (of a family
+    with two page groups, ``serving(cfg).window``: across the layers of
+    ``group``, "full" or "window"), in the
     family's own layout (TP divides the head dim across shards, so the
     global figure is the comparable one either way). Per-head K and V;
     int8 pages carry one f32 scale per token per KV head next to the
@@ -132,6 +148,9 @@ def kv_bytes_per_position(cfg: ModelConfig, kv_quant: str = "none") -> int:
     layers = cfg.n_layer
     if cfg.family == "granitemoehybrid":  # its attention layers only
         layers = cfg.layer_types.count("attention")
+    if cfg.family == "mellum":  # the layers whose pages the group holds
+        layers = cfg.layer_types.count(
+            "full_attention" if group == "full" else "sliding_attention")
     return layers * 2 * cfg.kv_heads * cfg.head_dim * itemsize
 
 
@@ -153,10 +172,11 @@ def init_cache(
         )
     if not serving(cfg).dense_cache:
         raise NotImplementedError(
-            f"the {cfg.family} family keeps per-row recurrent state beside "
-            "a paged pool (init_paged_cache(rows=)): serve it through "
-            "PagedBatchedDecodeEngine; there is no dense [B, max_len] "
-            "cache layout for it"
+            f"the {cfg.family} family keeps more than one pool of pages a "
+            "row (per-row recurrent state, init_paged_cache(rows=), or a "
+            "window group of pages, init_paged_cache(window_pool_pages=)): "
+            "serve it through PagedBatchedDecodeEngine; there is no dense "
+            "[B, max_len] cache layout for it"
         )
     dtype = jnp.dtype(dtype or cfg.dtype)
     shape = (
@@ -168,7 +188,7 @@ def init_cache(
 def init_paged_cache(
     cfg: ModelConfig, pool_pages: int, page_size: int, dtype=None,
     n_kv: int | None = None, kv_quant: str = "none",
-    rows: int | None = None,
+    rows: int | None = None, window_pool_pages: int | None = None,
 ) -> Cache:
     """Preallocate a PAGED [L, pool_pages, page_size, Hkv*D] key/value
     pool pair (serving/block_pool.py owns the host-side allocation; page
@@ -188,7 +208,11 @@ def init_paged_cache(
     (``serving(cfg).state_bytes_per_row``), how many rows the engine runs;
     the state
     leaves [L, rows + 1, ...] come back beside the pool (row ``rows`` is
-    the scratch row, as page 0 is the scratch page)."""
+    the scratch row, as page 0 is the scratch page).
+
+    ``window_pool_pages``: for a family with a window group of pages
+    (``serving(cfg).window``), that group's capacity; its pool ``k_w``/
+    ``v_w`` comes back beside ``k``/``v``, each over its own layers."""
     if kv_quant not in ("none", "int8"):
         raise ValueError(
             f"kv_quant must be 'none' or 'int8', got {kv_quant!r}"
@@ -215,6 +239,17 @@ def init_paged_cache(
 
         return granitemoehybrid.init_cache(
             cfg, pool_pages, page_size, rows, dtype)
+    if cfg.family == "mellum":
+        if kv_quant != "none" or n_kv is not None or not window_pool_pages:
+            raise NotImplementedError(
+                "mellum's cache is two unquantized, unsharded pools, one a "
+                "page group: say how many pages the window group has "
+                "(window_pool_pages=), and neither kv_quant nor n_kv"
+            )
+        from pytorch_distributed_tpu.models import mellum
+
+        return mellum.init_cache(
+            cfg, pool_pages, page_size, window_pool_pages, dtype)
     dtype = jnp.dtype(dtype or cfg.dtype)
     hkv = n_kv or cfg.kv_heads
     shape = (cfg.n_layer, pool_pages, page_size, hkv * cfg.head_dim)
@@ -250,7 +285,8 @@ def gather_pages(pool: jax.Array, layer, block_tables: jax.Array):
 
 
 def _cached_attention(q, cache, layer, pos, block_tables=None,
-                      paged_impl="gather", kv_quant="none", scale=None):
+                      paged_impl="gather", kv_quant="none", scale=None,
+                      window=None):
     """q [B, T, H, D] against layer ``layer`` of the stacked cache
     ({"k", "v"} leaves [L, B, S, Hkv, D]); queries sit at
     global positions pos..pos+T-1, keys j are valid iff j <= pos + i.
@@ -276,7 +312,12 @@ def _cached_attention(q, cache, layer, pos, block_tables=None,
     + scales).
 
     ``scale`` multiplies the scores in place of D^-1/2 (a family whose
-    attention is scaled by a published multiplier), on either path."""
+    attention is scaled by a published multiplier), on either path.
+
+    ``window`` (a sliding-window layer): the query at position i attends
+    keys i - window + 1 .. i only. The kernel then starts at the block the
+    window begins in (the table's entries before it may be the scratch
+    page); the gather masks the same keys."""
     if block_tables is not None and q.shape[1] == 1 and (
         paged_impl in ("kernel", "kernel_interpret")
     ):
@@ -288,6 +329,8 @@ def _cached_attention(q, cache, layer, pos, block_tables=None,
             q[:, 0], cache["k"], cache["v"], block_tables, pos,
             k_scales=cache.get("k_scale"), v_scales=cache.get("v_scale"),
             layer=layer, scale=scale,
+            first=None if window is None else jnp.maximum(
+                pos - (window - 1), 0),
             interpret=paged_impl == "kernel_interpret",
         )
         return out[:, None]
@@ -330,14 +373,83 @@ def _cached_attention(q, cache, layer, pos, block_tables=None,
     kpos = jax.lax.broadcasted_iota(jnp.int32, (t, s), 1)
     if getattr(pos, "ndim", 0):  # per-row positions -> [B, 1, T, S] mask
         valid = kpos[None] <= pos[:, None, None] + qpos[None]
+        if window is not None:
+            valid &= kpos[None] > pos[:, None, None] + qpos[None] - window
         valid = valid[:, None, None] if grouped else valid[:, None]
         scores = jnp.where(valid, scores, -1e30)
     else:
-        scores = jnp.where(kpos <= pos + qpos, scores, -1e30)
+        valid = kpos <= pos + qpos
+        if window is not None:
+            valid &= kpos > pos + qpos - window
+        scores = jnp.where(valid, scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
     if grouped:
         return jnp.einsum("bgrts,bsgd->btgrd", w, cv).reshape(b, t, h, d)
     return jnp.einsum("bhts,bshd->bthd", w, cv)
+
+
+def blocked_attention(q, cache, layer, pos, block_tables, *, window=None,
+                      scale=None):
+    """q [B, T, H, D] at positions pos[b]..pos[b]+T-1 against layer
+    ``layer`` of the paged pools {"k", "v"} [L, P, page, Hkv*D], a block of
+    cache positions at a time (``ops/paged_kernel.key_block_pages``) under a
+    running (online) softmax in float32: exact, each row to its own depth
+    pos[b] + T and, with ``window`` (a sliding-window layer: the query at i
+    attends keys i - window + 1 .. i), from the block its first query's
+    window begins in, so no temporary grows with the table's length and the
+    table's entries behind the window are never read (the plan of
+    ``models/kimi_k2.attend_expanded``). Returns [B, T, H, D]."""
+    from pytorch_distributed_tpu.ops.paged_kernel import key_block_pages
+
+    b, t, h, d = q.shape
+    page, width = cache["k"].shape[2:]
+    hkv = width // d
+    kb_pages = key_block_pages(block_tables.shape[1], page)
+    kb = kb_pages * page
+    scale = d**-0.5 if scale is None else scale
+
+    def one_row(args):
+        q_b, table, p0 = args  # [T, H, D], [n_pages], ()
+        qpos = p0 + jnp.arange(t, dtype=jnp.int32)
+        qg = q_b.reshape(t, hkv, h // hkv, d)
+
+        def one_block(i, carry):
+            m, l, acc = carry
+            pids = jax.lax.dynamic_slice_in_dim(table, i * kb_pages, kb_pages)
+            k_blk, v_blk = (
+                cache[n][layer, pids].reshape(kb, hkv, d).astype(q.dtype)
+                for n in ("k", "v"))
+            s = jnp.einsum(
+                "tgrd,sgd->grts", qg, k_blk,
+                preferred_element_type=jnp.float32,
+            ) * scale
+            kpos = i * kb + jnp.arange(kb, dtype=jnp.int32)
+            seen = kpos[None, :] <= qpos[:, None]
+            if window is not None:
+                seen &= kpos[None, :] > qpos[:, None] - window
+            s = jnp.where(seen, s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            # (a query whose keys of this block all lie behind its window
+            # keeps m at -1e30: its p is masked, not exp(0))
+            p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+            fix = jnp.exp(m - m_new)
+            acc = acc * fix[..., None] + jnp.einsum(
+                "grts,sgd->grtd", p.astype(q.dtype), v_blk,
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, l * fix + jnp.sum(p, axis=-1), acc
+
+        lo = 0 if window is None else jnp.maximum(p0 - (window - 1), 0) // kb
+        m, l, acc = jax.lax.fori_loop(
+            lo, (p0 + t + kb - 1) // kb, one_block, (
+                jnp.full((hkv, h // hkv, t), -1e30, jnp.float32),
+                jnp.zeros((hkv, h // hkv, t), jnp.float32),
+                jnp.zeros((hkv, h // hkv, t, d), jnp.float32),
+            ))
+        return (acc / l[..., None]).transpose(2, 0, 1, 3).reshape(
+            t, h, d).astype(q.dtype)
+
+    return jax.lax.map(one_row, (q, block_tables, pos))
 
 
 def _write(leaf, layer, new, pos, block_tables=None):
@@ -703,6 +815,23 @@ def forward(
             params, input_ids, cfg, cache, pos, block_tables,
             state_rows=state_rows, live=live, logits_index=logits_index,
             paged_impl=paged_impl,
+        )
+        return (logits, cache, aux) if return_aux else (logits, cache)
+    if cfg.family == "mellum":
+        if (block_tables is None or tensor_axis is not None
+                or block_transform is not None or lora is not None
+                or kv_quant != "none"):
+            raise NotImplementedError(
+                "the mellum family runs on its two page groups on one "
+                "device (block_tables given, both tables side by side; no "
+                "tensor axis, ZeRO-3 transform, LoRA or quantized pages): "
+                "models/mellum.forward"
+            )
+        from pytorch_distributed_tpu.models import mellum
+
+        logits, cache, aux = mellum.forward(
+            params, input_ids, cfg, cache, pos, block_tables,
+            live=live, logits_index=logits_index, paged_impl=paged_impl,
         )
         return (logits, cache, aux) if return_aux else (logits, cache)
     if cfg.family == "gpt2":

@@ -54,10 +54,12 @@ is computed per token-shard and averaged (the standard distributed
 convention — differs from the global-batch product only at O(1e-4) on
 balanced batches).
 
-``moe_dropless`` (the kimi_k2 family's layer, serving): no capacity and no
-drop. The router scores ALL experts with a sigmoid, picks ``top_k`` by
-score + selection bias, and gates by the picked scores renormalised and
-scaled; the layer is told which experts it HOLDS (``expert_offset`` .. +
+``moe_dropless`` (the kimi_k2 and mellum families' layer, serving): no
+capacity and no drop. The router scores ALL experts by the family's rule
+(``route``): a sigmoid, ``top_k`` picked by score + selection bias, gates the
+picked scores renormalised and scaled (kimi_k2, beside a shared expert every
+token passes through); or a softmax over all experts, its ``top_k`` largest
+renormalised, and no shared expert (mellum). The layer is told which experts it HOLDS (``expert_offset`` .. +
 the expert stacks' leading size), computes those experts' part for the
 pairs (token, expert) routed to them, and leaves the absent experts' part
 out — what an expert-parallel chip computes before the exchange. The pairs
@@ -326,6 +328,20 @@ def _route_sigmoid(xt, router, bias, top_k: int, routed_scale: float):
     return idx, gates * routed_scale
 
 
+def _route_softmax(xt, router, top_k: int, renormalise: bool):
+    """(expert_idx [T, K], gates [T, K] f32). In float32: p = softmax(x @
+    router) over ALL experts; the K largest are chosen; with ``renormalise``
+    (HF ``norm_topk_prob``) the gates are the chosen p over their sum, else
+    the chosen p as they are."""
+    p = jax.nn.softmax(
+        xt.astype(jnp.float32) @ router.astype(jnp.float32), axis=-1
+    )  # [T, X]
+    chosen, idx = jax.lax.top_k(p, top_k)
+    if renormalise:
+        chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx, chosen
+
+
 def dropless_block_rows(tokens: int, top_k: int, n_experts: int) -> int:
     """Rows of one expert block: the power of two from 8 to 128 that
     holds four times an expert's expected load of ``tokens`` tokens, so
@@ -383,21 +399,31 @@ def _experts_grouped(xt, stacks, layer, e_flat, counts, gates, rows,
 
 def moe_dropless(
     xt: jax.Array,  # [T, D]
-    params: dict,  # router [D, X], bias [X]; w_gate, w_in [H, D, F],
-    #               w_out [H, F, D] of the H experts held ([Le, H, ...]
-    #               where ``layer`` is given); shared {gate [D, Fs],
-    #               up [D, Fs], down [Fs, D]}
+    params: dict,  # router [D, X], bias [X] (sigmoid rule); w_gate, w_in
+    #               [H, D, F], w_out [H, F, D] of the H experts held
+    #               ([Le, H, ...] where ``layer`` is given); optionally
+    #               shared {gate [D, Fs], up [D, Fs], down [Fs, D]}
     *,
     top_k: int,
     expert_offset: int,
-    routed_scale: float,
+    routed_scale: float = 1.0,
     activation,
     live: jax.Array | None = None,  # [T] bool: rows that are tokens
     layer: jax.Array | None = None,  # index into whole expert stacks
+    route: str = "sigmoid",
+    renormalise: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (output [T, D], counts [3] int32): pairs (token, expert)
     routed to experts held here, rows the expert products ran over,
-    experts held that received a token. See the module docstring."""
+    experts held that received a token. ``route``: the routing rule,
+    "sigmoid" (``_route_sigmoid``: scores + selection bias, renormalised,
+    times ``routed_scale``) or "softmax" (``_route_softmax``: softmax over
+    all experts, top-k, renormalised where ``renormalise``). A layer with no
+    ``shared`` entry has no shared expert. See the module docstring."""
+    if route not in ("sigmoid", "softmax"):
+        raise ValueError(
+            f"moe_dropless: route must be 'sigmoid' or 'softmax', got "
+            f"{route!r}")
     t = xt.shape[0]
     stacks = {name: params[name] if layer is not None else params[name][None]
               for name in ("w_gate", "w_in", "w_out")}
@@ -405,9 +431,13 @@ def moe_dropless(
     held = stacks["w_in"].shape[1]
 
     with jax.named_scope("moe_route"):
-        idx, gates = _route_sigmoid(
-            xt, params["router"], params["bias"], top_k, routed_scale
-        )
+        if route == "sigmoid":
+            idx, gates = _route_sigmoid(
+                xt, params["router"], params["bias"], top_k, routed_scale
+            )
+        else:
+            idx, gates = _route_softmax(
+                xt, params["router"], top_k, renormalise)
         local = idx - expert_offset
         here = (local >= 0) & (local < held)
         if live is not None:
@@ -422,13 +452,17 @@ def moe_dropless(
             xt, stacks, layer, e_flat, counts, gates, rows, activation
         )
 
-    with jax.named_scope("moe_shared"):
-        sh = params["shared"]
-        g = activation(xt @ sh["gate"].astype(xt.dtype))
-        u = xt @ sh["up"].astype(xt.dtype)
-        shared = (g * u) @ sh["down"].astype(xt.dtype)
+    shared = None
+    if "shared" in params:
+        with jax.named_scope("moe_shared"):
+            sh = params["shared"]
+            g = activation(xt @ sh["gate"].astype(xt.dtype))
+            u = xt @ sh["up"].astype(xt.dtype)
+            shared = (g * u) @ sh["down"].astype(xt.dtype)
 
     stats = jnp.stack([
         jnp.sum(here), n_blocks * rows, jnp.sum(counts > 0),
     ]).astype(jnp.int32)
-    return (routed + shared.astype(jnp.float32)).astype(xt.dtype), stats
+    if shared is not None:
+        routed = routed + shared.astype(jnp.float32)
+    return routed.astype(xt.dtype), stats

@@ -54,6 +54,14 @@ plan:
   gather path copies), arrive a row a grid step, and reach the heads'
   [H, block] through one small product with the heads' 0/1 membership.
 
+A layer that attends a sliding WINDOW passes each row's first visible
+position (``first`` [B], a fourth scalar-prefetch operand, present only
+then: ``windowed`` is static, and a call without it traces the kernel it
+always did): the row's loop starts at block ``first // block`` instead of
+0, keys before ``first`` are masked, and the blocks behind the window are
+neither copied nor scored (their table entries may point at the scratch
+page: the pool released them).
+
 Every page of a started block is copied whole, also the pages past the
 row's depth (table entries past a row's pages point at the scratch page):
 the mask gives them probability 0, and what they hold is the pool's, never
@@ -133,18 +141,20 @@ def _paged_kernel(
     layer_ref,  # [1] int32 (scalar prefetch)
     tables_ref,  # [B, n_pages] int32 (scalar prefetch)
     pos_ref,  # [B] int32 (scalar prefetch): the row's query position
-    q_ref,  # [1, H, Hkv*D]: the row's queries, spread (``_spread_heads``)
-    k_ref,  # [L, P, page, Hkv*D], in HBM: read by the copies below only
-    v_ref,
-    *rest,  # int8 pages: the row's scales ks_ref, vs_ref [1, blocks, Hkv,
-    # block] f32; then o_ref [1, H, Hkv*D]; then the scratch: kbuf, vbuf
-    # [2, block, Hkv*D] (the block being computed and the one arriving), a
-    # DMA semaphore a pool and buffer [2, 2], slot [1] int32 in SMEM (the
-    # buffer this row's first block is in), acc [H, Hkv*D], m, l [H, 1] f32
+    *rest,  # windowed: first_ref [B] int32 (scalar prefetch), the row's
+    # first visible key; then q_ref [1, H, Hkv*D]: the row's queries,
+    # spread (``_spread_heads``); k_ref, v_ref [L, P, page, Hkv*D], in HBM:
+    # read by the copies below only; int8 pages: the row's scales ks_ref,
+    # vs_ref [1, blocks, Hkv, block] f32; then o_ref [1, H, Hkv*D]; then the
+    # scratch: kbuf, vbuf [2, block, Hkv*D] (the block being computed and
+    # the one arriving), a DMA semaphore a pool and buffer [2, 2], slot [1]
+    # int32 in SMEM (the buffer this row's first block is in), acc
+    # [H, Hkv*D], m, l [H, 1] f32
     page: int,
     block_pages: int,
     scale: float,
     quantized: bool,
+    windowed: bool,
 ):
     """Online softmax over one row's blocks of pages. With ``quantized``
     the copies move INT8 K/V pages — HBM traffic for a page drops to a
@@ -153,6 +163,9 @@ def _paged_kernel(
     full-precision kernel's exactly (same accumulator dtypes, same
     masking), so quantized-vs-gather equivalence is pinned the same way
     (tests/test_quant.py)."""
+    if windowed:
+        first_ref, *rest = rest
+    q_ref, k_ref, v_ref, *rest = rest
     if quantized:
         ks_ref, vs_ref, *rest = rest
     o_ref, kbuf, vbuf, sems, slot_ref, acc_sc, m_sc, l_sc = rest
@@ -160,10 +173,23 @@ def _paged_kernel(
     rows = pl.num_programs(0)
     block = page * block_pages
     layer = layer_ref[0]
-    depth = pos_ref[b]  # keys 0..depth (inclusive) are valid
-    # never past the table, whatever the caller's depth says
-    n_blocks = jnp.clip(
-        depth // block + 1, 1, tables_ref.shape[1] // block_pages)
+    max_blocks = tables_ref.shape[1] // block_pages
+
+    def end_block(depth):
+        """One past the last block a row at ``depth`` reads: never past the
+        table, whatever the caller's depth says."""
+        return jnp.clip(depth // block + 1, 1, max_blocks)
+
+    def first_block(row):
+        """The block the row's first visible key lies in (0: no window)."""
+        if not windowed:
+            return 0
+        return jnp.clip(
+            first_ref[row] // block, 0, end_block(pos_ref[row]) - 1)
+
+    depth = pos_ref[b]  # keys first..depth (inclusive) are valid
+    n_blocks = end_block(depth)
+    lo = first_block(b)
 
     def pages_of(row, i, slot, do: str):
         """``do`` ("start" or "wait") the copy of every page of block i of
@@ -183,7 +209,7 @@ def _paged_kernel(
     @pl.when(b == 0)
     def _first_block_of_the_call():
         slot_ref[0] = 0
-        pages_of(0, 0, 0, "start")
+        pages_of(0, lo, 0, "start")
 
     slot0 = slot_ref[0]
     acc_sc[:] = jnp.zeros_like(acc_sc[:])
@@ -201,7 +227,7 @@ def _paged_kernel(
         member = _membership(acc_sc.shape[0], ks_ref.shape[2], jnp.float32)
 
     def one_block(i, carry):
-        slot = (slot0 + i) % 2
+        slot = (slot0 + i - lo) % 2 if windowed else (slot0 + i) % 2
 
         # the next block — this row's, or the next row's first — arrives
         # in the other buffer while this one is computed
@@ -211,7 +237,8 @@ def _paged_kernel(
 
         @pl.when(jnp.logical_and(i + 1 == n_blocks, b + 1 < rows))
         def _next_row():
-            pages_of(b + 1, 0, 1 - slot, "start")
+            nxt = b + 1
+            pages_of(nxt, first_block(nxt), 1 - slot, "start")
 
         pages_of(b, i, slot, "wait")
         q = q_ref[0]  # [H, Hkv*D]
@@ -222,7 +249,10 @@ def _paged_kernel(
         if quantized:
             s = s * per_head(ks_ref[0, i])
         kpos = i * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos <= depth, s, NEG_INF)
+        seen = kpos <= depth
+        if windowed:
+            seen = jnp.logical_and(seen, kpos >= first_ref[b])
+        s = jnp.where(seen, s, NEG_INF)
         m_prev = m_sc[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -245,10 +275,11 @@ def _paged_kernel(
         m_sc[:] = m_new
         return carry
 
-    # block 0 holds position 0, which every row may see: the running
-    # maximum is finite from the first block on
-    jax.lax.fori_loop(0, n_blocks, one_block, None)
-    slot_ref[0] = (slot0 + n_blocks) % 2
+    # the row's first block holds its first visible key (position 0 where
+    # there is no window): the running maximum is finite from it on
+    jax.lax.fori_loop(lo, n_blocks, one_block, None)
+    slot_ref[0] = (slot0 + n_blocks - lo) % 2 if windowed else (
+        slot0 + n_blocks) % 2
     o_ref[0] = (acc_sc[:] / l_sc[:]).astype(o_ref.dtype)
 
 
@@ -258,11 +289,12 @@ def _paged_kernel(
 @functools.partial(
     jax.jit, static_argnames=("scale", "block_pages", "interpret"))
 def _paged_call(q, k_pages, v_pages, scales, layer, block_tables, lengths,
-                *, scale, block_pages, interpret):
+                first, *, scale, block_pages, interpret):
     """``k_pages``/``v_pages`` are STACKED [L, P, page, Hkv*D] pools and
     ``layer`` [1] picks the layer; ``scales`` is ``()`` for full-precision
     pages or the ``(k_scales, v_scales)`` [L, P, page, Hkv] pools for
-    int8 pages."""
+    int8 pages; ``first`` is ``()`` or ``(first [B],)``, a window's first
+    visible key a row."""
     b, h, d = q.shape
     n_pages = block_tables.shape[1]
     page, w = k_pages.shape[2:]
@@ -272,7 +304,7 @@ def _paged_call(q, k_pages, v_pages, scales, layer, block_tables, lengths,
     kernel = functools.partial(
         _paged_kernel,
         page=page, block_pages=block_pages, scale=scale,
-        quantized=bool(scales),
+        quantized=bool(scales), windowed=bool(first),
     )
     # the rows' scales, a block a leading index: [B, blocks, Hkv, block]
     scales = tuple(
@@ -283,7 +315,7 @@ def _paged_call(q, k_pages, v_pages, scales, layer, block_tables, lengths,
     scale_spec = pl.BlockSpec(
         (1, max_blocks, hkv, block), lambda bi, *_: (bi, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + len(first),
         grid=(b,),
         in_specs=[row_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
         + [scale_spec] * len(scales),
@@ -310,8 +342,8 @@ def _paged_call(q, k_pages, v_pages, scales, layer, block_tables, lengths,
             dimension_semantics=("arbitrary",)
         ),
         name=KERNEL_NAME,
-    )(layer, block_tables, lengths, _spread_heads(q, hkv), k_pages, v_pages,
-      *scales)
+    )(layer, block_tables, lengths, *first, _spread_heads(q, hkv), k_pages,
+      v_pages, *scales)
     return _own_lanes(o_wide, hkv)
 
 
@@ -326,11 +358,14 @@ def paged_decode_attention(
     v_scales: jax.Array | None = None,
     layer: jax.Array | int | None = None,
     scale: float | None = None,
+    first: jax.Array | None = None,  # [B] int32: a window's first key
     interpret: bool | None = None,
 ) -> jax.Array:
     """Paged single-query attention, [B, H, D] -> [B, H, D]. ``lengths``
     is each row's query position: key j is attended iff j <= lengths[b]
-    (the dense decode-step mask at T=1). ``interpret=None`` means the
+    (the dense decode-step mask at T=1) and, where ``first`` is given (a
+    sliding-window layer), j >= first[b]: the blocks before it are not
+    read, so their table entries may be the scratch page. ``interpret=None`` means the
     compiled kernel and is an error off the chip — interpreter mode is
     never chosen for the caller.
 
@@ -378,6 +413,7 @@ def paged_decode_attention(
         jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(lengths, jnp.int32),
+        () if first is None else (jnp.asarray(first, jnp.int32),),
         scale=float(d**-0.5 if scale is None else scale),
         block_pages=key_block_pages(block_tables.shape[1], k_pages.shape[2]),
         interpret=bool(interpret),
@@ -386,7 +422,7 @@ def paged_decode_attention(
 
 def paged_decode_attention_reference(
     q, k_pages, v_pages, block_tables, lengths,
-    k_scales=None, v_scales=None, scale=None,
+    k_scales=None, v_scales=None, scale=None, first=None,
 ) -> jax.Array:
     """Pure-XLA reference: gather the per-row page view (dequantizing it
     when scale pools are given) and run the dense masked-softmax math
@@ -423,6 +459,10 @@ def paged_decode_attention_reference(
     valid = kpos[None, None, :] <= jnp.asarray(lengths, jnp.int32)[
         :, None, None
     ]
+    if first is not None:
+        valid &= kpos[None, None, :] >= jnp.asarray(first, jnp.int32)[
+            :, None, None
+        ]
     scores = jnp.where(valid, scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum(

@@ -40,6 +40,18 @@ Three responsibilities:
    write simply goes to a fresh page). tests/test_serving_paged.py pins
    the fork case.
 
+4. **Page groups.** A family whose layers come in two kinds, some attending
+   every position and some a sliding window (``decode.Serving.window``),
+   keeps a pool a kind: the engine holds one ``BlockPool`` a group, each
+   counting its own pages, and a row has a table in each, indexed by
+   absolute page number. The full group's pages are held to the row's whole
+   depth; a window group's page is released as soon as no query still to
+   come can see it (``first_kept_page``), so a row never holds more than
+   ``window_pages_bound`` of them whatever its depth, and the released
+   table entries point at the scratch page. A window group shares nothing:
+   a cached chunk would have to keep the last ``window`` positions before
+   it, so its pool's prefix cache stays empty.
+
 Page id 0 is RESERVED as the scratch page: block-table entries default
 to 0, so free/garbage rows in the oblivious decode dispatch write and
 read page 0 — which no live row's table ever points at. (Concurrent
@@ -53,6 +65,21 @@ import dataclasses
 import hashlib
 
 import numpy as np
+
+
+def first_kept_page(next_pos: int, window: int, page_size: int) -> int:
+    """The first page of a window group's table that a query at position
+    ``next_pos`` or later can still see: the one that holds position
+    ``next_pos - window + 1``. Every page before it lies wholly behind the
+    window of every query to come and goes back to the pool."""
+    return max(next_pos - window + 1, 0) // page_size
+
+
+def window_pages_bound(window: int, chunk_tokens: int, page_size: int) -> int:
+    """The most pages of a window group one row holds: while a chunk is
+    written at p, positions p - window + 1 .. p + chunk - 1, which may begin
+    and end inside a page."""
+    return -(-(window - 1 + chunk_tokens) // page_size) + 1
 
 
 @dataclasses.dataclass

@@ -1144,7 +1144,8 @@ class BatchedDecodeEngine:
         ):
             raise NotImplementedError(
                 f"the {cfg.family} family is served from a paged pool "
-                "only (a latent pool, or pages beside per-row state): "
+                "only (a latent pool, pages beside per-row state, or two "
+                "groups of pages): "
                 "serve it through PagedBatchedDecodeEngine "
                 "(decode.init_cache has no dense layout for it)"
             )
@@ -2793,6 +2794,11 @@ class _PagedSlot(_Slot):
     prefill_keydata: np.ndarray | None = None  # key for the final chunk draw
     resume_base: int = 0  # len(resume gen) riding ahead of fresh tokens
     chain_key: str = ""  # prefix-cache chain key at pos (1 digest/publish)
+    # a family with a window group of pages: the row's window table is the
+    # second half of ``table``, by absolute page number; it holds pages
+    # wfirst .. wnext - 1 and those before point at the scratch page
+    wfirst: int = 0
+    wnext: int = 0
 
     @property
     def ready(self) -> bool:
@@ -2878,6 +2884,19 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
     through the gathered window elsewhere (``stats()["paged_decode_impl"]``
     says which).
 
+    **Page groups** (a family whose ``decode.Serving.window`` is set: some
+    layers attend a sliding window): a second ``BlockPool`` (``wpool``,
+    ``window_pool_pages``; left unset, ``slots`` x the most one row holds
+    + the scratch page) feeds a second block table a row, handed to the
+    programs beside the first ([slots, 2 max_pages]). Admission takes the
+    prompt's pages of the full group and the first chunk's of the window
+    group; every chunk and decode step grows both; after each, the window
+    pages no query to come can see go back to their pool
+    (``block_pool.first_kept_page``) and their table entries to the scratch
+    page. Either group running dry preempts as the one pool does. Such a
+    family takes no prefix hit, and preemption, ``snapshot``/``restore``
+    rebuild its window pages by re-prefill from position 0.
+
     Knobs: ``page_size`` (tokens per KV page; must divide ``max_len``),
     ``pool_pages`` (pool capacity incl. the reserved scratch page 0;
     default = dense-equivalent ``slots * max_len/page_size + 1``),
@@ -2928,6 +2947,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         session_pin_budget_pages: int | None = None,
         batch_admit_free_frac: float = 0.25,
         role: str = "colocated",
+        window_pool_pages: int | None = None,
         **kw,
     ) -> None:
         if page_size < 1 or max_len % page_size:
@@ -2989,9 +3009,38 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 "never be served"
             )
         self.pool_pages = int(pool_pages)
-        from pytorch_distributed_tpu.serving.block_pool import BlockPool
+        from pytorch_distributed_tpu.serving.block_pool import (
+            BlockPool,
+            window_pages_bound,
+        )
 
         self.pool = BlockPool(self.pool_pages, self.page_size, self.chunk)
+        # A window group of pages beside the pool (``decode.Serving.window``;
+        # 0: there is none and a row has one table).
+        self._window = self._family.window
+        self.wpool = None
+        if self._window:
+            self._window_row_bound = window_pages_bound(
+                self._window, self.chunk, self.page_size)
+            if window_pool_pages is None:
+                window_pool_pages = slots * self._window_row_bound + 1
+            if window_pool_pages < self._window_row_bound + 1:
+                raise ValueError(
+                    f"window_pool_pages ({window_pool_pages}) must be >= "
+                    f"{self._window_row_bound + 1}: what one row holds of "
+                    f"the window group while a chunk is written (a window "
+                    f"of {self._window}, chunks of {self.chunk}, pages of "
+                    f"{self.page_size}) plus the scratch page"
+                )
+            self.window_pool_pages = int(window_pool_pages)
+            self.wpool = BlockPool(
+                self.window_pool_pages, self.page_size, self.chunk)
+        elif window_pool_pages is not None:
+            raise ValueError(
+                f"window_pool_pages: the {cfg.family} family has no window "
+                "group of pages")
+        # a row's tables side by side, as the programs take them
+        self._table_width = self.max_pages * (2 if self._window else 1)
         if paged_attention is None:
             # Left unset, the pages are read by a kernel wherever one can
             # run (the gather copies every row's whole table a layer,
@@ -3036,6 +3085,10 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         # decode step is state row i, a prefill dispatch names its rows'
         # slots, and row ``slots`` is the scratch row.
         self._row_state = self._family.state_bytes_per_row
+        # Neither rows with state nor rows with a window group take a prefix
+        # another row cached: nothing is matched, published or pinned, and
+        # ``prefix_queries`` stays 0.
+        self._no_prefix = bool(self._row_state or self._window)
         # A model that counts its work has its programs hand the counts
         # back between the tokens and the sentinel; they accumulate here
         # per program kind, beside what the engine itself knows of a
@@ -3122,6 +3175,8 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             self.cfg, self.pool_pages, self.page_size, n_kv=self._n_kv,
             kv_quant=self.kv_quant,
             rows=self.slots if self._row_state else None,
+            window_pool_pages=(
+                self.window_pool_pages if self._window else None),
         )
         if self.device is not None:
             # Committed inputs pin every jitted program's outputs to the
@@ -3137,12 +3192,19 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         rows (pages_in_use x page_size positions), to set against the
         dense engine's slots x max_len."""
         per = self._bytes_per_position()
-        return {
+        out = {
             "allocated": self.pool_pages * self.page_size * per,
             "peak_in_use": (
                 self.pool.stats["peak_pages_in_use"] * self.page_size * per
             ),
         }
+        if self._window:
+            wper = _kv_bytes_per_position(self.cfg, group="window")
+            out["allocated"] += (
+                self.window_pool_pages * self.page_size * wper)
+            out["peak_in_use"] += (
+                self.wpool.stats["peak_pages_in_use"] * self.page_size * wper)
+        return out
 
     def stats(self) -> dict[str, Any]:
         """The uniform snapshot with the paged fields filled in: page
@@ -3184,6 +3246,12 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             paged_decode_impl=self._paged_impl,
         )
         out["counters"]["session_evictions"] = self._sessions.evictions
+        if self._window:
+            out.update(
+                window_pool_pages=self.window_pool_pages,
+                window_free_pages=self.wpool.free_pages(),
+                window_pages_in_use=self.wpool.pages_in_use(),
+            )
         return out
 
     # -- programs ----------------------------------------------------------
@@ -3405,7 +3473,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         pages release so retention sees them resident), then hand the
         tracker the new transcript + the full chain to pin."""
         toks = self._partial_tokens(s.prompt, s.generated)
-        if self._row_state:
+        if self._no_prefix:
             # nothing published, nothing to pin: the transcript alone
             self._sessions.on_turn_done(s.session, toks, [])
             return
@@ -3587,10 +3655,11 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         rounded up to the chunk the padded final prefill writes."""
         prefix = self._partial_tokens(req.prompt, req.gen)
         plen = prefix.shape[0]
-        if req.nan_retried or self._row_state:
-            # (nor where rows hold recurrent state: a cached prefix's pages
-            # come without the state at its end, so nothing is matched,
-            # published or pinned, and ``prefix_queries`` stays 0)
+        if req.nan_retried or self._no_prefix:
+            # (nor where rows hold recurrent state or a window group of
+            # pages: a cached prefix's pages come without the state at its
+            # end, or the window's positions before it, so nothing is
+            # matched, published or pinned, and ``prefix_queries`` stays 0)
             cached, shared, chain_key = 0, [], ""
         else:
             cached, shared, chain_key = self.pool.match_prefix(
@@ -3603,9 +3672,18 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             # the hit counters are concerned — a head-of-line request
             # retrying every tick must not inflate the committed stats.
             # (A quarantine retry never queried, so nothing to cancel.)
-            if not (req.nan_retried or self._row_state):
+            if not (req.nan_retried or self._no_prefix):
                 self.pool.cancel_match(cached, shared)
             return None
+        wfresh = []
+        if self._window:
+            # the window group: the first chunk's pages now, the rest chunk
+            # by chunk as the pages behind the window come back
+            wfresh = self.wpool.alloc(
+                min(ext, self.chunk) // self.page_size)
+            if wfresh is None:
+                self.pool.release(fresh)
+                return None
         if cached:
             log_event(
                 "prefix_hit", rid=req.rid, cached_tokens=cached,
@@ -3613,8 +3691,9 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 quant=self.kv_quant if self.kv_quant != "none" else None,
             )
         pids = list(shared) + fresh
-        table = np.zeros((self.max_pages,), np.int32)
+        table = np.zeros((self._table_width,), np.int32)
         table[: len(pids)] = pids
+        table[self.max_pages: self.max_pages + len(wfresh)] = wfresh
         if req.session is not None:
             # First admission of a session turn commits its prefix-hit
             # economics (preemption re-admissions are de-duplicated by
@@ -3632,7 +3711,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             prefix=prefix, prefill_len=plen, table=table, pids=pids,
             n_pages=len(pids), prefill_keydata=req.prefill_keydata,
             resume_base=len(req.gen), chain_key=chain_key,
-            stamps=req.stamps,
+            stamps=req.stamps, wnext=len(wfresh),
         )
 
     def _chunk_prefill_tick(self, params, finished: list[int]) -> None:
@@ -3664,6 +3743,14 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 (i, s) for i, s in rows
                 if s.tier != TIER_RANK[BATCH]
             ]
+        if rows and self._window:
+            # each row's window table grown over the chunk it writes now
+            # (a group run dry preempts for the pages: the rows still in
+            # their slots afterwards are the ones that go)
+            for i, s in rows:
+                if self._slots[i] is s:
+                    self._grow_window(i, s, s.pos + self.chunk, finished)
+            rows = [(i, s) for i, s in rows if self._slots[i] is s]
         if not rows:
             return
         with self.timers.span("engine.build.prefill"):
@@ -3673,7 +3760,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             chunks = np.zeros((npad, self.chunk), np.int32)
             valid = np.ones((npad,), np.int32)
             start = np.zeros((npad,), np.int32)
-            tables = np.zeros((npad, self.max_pages), np.int32)
+            tables = np.zeros((npad, self._table_width), np.int32)
             greedy = np.zeros((npad,), np.bool_)
             t = np.ones((npad,), np.float32)
             k = np.full((npad,), self.cfg.vocab_size, np.int32)
@@ -3725,7 +3812,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                     )
                     continue
                 v = min(self.chunk, s.prefill_len - s.pos)
-                if v == self.chunk and not self._row_state:
+                if v == self.chunk and not self._no_prefix:
                     # A full chunk lies entirely inside the prefix:
                     # publish its pages for prefix sharing (clean chunks
                     # only — a flagged row never contaminates the cache).
@@ -3757,6 +3844,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                             t=round(self._clock(), 6),
                         )
                     self._maybe_retire(row, finished)
+        self._release_behind_window()
 
     def _grow_for_drafts(self, s: _PagedSlot, n: int) -> int:
         """Best-effort block-table growth covering the row's draft
@@ -3899,7 +3987,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             b = self.slots
             toks = np.zeros((b,), np.int32)
             pos = np.zeros((b,), np.int32)
-            tables = np.zeros((b, self.max_pages), np.int32)
+            tables = np.zeros((b, self._table_width), np.int32)
             folds = np.zeros((b,), np.int32)
             greedy = np.ones((b,), np.bool_)
             t = np.ones((b,), np.float32)
@@ -3942,6 +4030,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 s.pos += 1
                 s.fold += 1
                 self._maybe_retire(i, finished)
+        self._release_behind_window()
 
     def _count_dispatch(self, kind: str, aux, tokens: int, ready=()) -> None:
         """One dispatch onto the counters of its program kind: the counts
@@ -3962,6 +4051,18 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 latent_positions_read=reach,
                 kv_positions_window=self.slots * self.max_len,
                 latent_positions_window=self.slots * self.max_len)
+            did["kv_positions_read.full"] = reach
+            if self._window:
+                # a layer of the window group: the window's keys a row;
+                # and, sampled here, what that group's pages hold of all
+                # rows beside what some row's window needs of it
+                did["kv_positions_read.window"] = sum(
+                    min(s.pos + 1, self._window) for _, s in ready)
+                live = [s for s in self._slots if s is not None]
+                did["window_positions_held"] = self.page_size * sum(
+                    s.wnext - s.wfirst for s in live)
+                did["window_positions_needed"] = sum(
+                    min(s.pos + 1, self._window) for s in live)
         for name in self._family.counters:
             if name in did:
                 self.counters[name] += did[name]
@@ -3986,43 +4087,91 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 continue
             if skip_batch and s.tier == TIER_RANK[BATCH]:
                 continue
-            if s.pos // self.page_size < s.n_pages:
-                continue
-            while True:
-                got = self.pool.alloc(1)
-                if got is not None:
-                    s.table[s.n_pages] = got[0]
-                    s.pids += got
-                    s.n_pages += 1
-                    break
-                # Retention must never deadlock allocation: idle-session
-                # pins break (loudly) before any live row is preempted.
-                if self._sessions.evict_idle():
-                    continue
-                others = [
-                    o.tier for o in self._slots
-                    if o is not None and o.rid != s.rid
-                ]
-                if others and max(others) < s.tier:
-                    # Every neighbour strictly outranks this row: IT is
-                    # the lowest-priority occupant, so it yields its own
-                    # pages (a batch row must never evict interactive
-                    # state to keep growing) — clean resume entry, like
-                    # any other preemption.
-                    self._preempt_row(i)
-                    break
-                if not self._preempt_one(exclude_rid=s.rid, finished=finished):
-                    from pytorch_distributed_tpu.serving.lifecycle import (
-                        PagePoolExhausted,
-                    )
+            if s.pos // self.page_size >= s.n_pages:
+                pid = self._take_page(self.pool, i, s, finished)
+                if pid is None:
+                    continue  # the row itself yielded its pages
+                s.table[s.n_pages] = pid
+                s.pids.append(pid)
+                s.n_pages += 1
+            if self._window:
+                self._grow_window(i, s, s.pos + 1, finished)
 
-                    raise PagePoolExhausted(
-                        f"no KV page available for rid {s.rid} at depth "
-                        f"{s.pos} and nothing left to preempt — "
-                        f"pool_pages={self.pool_pages} cannot hold one "
-                        "row this deep (construction should have "
-                        "rejected this configuration)"
-                    )
+    def _take_page(self, pool, i: int, s: _PagedSlot, finished) -> int | None:
+        """One page of ``pool`` (a group's) for row ``i``, preempting for it
+        where the group has run dry; None where row ``i`` itself was
+        preempted (it is the lowest-priority occupant)."""
+        while True:
+            got = pool.alloc(1)
+            if got is not None:
+                return got[0]
+            # Retention must never deadlock allocation: idle-session
+            # pins break (loudly) before any live row is preempted.
+            if self._sessions.evict_idle():
+                continue
+            others = [
+                o.tier for o in self._slots
+                if o is not None and o.rid != s.rid
+            ]
+            if others and max(others) < s.tier:
+                # Every neighbour strictly outranks this row: IT is
+                # the lowest-priority occupant, so it yields its own
+                # pages (a batch row must never evict interactive
+                # state to keep growing) — clean resume entry, like
+                # any other preemption.
+                self._preempt_row(i)
+                return None
+            if not self._preempt_one(exclude_rid=s.rid, finished=finished):
+                from pytorch_distributed_tpu.serving.lifecycle import (
+                    PagePoolExhausted,
+                )
+
+                raise PagePoolExhausted(
+                    f"no KV page available for rid {s.rid} at depth "
+                    f"{s.pos} and nothing left to preempt — "
+                    f"pool_pages={pool.pool_pages} cannot hold one "
+                    "row this deep (construction should have "
+                    "rejected this configuration)"
+                )
+
+    def _grow_window(self, i: int, s: _PagedSlot, upto: int,
+                     finished) -> bool:
+        """Grow row ``i``'s window table over positions < ``upto`` (a chunk
+        about to be written, a decode step's one position), a page at a
+        time from the window group; False where the row was preempted for
+        the pages it lacks."""
+        mp = self.max_pages
+        while s.wnext * self.page_size < min(upto, self.max_len):
+            pid = self._take_page(self.wpool, i, s, finished)
+            if pid is None:
+                return False
+            s.table[mp + s.wnext] = pid
+            s.wnext += 1
+        return True
+
+    def _release_behind_window(self) -> None:
+        """After a dispatch: every row's window-group pages that no query
+        still to come can see (those wholly behind ``pos - window + 1``, the
+        row's next query being at ``pos``) go back to their pool, and their
+        table entries to the scratch page."""
+        if not self._window:
+            return
+        from pytorch_distributed_tpu.serving import block_pool
+
+        mp = self.max_pages
+        with self.timers.span("engine.release_window"):
+            for s in self._slots:
+                if s is None:
+                    continue
+                keep = min(block_pool.first_kept_page(
+                    s.pos, self._window, self.page_size), s.wnext)
+                if keep <= s.wfirst:
+                    continue
+                gone = s.table[mp + s.wfirst: mp + keep]
+                self.wpool.release(gone.tolist())
+                gone[:] = 0
+                self.counters["window_pages_released"] += keep - s.wfirst
+                s.wfirst = keep
 
     def _preempt_one(self, *, exclude_rid: int, finished) -> bool:
         # Preempt-lowest-priority-then-youngest (scheduler.py): the
@@ -4062,6 +4211,10 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
     def _on_slot_freed(self, s: _Slot) -> None:
         self.pool.release(s.pids)
         s.pids = []
+        if self._window:
+            mp = self.max_pages
+            self.wpool.release(s.table[mp + s.wfirst: mp + s.wnext].tolist())
+            s.wfirst = s.wnext = 0
 
     def _recover_dispatch_failure(self, kind, err, group_pendings,
                                   finished) -> None:
@@ -4073,7 +4226,10 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         for s in self._slots:
             if s is not None:
                 s.pids = []
+                s.wfirst = s.wnext = 0
         self.pool.reset()
+        if self._window:
+            self.wpool.reset()
         # Every pinned chunk's content died with the pool: drop the
         # pins (transcripts survive — the next turn re-pays prefill).
         self._sessions.on_pool_reset()
@@ -4149,7 +4305,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 jnp.zeros((npad, self.chunk), jnp.int32),
                 jnp.ones((npad,), jnp.int32),
                 jnp.zeros((npad,), jnp.int32),
-                jnp.zeros((npad, mp), jnp.int32),
+                jnp.zeros((npad, self._table_width), jnp.int32),
                 cache,
                 jnp.ones((npad,), jnp.bool_),
                 jnp.ones((npad,), jnp.float32),
@@ -4166,7 +4322,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
                 jnp.zeros((b,), jnp.int32),
                 cache,
                 jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b, mp), jnp.int32),
+                jnp.zeros((b, self._table_width), jnp.int32),
                 jnp.zeros((b,), jnp.int32),
                 jnp.ones((b,), jnp.bool_),
                 jnp.ones((b,), jnp.float32),

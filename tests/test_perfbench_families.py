@@ -11,7 +11,9 @@ the whole of it takes minutes); the two files that prove what no test under
   not correct), its two readers, and its configuration file;
 - ``perfbench/tests/test_granitemoehybrid.py``: the same for the
   granitemoehybrid cell (per-row recurrent state beside the pages), with its
-  count module held to its issue's arithmetic.
+  count module held to its issue's arithmetic;
+- ``perfbench/tests/test_mellum.py``: the same for the mellum cell (two
+  groups of pages, softmax-routed experts), with its three readers.
 
 The cases run where they are defined; this module only names them.
 """
@@ -24,10 +26,12 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from perfbench.tests import (  # noqa: E402
     test_granitemoehybrid,
     test_kimi_k2,
+    test_mellum,
     test_other_family,
 )
 
-for _module in (test_other_family, test_kimi_k2, test_granitemoehybrid):
+for _module in (test_other_family, test_kimi_k2, test_granitemoehybrid,
+                test_mellum):
     for _name, _thing in vars(_module).items():
         # its tests, and the fixture its tests ask for by name
         if _name.startswith("test_") or _name == "family":
