@@ -21,7 +21,9 @@ bf16) with random weights made from a seed:
            of 64 x 640 bf16) against the gathered window; one period of
            granite-4.0-h-micro at its published widths (recurrent state a
            row beside paged KV) on a ragged batch against the float32
-           reference; then the serve requests again through an engine
+           reference, and its state kernel (ops/ssm_kernel.py) against
+           the plain step on 32 rows of a 36-layer leaf, microseconds a
+           layer for both; then the serve requests again through an engine
            built with ``paged_attention="auto"``.
 - four_chips (only when the machine shows >= 4 devices):
            ``DistributedTrainer`` ZeRO-3 over ``fsdp=4`` on the pjit and on
@@ -128,6 +130,13 @@ PAGED_KERNEL_ATOL = 2e-2
 # bound is relative); a state not reset, a tail dropped or a padded tail run
 # on is an error of the spread itself.
 HYBRID_STATE_RTOL = 0.25
+
+# ops/ssm_kernel.py against the plain step, both float32 on the vector unit:
+# the state is one product and one sum an entry (the same roundings), y sums
+# 128 products of order 1 in another order (my chip run, PR 37: 7.6e-5 on y
+# summed over 36 layers, 0 on the state). A wrong row, head or group is an
+# error of order 1.
+STATE_STEP_ATOL = 1e-3
 
 # One-chip vs four-chip per-step loss: the same data, weights and f32
 # master state, but bf16 activations summed in another order (per-chip
@@ -568,8 +577,10 @@ def hybrid_state_case(rehearsal: bool):
     ``decode.forward`` on the state rows of ONE cache, against the float32
     reference's full forward of the same bfloat16 weights; the decode steps
     twice over, their attention layer reading its pages through the gathered
-    window and through ops/paged_kernel.py. The rows: 0 free
-    throughout; 1 a prompt shorter than a chunk (from depth 0, a padded
+    window with the plain state step, and through ops/paged_kernel.py with
+    the Mamba layers' state advanced by ops/ssm_kernel.py (the decode step's
+    rows are then the leaf's first rows, as in the engine's). The rows: 0
+    free throughout; 1 a prompt shorter than a chunk (from depth 0, a padded
     chunk); 2 and 3 either side of a chunk boundary (chunk - 1 and chunk + 1
     tokens: the second chunk holds ONE token); 4 a REUSED row, which another
     prompt ran through first. Returns ({paged_impl: max |program -
@@ -623,7 +634,8 @@ def hybrid_state_case(rehearsal: bool):
     def forward(params, cache, toks, pos, live, state_rows,
                 paged_impl="gather"):
         return decode.forward(
-            params, toks, cfg, cache, pos, block_tables=tables[state_rows],
+            params, toks, cfg, cache, pos, block_tables=(
+                tables if state_rows is None else tables[state_rows]),
             live=live, state_rows=state_rows, paged_impl=paged_impl)
 
     def call(*args, **kw):  # the weights an ARGUMENT: closed over, they are
@@ -670,7 +682,8 @@ def hybrid_state_case(rehearsal: bool):
                 toks[r, 0], pos[r] = ids[r][n + step], n + step
             lg, cache = call(
                 cache, jnp.asarray(toks), jnp.asarray(pos),
-                jnp.asarray(np.arange(rows)[:, None] > 0), jnp.arange(rows),
+                jnp.asarray(np.arange(rows)[:, None] > 0),
+                jnp.arange(rows) if impl == "gather" else None,
                 paged_impl=impl)
             for r in lengths:
                 got[r].append(np.asarray(lg[r, 0], np.float32))
@@ -680,6 +693,84 @@ def hybrid_state_case(rehearsal: bool):
         errs[impl] = max(float(np.abs(np.stack(got[r]) - want[r]).max())
                          for r in lengths)
     return errs, float(np.mean([w.std() for w in want.values()]))
+
+
+def state_step_case(rehearsal: bool):
+    """ops/ssm_kernel.py against the plain step (``ops/ssm.ssm_step`` under
+    the model's two selects and its update in place) at the
+    granite-4.0-h-micro cell's shapes: 32 batch rows on a leaf of 36 layers
+    x 33 rows x 64 heads x [64, 128] float32 (toy shapes in rehearsal), one
+    lane dead, one row beginning its sequence, every layer advanced once by
+    ONE program that scans them with the leaf as its carry (what the decode
+    step does). Returns (max |kernel - plain| over the live rows' y, over
+    the whole leaf, and microseconds a layer for both: None in rehearsal, a
+    CPU's time is no device number)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_distributed_tpu.ops.ssm import ssm_step
+    from pytorch_distributed_tpu.ops.ssm_kernel import ssm_state_step
+
+    layers, b, h, p, n = (
+        (3, 4, 8, 8, 16) if rehearsal else (36, 32, 64, 64, 128))
+    rng = np.random.default_rng(SEED)
+    live = np.ones(b, bool)
+    live[b // 2] = False
+    fresh = np.zeros(b, bool)
+    fresh[1] = True
+    live, fresh = jnp.asarray(live), jnp.asarray(fresh)
+    x = jnp.asarray(rng.standard_normal((b, h, p)), jnp.bfloat16)
+    dt = jnp.where(live[:, None], jnp.asarray(
+        rng.uniform(1e-4, 1e-2, (b, h)), jnp.float32), 0.0)
+    a = -jnp.asarray(rng.uniform(1, 16, (h,)), jnp.float32)
+    bm, cm = (jnp.asarray(rng.standard_normal((b, 1, n)), jnp.bfloat16)
+              for _ in range(2))
+    leaf0 = rng.standard_normal((layers, b + 1, h, p, n), np.float32)
+
+    def plain(leaf, layer):
+        state = leaf[layer, :b]
+        y, new = ssm_step(x, dt, a, bm, cm, jnp.where(
+            fresh[:, None, None, None], 0.0, state))
+        new = jnp.where(live[:, None, None, None], new, state)
+        return y, leaf.at[layer, :b].set(new)
+
+    def kernel(leaf, layer):
+        return ssm_state_step(
+            x, dt, a, bm, cm, leaf, layer, live, fresh, interpret=rehearsal)
+
+    def every_layer(step):
+        def body(leaf, layer):
+            y, leaf = step(leaf, layer)
+            return leaf, y
+
+        return jax.jit(lambda leaf: jax.lax.scan(
+            body, leaf, jnp.arange(layers, dtype=jnp.int32)),
+            donate_argnums=0)
+
+    out, us = {}, {}
+    for name, step in (("plain", plain), ("kernel", kernel)):
+        run = every_layer(step)
+        leaf, y = run(jnp.asarray(leaf0))
+        out[name] = (np.asarray(y), np.asarray(leaf))
+        if not rehearsal:
+            for _ in range(3):
+                leaf, y = run(leaf)
+            jax.block_until_ready(leaf)
+            t0 = time.perf_counter()
+            for _ in range(10):
+                leaf, y = run(leaf)
+            jax.block_until_ready(leaf)
+            us[name] = (time.perf_counter() - t0) / (10 * layers) * 1e6
+        del leaf, y
+    (y0, l0), (y1, l1) = out["plain"], out["kernel"]
+    on = np.asarray(live)
+    dead = ~np.append(on, False)  # the dead lane and the scratch row
+    for got in (l0, l1):  # bit for bit, through both
+        assert (got[:, dead] == leaf0[:, dead]).all()
+    assert np.isfinite(y1).all()
+    return (float(np.abs(y1 - y0)[:, on].max()), float(np.abs(l1 - l0).max()),
+            us.get("plain"), us.get("kernel"))
 
 
 def phase_kernels(params, cfg, sizes: Sizes, requests, served,
@@ -742,6 +833,32 @@ def phase_kernels(params, cfg, sizes: Sizes, requests, served,
             f"{HYBRID_STATE_RTOL:g} x std)"
         )
         assert err <= HYBRID_STATE_RTOL * std, (impl, err, std)
+
+    dy, ds, us_plain, us_kernel = state_step_case(rehearsal)
+    took = ("not measured (a rehearsal)" if rehearsal else
+            f"{us_kernel:.0f} us a layer, the plain step {us_plain:.0f}")
+    print(
+        f"kernels: ssm_state_step on 32 rows of a 36-layer leaf (a dead "
+        f"lane, a row begun anew): max |kernel - plain step| = {dy:.2e} on "
+        f"y, {ds:.2e} on the state (bound {STATE_STEP_ATOL:g}); {took}"
+    )
+    assert max(dy, ds) <= STATE_STEP_ATOL, (dy, ds)
+    # with nothing set, an engine of that family takes both kernels on the
+    # chip (an engine allocates and compiles nothing until it is warmed)
+    from pytorch_distributed_tpu.config import model_config
+    from pytorch_distributed_tpu.serving.engine import (
+        PagedBatchedDecodeEngine,
+    )
+
+    built = PagedBatchedDecodeEngine(
+        model_config("granite-4.0-h-micro", dtype="bfloat16"), slots=2,
+        max_len=128, page_size=64).stats()
+    want = ("gather", "xla") if rehearsal else ("kernel", "kernel")
+    assert (built["paged_decode_impl"], built["state_step_impl"]) == want, (
+        built["paged_decode_impl"], built["state_step_impl"])
+    print(f"kernels: a granite-4.0-h-micro engine with nothing set is "
+          f"built with paged_decode_impl {want[0]!r}, state_step_impl "
+          f"{want[1]!r}")
 
     # The same requests through an engine that picks its own paged
     # attention: on the chip "auto" must mean the compiled kernel.

@@ -63,7 +63,10 @@ group's padding points there. A call names each batch row's state row
 A row whose call starts at position 0 starts from ZERO state and tail inside
 the program, so a slot reused by the next request never sees the last one's;
 an entry whose ``live`` is false (a padded tail, a free or mid-prefill decode
-lane) leaves state and tail as they were.
+lane) leaves state and tail as they were. The decode step built for a TPU
+advances ``ssm`` through ops/ssm_kernel.py (``paged_impl`` "kernel": one pass
+over each live row's state where it lies in the leaf, y from the same pass);
+every other call, and every call off the chip, through ``ops/ssm.py``.
 
 Served only: no training path (the backward of the chunked scan is not
 built), so ``apply`` is the cache-free forward for tests and tools and there
@@ -267,9 +270,12 @@ def _begins_sequence(pos, live):
     return (pos == 0) & live[:, 0]
 
 
-def _mamba(h, mp, cache, layer, pos, rows, live, cfg: ModelConfig):
-    """The Mamba-2 mixer over h [B, T, E] from each row's carried state and
-    tail; returns (out [B, T, E], cache)."""
+def _mixer_inputs(h, mp, cache, layer, pos, rows, live, cfg: ModelConfig):
+    """A Mamba-2 mixer up to its recurrence: the projections of h [B, T, E],
+    the convolution from each row's carried tail, the split and the step
+    sizes. Returns (z, x [B, T, H, P], B and C [B, T, G, N], dt [B, T, H]
+    float32 and 0 where the entry is no token, a [H], the rows that begin
+    their sequence [B], the new tail)."""
     b, t, _ = h.shape
     hm, dh, n, g = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
                     cfg.mamba_n_groups)
@@ -277,10 +283,8 @@ def _mamba(h, mp, cache, layer, pos, rows, live, cfg: ModelConfig):
     zx = h @ mp["in_proj"].astype(h.dtype)
     z, xbc = zx[..., :di], zx[..., di:]
     dt = h @ mp["dt_proj"].astype(h.dtype)
-    state = _state_of(cache["ssm"], layer, rows, b)
     tail = _state_of(cache["conv"], layer, rows, b, row_axis=2)
     fresh = _begins_sequence(pos, live)
-    state = jnp.where(fresh[:, None, None, None], 0.0, state)
     tail = jnp.where(fresh[:, None, None], jnp.zeros((), tail.dtype), tail)
     n_live = jnp.sum(live, axis=1, dtype=jnp.int32)
     with jax.named_scope("ssm_conv"):
@@ -295,6 +299,34 @@ def _mamba(h, mp, cache, layer, pos, rows, live, cfg: ModelConfig):
         live[..., None],
         jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"]), 0.0)
     a = -jnp.exp(mp["A_log"].astype(jnp.float32))
+    return z, x, bm, cm, dt, a, fresh, new_tail
+
+
+def _mixer_output(y, x, z, mp, cache, layer, rows, ssm, new_tail,
+                  cfg: ModelConfig):
+    """A Mamba-2 mixer from its recurrence's y [B, T, H, P] on: the skip
+    term, the gate, the norm, the out-projection; the cache with the
+    advanced ``ssm`` leaf and the new tail written where it lies."""
+    b, t = x.shape[:2]
+    y = y + mp["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    y = y.reshape(b, t, -1).astype(z.dtype) * jax.nn.silu(z)
+    y = rms_norm(y, mp["norm"], eps=cfg.layer_norm_epsilon)
+    cache = {
+        **cache, "ssm": ssm,
+        "conv": _state_to(cache["conv"], layer, rows, new_tail, row_axis=2),
+    }
+    return y @ mp["out_proj"].astype(z.dtype), cache
+
+
+def _mamba(h, mp, cache, layer, pos, rows, live, cfg: ModelConfig):
+    """The Mamba-2 mixer over h [B, T, E] from each row's carried state and
+    tail, the recurrence by ``ops/ssm.py``; returns (out [B, T, E],
+    cache)."""
+    t = h.shape[1]
+    z, x, bm, cm, dt, a, fresh, new_tail = _mixer_inputs(
+        h, mp, cache, layer, pos, rows, live, cfg)
+    state = _state_of(cache["ssm"], layer, rows, h.shape[0])
+    state = jnp.where(fresh[:, None, None, None], 0.0, state)
     if t == 1:
         with jax.named_scope("ssm_step"):
             y, new_state = ssm_step(
@@ -307,15 +339,28 @@ def _mamba(h, mp, cache, layer, pos, rows, live, cfg: ModelConfig):
         with jax.named_scope("ssm_scan"):
             y, new_state = ssd_chunked(
                 x, dt, a, bm, cm, state, cfg.mamba_chunk_size)
-    y = y + mp["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-    y = y.reshape(b, t, di).astype(h.dtype) * jax.nn.silu(z)
-    y = rms_norm(y, mp["norm"], eps=cfg.layer_norm_epsilon)
-    cache = {
-        **cache,
-        "ssm": _state_to(cache["ssm"], layer, rows, new_state),
-        "conv": _state_to(cache["conv"], layer, rows, new_tail, row_axis=2),
-    }
-    return y @ mp["out_proj"].astype(h.dtype), cache
+    return _mixer_output(
+        y, x, z, mp, cache, layer, rows,
+        _state_to(cache["ssm"], layer, rows, new_state), new_tail, cfg)
+
+
+def _mamba_in_place(h, mp, cache, layer, pos, live, cfg: ModelConfig,
+                    interpret: bool):
+    """``_mamba`` for one token a row on the leaf's FIRST B rows (the decode
+    step), the recurrence by ops/ssm_kernel.py: one pass over each live
+    row's state where it lies in the ``ssm`` leaf, y from the same pass, a
+    dead lane's state neither read nor written."""
+    # (imported where it is used: Pallas costs its importer 1.4 s)
+    from pytorch_distributed_tpu.ops.ssm_kernel import ssm_state_step
+
+    z, x, bm, cm, dt, a, fresh, new_tail = _mixer_inputs(
+        h, mp, cache, layer, pos, None, live, cfg)
+    with jax.named_scope("ssm_step"):
+        y, ssm = ssm_state_step(
+            x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], cache["ssm"], layer,
+            live[:, 0], fresh, interpret=interpret)
+    return _mixer_output(
+        y[:, None], x, z, mp, cache, layer, None, ssm, new_tail, cfg)
 
 
 def _attention(h, ap, cache, layer, pos, tables, cfg: ModelConfig,
@@ -343,7 +388,12 @@ def _layer(x, bp, kind, cache, layer, pos, tables, rows, live,
            cfg: ModelConfig, paged_impl="gather"):
     eps, r = cfg.layer_norm_epsilon, cfg.residual_multiplier
     h = rms_norm(x, bp["ln_mix"], eps=eps)
-    if kind == "mamba":
+    if kind == "mamba" and (
+            h.shape[1] == 1 and rows is None and paged_impl != "gather"):
+        m, cache = _mamba_in_place(
+            h, bp["mixer"], cache, layer, pos, live, cfg,
+            interpret=paged_impl == "kernel_interpret")
+    elif kind == "mamba":
         m, cache = _mamba(h, bp["mixer"], cache, layer, pos, rows, live, cfg)
     else:
         m, cache = _attention(
@@ -392,7 +442,8 @@ def forward(params: Params, input_ids, cfg: ModelConfig, cache: dict, pos,
     that is given —, cache, counts [2] int32: the entries that were tokens,
     the entries computed over). ``paged_impl``: how a call of one token a
     row reads its attention layers' pages (``decode._cached_attention``:
-    "gather" / "kernel" / "kernel_interpret")."""
+    "gather" / "kernel" / "kernel_interpret") and, where ``state_rows`` is
+    left out, advances its Mamba layers' state (``_mamba_in_place``)."""
     b, t = input_ids.shape
     pos = jnp.asarray(pos, jnp.int32)
     if live is None:

@@ -9,7 +9,9 @@ step dt_t > 0 and a decay rate A < 0:
 
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t        y_t = S_t C_t
 
-(the skip D x_t and the gate are the model's). ``ssm_step`` is that line.
+(the skip D x_t and the gate are the model's). ``ssm_step`` is that line
+(``ops/ssm_kernel.py`` is the same line on a TPU, the state passed over once
+where it lies in the serving cache; this one is what it is held to).
 ``ssd_chunked`` computes the same over a block of ``chunk`` tokens at once
 (Dao & Gu 2024, "Transformers are SSMs", section 6): with l_t the running
 sum of dt A inside the block,

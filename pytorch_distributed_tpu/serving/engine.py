@@ -425,6 +425,7 @@ class DecodeEngine:
             "evictions": None,
             "kv_quant": "none",
             "paged_decode_impl": None,
+            "state_step_impl": None,
             "kv_bytes_per_position": _kv_bytes_per_position(self.cfg),
             "state_bytes_per_row": decode.serving(
                 self.cfg).state_bytes_per_row,
@@ -2660,6 +2661,7 @@ class BatchedDecodeEngine:
             "evictions": None,
             "kv_quant": "none",
             "paged_decode_impl": None,
+            "state_step_impl": None,
             # what one cache position costs across all layers, in the
             # family's own page layout (per-head K and V, or one latent),
             # and what a ROW costs whatever its depth (recurrent state)
@@ -2882,7 +2884,11 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
     defaults to ``"auto"``: its decode step reads the pool through its
     kernel (ops/latent_paged_kernel.py, ops/paged_kernel.py) on a TPU and
     through the gathered window elsewhere (``stats()["paged_decode_impl"]``
-    says which).
+    says which). The same setting decides how a family with per-row
+    recurrent state advances it in the decode step: where the pages'
+    kernel runs, one pass over each live row's state where it lies
+    (ops/ssm_kernel.py), else plain XLA (``stats()["state_step_impl"]``:
+    ``kernel`` / ``kernel_interpret`` / ``xla``; None without such state).
 
     **Page groups** (a family whose ``decode.Serving.window`` is set: some
     layers attend a sliding window): a second ``BlockPool`` (``wpool``,
@@ -3242,8 +3248,13 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
             prefix_hits=ps["prefix_hits"],
             evictions=ps["evictions"],
             kv_quant=self.kv_quant,
-            # what the decode program reads the pages through
+            # what the decode program reads the pages through, and what it
+            # advances a row's recurrent state through (ops/ssm_kernel.py
+            # wherever the pages' kernel runs; None: no such state)
             paged_decode_impl=self._paged_impl,
+            state_step_impl=(
+                {"gather": "xla"}.get(self._paged_impl, self._paged_impl)
+                if self._row_state else None),
         )
         out["counters"]["session_evictions"] = self._sessions.evictions
         if self._window:

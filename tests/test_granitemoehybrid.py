@@ -26,7 +26,11 @@ from pytorch_distributed_tpu.config import (  # noqa: E402
 )
 from pytorch_distributed_tpu.models import decode  # noqa: E402
 from pytorch_distributed_tpu.models import granitemoehybrid as gmh  # noqa: E402
-from pytorch_distributed_tpu.ops import paged_kernel, ssm  # noqa: E402
+from pytorch_distributed_tpu.ops import (  # noqa: E402
+    paged_kernel,
+    ssm,
+    ssm_kernel,
+)
 from pytorch_distributed_tpu.serving.engine import (  # noqa: E402
     BatchedDecodeEngine,
     PagedBatchedDecodeEngine,
@@ -94,6 +98,22 @@ def warm(params):
     eng = engine()
     eng.warmup(params)
     return eng
+
+
+@pytest.fixture(scope="module")
+def warm_kernels(params):
+    """The same engine with its decode step built on the two kernels, in
+    the interpreter: the attention layers' pages through ops/paged_kernel.py
+    in blocks of two pages, the Mamba layers' state through
+    ops/ssm_kernel.py."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(paged_kernel, "KEY_BLOCK", 2 * PAGE)
+        eng = engine(paged_attention="kernel_interpret")
+        eng.warmup(params)
+    return eng
+
+
+STATE_STEP_IMPL = {"warm": "xla", "warm_kernels": "kernel_interpret"}
 
 
 @jax.jit
@@ -431,26 +451,37 @@ def test_paged_engine_serves_the_reference_greedy_tokens(params, warm):
     assert st["paged_decode_impl"] == "gather"
 
 
+@pytest.mark.parametrize("head_block", [4, 1])
 def test_the_kernel_engine_serves_the_gather_engines_tokens(
-        params, warm, monkeypatch):
+        head_block, params, warm, monkeypatch):
     """``paged_attention="kernel_interpret"``: the decode step reads the
     attention layers' pages through ops/paged_kernel.py (blocks of two
     pages, so the deeper rows take several), scaled by
-    ``attention_multiplier``: the gather engine's tokens, the reference's
-    greedy continuations. The same engine over a configuration whose
+    ``attention_multiplier``, and advances the Mamba layers' state through
+    ops/ssm_kernel.py (a group's four heads as one block, and a head a
+    block): the gather engine's tokens, the reference's greedy
+    continuations, more requests than rows, so every state row is begun
+    anew inside the kernel. The same engine over a configuration whose
     multiplier is D^-1/2 (what the kernel scales by when nobody says) does
     not serve them: the scale reaches the kernel."""
     monkeypatch.setattr(paged_kernel, "KEY_BLOCK", 2 * PAGE)
+    monkeypatch.setattr(ssm_kernel, "HEAD_BLOCK", head_block)
     rng = np.random.default_rng(0)
     sent = [rng.integers(0, MODEL["vocab_size"], n).tolist()
             for n in (5, 19, 8, 30, 11, 3, 17)]
     eng = engine(paged_attention="kernel_interpret")
-    assert eng.stats()["paged_decode_impl"] == "kernel_interpret"
+    st = eng.stats()
+    assert st["paged_decode_impl"] == st["state_step_impl"] == (
+        "kernel_interpret")
+    assert warm.stats()["state_step_impl"] == "xla"
     got = serve(eng, params, sent)
     assert got == serve(warm, params, sent)
     assert all(is_greedy_reference(params, p, g) for p, g in zip(sent, got))
     c = eng.stats()["counters"]
     assert 0 < c["kv_positions_read"] < c["kv_positions_window"]
+    assert c["state_rows_advanced"] > 0
+    if head_block != 4:
+        return
     wrong = PagedBatchedDecodeEngine(
         program_config(attention_multiplier=CFG.head_dim ** -0.5), slots=4,
         max_len=MAX_LEN, page_size=PAGE, prefill_chunk=CHUNK,
@@ -491,8 +522,9 @@ def test_left_unset_every_family_builds_the_gather_off_the_chip(
         preset, dense_cache):
     """``paged_attention`` unset is "auto" (the kernel on a TPU, the gather
     here) for a family with no dense cache and "gather" for the others;
-    ``stats()`` names what the decode program was built with. (An engine
-    allocates and compiles nothing until it is warmed.)"""
+    ``stats()`` names what the decode program was built with, its state
+    step too. (An engine allocates and compiles nothing until it is
+    warmed.)"""
     cfg = model_config(preset, dtype="bfloat16")
     assert decode.serving(cfg).dense_cache == dense_cache
     for asked, built in ((None, "gather"), ("auto", "gather"),
@@ -500,6 +532,11 @@ def test_left_unset_every_family_builds_the_gather_off_the_chip(
         eng = PagedBatchedDecodeEngine(
             cfg, slots=2, max_len=128, page_size=64, paged_attention=asked)
         assert eng.stats()["paged_decode_impl"] == built
+        # a family with row state advances it through the state kernel
+        # wherever the pages' kernel runs, and in plain XLA elsewhere
+        assert eng.stats()["state_step_impl"] == (
+            {"gather": "xla", "kernel": "kernel"}[built]
+            if decode.serving(cfg).state_bytes_per_row else None)
 
 
 def test_a_padded_prefill_group_leaves_the_real_rows_pages_alone(
@@ -529,12 +566,16 @@ def test_a_slot_reused_by_a_second_request_serves_a_fresh_engines_tokens(
         assert len(gen) == 6 and is_greedy_reference(params, prompt, gen)
 
 
+@pytest.mark.parametrize("built", ["warm", "warm_kernels"])
 def test_neighbours_decode_steps_leave_other_rows_bit_unchanged(
-        params, warm, monkeypatch):
+        built, params, request, monkeypatch):
     """While one row decodes, a row in the middle of its prefill (between
     two of its chunks), a free row and the scratch row keep state and tail
-    bit for bit across every decode dispatch; the decoding row's change."""
-    eng = warm
+    bit for bit across every decode dispatch; the decoding row's change.
+    Through the plain state step (two selects) and through the state
+    kernel (a dead lane is neither read nor written)."""
+    eng = request.getfixturevalue(built)
+    assert eng.stats()["state_step_impl"] == STATE_STEP_IMPL[built]
     real, seen = eng._dispatch, []
 
     def spy(kind, *args):

@@ -13,8 +13,12 @@ dispatch); and the prefill program holds no per-position state
 one). The decode step is compiled both ways: as the chip builds it, its four
 attention layers reading their pages through ops/paged_kernel.py (one custom
 call in the period's body, and no ``[32, 4096, 512]`` window of K or V, nor
-its ``[32, 4096, 8, 64]`` relayout, anywhere in the program), and with the
-gathered window, the path off the chip.
+its ``[32, 4096, 8, 64]`` relayout, anywhere in the program) and its Mamba
+layers advancing their state through ops/ssm_kernel.py (nine custom calls in
+the period's body, the ``ssm`` leaf aliased through each, and the rows'
+state ``[32, 64, 64, 128]`` float32 nowhere among the program's values: no
+slice of the leaf is taken, no second copy of it made), and with the
+gathered window and the plain state step, the path off the chip.
 
 One file, the topology described inside a fixture: only the worker that is
 given this file loads the TPU's library (on-chip-measurement guide, 2).
@@ -31,6 +35,9 @@ import pytest
 from pytorch_distributed_tpu.config import model_config
 from pytorch_distributed_tpu.models import decode, get_model
 from pytorch_distributed_tpu.ops.paged_kernel import KERNEL_NAME
+from pytorch_distributed_tpu.ops.ssm_kernel import (
+    KERNEL_NAME as STATE_KERNEL_NAME,
+)
 from pytorch_distributed_tpu.serving.engine import PagedBatchedDecodeEngine
 
 # the cell's own engine arguments
@@ -125,14 +132,24 @@ def test_program_compiles_for_v5e_and_updates_its_cache_in_place(
         rf'custom-call\(.*custom_call_target="tpu_custom_call".*'
         rf'{KERNEL_NAME}', text))
     windows = re.findall(r"bf16\[32,4096,(?:512|8,64)\]", text)
+    # the state kernel: a custom call a Mamba layer of the period, the leaf
+    # its operand 8 and its output 1; the plain step elsewhere (a prefill
+    # chunk is many positions a row; the path off the chip)
+    state_calls = re.findall(
+        rf'custom-call\(.*custom_call_target="tpu_custom_call".*'
+        rf'{STATE_KERNEL_NAME}.*', text)
+    rows_state = re.findall(r"f32\[32,64,64,128\]", text)
     if kind == "prefill":
-        assert calls == 0
+        assert calls == 0 and not state_calls
         assert memory.temp_size_in_bytes < PER_POSITION_STATE_BYTES
     elif impl == "kernel":
         assert calls == 1 and not windows
+        assert len(state_calls) == 9 and not rows_state
+        assert all("output_to_operand_aliasing={{1}: (8, {})}" in call
+                   and "f32[36,33,64,64,128]" in call for call in state_calls)
         assert memory.temp_size_in_bytes < WINDOW_BYTES
     else:
         # the gathered window of four attention layers' K and V, nothing
         # that grows with the weights
-        assert calls == 0 and windows
+        assert calls == 0 and windows and not state_calls
         assert WINDOW_BYTES < memory.temp_size_in_bytes < 1.0e9
