@@ -564,20 +564,19 @@ class ReplicaRouter:
     def progress(self, rid: int, since: int = 0):
         """Tokens-so-far, from index ``since`` on, for a live or terminal
         router rid (the SSE streaming read) — None for unknown rids."""
-        with self.timers.span("router.progress"):
-            if rid in self.results:
-                return np.asarray(self.results[rid].tokens)[since:]
-            for orid, q in self._orphans:
-                if orid == rid:
-                    return np.concatenate([
-                        np.asarray(q.prompt, np.int32),
-                        np.asarray(q.gen, np.int32),
-                    ])[since:]
-            loc = self._assign.get(rid)
-            if loc is None:
-                return None
-            rep_id, erid = loc
-            return self._replicas[rep_id].engine.peek_tokens(erid, since)
+        if rid in self.results:
+            return np.asarray(self.results[rid].tokens)[since:]
+        for orid, q in self._orphans:
+            if orid == rid:
+                return np.concatenate([
+                    np.asarray(q.prompt, np.int32),
+                    np.asarray(q.gen, np.int32),
+                ])[since:]
+        loc = self._assign.get(rid)
+        if loc is None:
+            return None
+        rep_id, erid = loc
+        return self._replicas[rep_id].engine.peek_tokens(erid, since)
 
     def has_work(self) -> bool:
         return bool(self._orphans) or any(

@@ -124,8 +124,10 @@ class ServingServer:
         # Monotonic but for the gauge ``streams_open``; ``http_4xx``
         # leaves the 429s out. Timers (profiling/spans.py):
         # ``server.lock_wait`` (contended waits for the router lock),
-        # ``server.sse_write`` and ``request_wall`` (a generate call
-        # answered in full, from its body parsed to its last byte).
+        # ``server.peek`` (once a drive tick, under the lock: every open
+        # stream's new tokens read), ``server.sse_write`` and
+        # ``request_wall`` (a generate call answered in full, from its
+        # body parsed to its last byte).
         self.counters: dict[str, int] = {
             "http_requests": 0, "http_429": 0, "http_4xx": 0,
             "client_disconnects": 0, "streams_open": 0, "tokens_sent": 0,
@@ -199,7 +201,8 @@ class ServingServer:
         if not self.router.has_work():
             return None
         finished = self.router.step(self.params)
-        return finished, {rid: self._peek(rid, n) for rid, n in streams}
+        with self.timers.span("server.peek"):
+            return finished, {rid: self._peek(rid, n) for rid, n in streams}
 
     async def _drive_loop(self) -> None:
         while self._running:

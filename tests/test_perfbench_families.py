@@ -13,7 +13,9 @@ the whole of it takes minutes); the two files that prove what no test under
   granitemoehybrid cell (per-row recurrent state beside the pages), with its
   count module held to its issue's arithmetic;
 - ``perfbench/tests/test_mellum.py``: the same for the mellum cell (two
-  groups of pages, softmax-routed experts), with its three readers.
+  groups of pages, softmax-routed experts), with its three readers;
+- ``perfbench/tests/test_dispatch_readers.py``: the readers of the dispatch
+  boundary and the drive loop's peek (PR 38) against hand-worked answers.
 
 The cases run where they are defined; this module only names them.
 """
@@ -24,6 +26,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from perfbench.tests import (  # noqa: E402
+    test_dispatch_readers,
     test_granitemoehybrid,
     test_kimi_k2,
     test_mellum,
@@ -31,7 +34,7 @@ from perfbench.tests import (  # noqa: E402
 )
 
 for _module in (test_other_family, test_kimi_k2, test_granitemoehybrid,
-                test_mellum):
+                test_mellum, test_dispatch_readers):
     for _name, _thing in vars(_module).items():
         # its tests, and the fixture its tests ask for by name
         if _name.startswith("test_") or _name == "family":

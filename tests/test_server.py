@@ -369,3 +369,25 @@ def test_open_streams_take_no_lock_of_their_own_between_ticks():
     # no work
     assert calls.count("_tick") >= ticks
     assert set(calls) <= {"submit", "pop_result", "_peek", "_tick"}
+
+
+@pytest.mark.parametrize("n_streams", [1, 3])
+def test_a_drive_tick_reads_its_streams_under_one_span(n_streams):
+    """A drive tick (``ServingServer._tick``) reads every open stream's
+    new tokens under ONE ``server.peek`` span, whatever the number of
+    streams; ``router.progress`` has no span or timer of its own (it was
+    one a stream a tick), neither in the tick nor in a poller's fallback
+    read."""
+    cfg = _cfg()
+    params = get_model(cfg).init(jax.random.key(0), cfg)
+    server = _setup(cfg, params, n_replicas=1)
+    rids = [server.router.submit([3 + i] * 6, 4) for i in range(n_streams)]
+    for ticks in (1, 2):
+        finished, streamed = server._tick(tuple((rid, 0) for rid in rids))
+        assert sorted(streamed) == rids
+        assert server.timers.snapshot()["server.peek"]["count"] == ticks
+    assert all(since == 0 and toks is not None
+               for since, toks, _ in streamed.values())
+    server.router.progress(rids[0], 0)  # a poller's own read
+    assert not any("progress" in name
+                   for name in server.router.timers.snapshot())

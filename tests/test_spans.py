@@ -65,7 +65,10 @@ def test_timers_count_total_max_and_nest():
     assert timers.snapshot()["outer"]["count"] == 2
 
 
-def test_engine_spans_reach_the_benchmark_through_the_xplane(tmp_path):
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """(engine, trace form) of one profiler session over an engine that
+    admits, prefills and decodes: the session the cases below read."""
     cfg = _cfg()
     params = get_model(cfg).init(jax.random.key(0), cfg)
     eng = PagedBatchedDecodeEngine(
@@ -73,15 +76,32 @@ def test_engine_spans_reach_the_benchmark_through_the_xplane(tmp_path):
     )
     eng.submit(_prompt(5, 1), 8)
     eng.step(params)  # compiles outside the session
-    cap = trace.capture(str(tmp_path / "trace")).start()
+    cap = trace.capture(str(tmp_path_factory.mktemp("trace"))).start()
     eng.submit(_prompt(6, 2), 6)
     while eng.has_work():
         eng.step(params)
     form = trace.load_xplane(cap.stop())
     cap.discard()
+    return eng, form
+
+
+def _named(form, name):
+    """[(start, end)] of the host spans called ``name``, or whose name
+    starts with it when it ends with a dot."""
+    match = (lambda n: n.startswith(name)) if name.endswith(".") else (
+        lambda n: n == name)
+    return sorted((s, s + d) for n, s, d in form["host"] if match(n))
+
+
+def _inside(span, parents):
+    return any(p0 <= span[0] and span[1] <= p1 for p0, p1 in parents)
+
+
+def test_engine_spans_reach_the_benchmark_through_the_xplane(traced):
+    eng, form = traced
 
     def named(name):
-        return sorted((s, s + d) for n, s, d in form["host"] if n == name)
+        return _named(form, name)
 
     ticks = named("pb.engine.tick")
     assert len(ticks) >= 3
@@ -100,6 +120,39 @@ def test_engine_spans_reach_the_benchmark_through_the_xplane(tmp_path):
         "pb.engine.dispatch.decode_step")
     # the timers counted the same ticks, traced or not
     assert eng.stats()["timers"]["engine.tick"]["count"] == len(ticks) + 1
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode_step"])
+def test_put_launch_and_sync_nest_at_the_dispatch_boundary(traced, kind):
+    """The staged arguments' transfers (``put``) lie inside the build; the
+    program call (``launch``), then the read-back of its outputs
+    (``sync``), one of each, lie in that order inside the dispatch of the
+    same kind: what ``perfbench/layer_metrics`` ``arg_put_ms``,
+    ``launch_ms`` and ``readback_ms`` read."""
+    eng, form = traced
+    puts = _named(form, "pb.engine.put." + kind)
+    assert puts
+    builds = _named(form, "pb.engine.build.")
+    assert all(_inside(put, builds) for put in puts)
+    calls = _named(form, "pb.engine.dispatch." + kind)
+    launches = _named(form, "pb.engine.launch." + kind)
+    syncs = _named(form, "pb.engine.sync." + kind)
+    assert calls and len(launches) == len(syncs) == len(calls)
+    for d0, d1 in calls:
+        (launch,) = [s for s in launches if _inside(s, [(d0, d1)])]
+        (sync,) = [s for s in syncs if _inside(s, [(d0, d1)])]
+        assert launch[1] <= sync[0]
+    # every dispatch staged its arguments first, in the tick's own build
+    assert len(puts) == len(calls)
+    # ``tick_host_ms`` reads every ``pb.engine.dispatch.*`` as one of the
+    # tick's calls: the prefix holds the calls alone
+    assert {n for n, _, _ in form["host"]
+            if n.startswith("pb.engine.dispatch.")} == {
+        "pb.engine.dispatch.prefill", "pb.engine.dispatch.decode_step"}
+    timers = eng.stats()["timers"]
+    n = timers["engine.dispatch." + kind]["count"]
+    for stem in ("put", "launch", "sync"):
+        assert timers[f"engine.{stem}.{kind}"]["count"] == n, stem
 
 
 def test_queue_wait_and_request_timers_exact_on_a_virtual_clock():
