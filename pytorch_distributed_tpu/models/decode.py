@@ -905,12 +905,23 @@ def forward(
 # -- sampling --------------------------------------------------------------
 #
 # Greedy-vs-sampled is the ONE static bit (a greedy program has no
-# division, no vocab sort, no PRNG); everything else about the sampling
+# division, no top-k, no PRNG); everything else about the sampling
 # config is a traced scalar, so a serving loop sweeping temperature /
 # top_k / top_p reuses one compiled program. ``None`` top_k / top_p are
 # encoded as out-of-range sentinels (k = vocab size keeps the full
 # support; p = 2.0 keeps every cumulative mass) rather than separate
-# static program variants.
+# static program variants. A sampled program holds two ways to draw
+# behind ONE ``lax.cond`` on its rows' own k: the top ``SAMPLE_CAP``
+# tokens (a partial top-k), or the whole vocabulary sorted, which runs
+# only when a sampled row asks for more than the cap.
+
+# Rows asking for at most this many tokens draw from a partial top-k of
+# this width (one lane width; covers top-k 40, 50, 100). A constant of
+# the module: what the rows ask for picks the path, nothing else does.
+SAMPLE_CAP = 128
+# The top SAMPLE_CAP is found among the entries of the SAMPLE_CAP segments
+# (of this many) whose maxima are largest (``_top_cap``).
+_CAP_SEGMENTS = 2048
 
 
 def sampling_scalars(
@@ -932,9 +943,86 @@ def sampling_scalars(
     return t, k, p
 
 
+def _sample_cap(vocab_size: int) -> int:
+    return min(SAMPLE_CAP, vocab_size)
+
+
+def needs_full_sort(greedy, top_k, vocab_size: int):
+    """Whether a batch's draw needs the whole vocabulary sorted: some
+    sampled row asks for more than ``SAMPLE_CAP`` tokens (top-k off is
+    k = vocab). ``greedy`` [B] bool, ``top_k`` [B] int; numpy on the
+    host (the paged engine's ``sample_full_sort.<kind>`` counter) and
+    traced in the program (``sample_token_rows``'s branch), one rule."""
+    return ((top_k > _sample_cap(vocab_size)) & ~greedy).any()
+
+
 def _sample_greedy(logits):
     """[B, V] -> [B] argmax tokens (the static greedy program)."""
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _kept(vals, top_k, top_p):
+    """[B, n] tempered logits sorted descending -> the same with -inf
+    outside the support: ranks < top_k, then within them the tokens whose
+    CUMULATIVE mass before them is < top_p (the argmax token always
+    survives). ``top_k`` / ``top_p`` scalars or ``[B, 1]``."""
+    rank = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
+    in_k = rank < top_k
+    probs = jax.nn.softmax(jnp.where(in_k, vals, -jnp.inf), axis=-1)
+    cum_before = jnp.cumsum(probs, axis=-1) - probs
+    return jnp.where(in_k & (cum_before < top_p), vals, -jnp.inf)
+
+
+def _top_cap(x):
+    """[B, V] tempered logits -> each row's top ``SAMPLE_CAP`` values,
+    sorted descending, and their token ids; exact. Each of a row's top
+    ``SAMPLE_CAP`` lies in one of the ``SAMPLE_CAP`` segments of the
+    vocabulary with the largest maxima (a segment outside them lies under
+    ``SAMPLE_CAP`` larger maxima), so the top-k runs over those segments'
+    entries alone. On a TPU v5e at 32 rows x 100,352 a whole draw took
+    0.36 ms this way (0.07 ms more with noise over the whole vocabulary),
+    1.34 ms through the compiler's own top-k op and 3.80 ms over the full
+    sort."""
+    b, v = x.shape
+    cap = _sample_cap(v)
+    if v <= _CAP_SEGMENTS:
+        return jax.lax.top_k(x, cap)
+    n = -(-v // _CAP_SEGMENTS)  # a segment's length
+    segs = jnp.pad(
+        x, ((0, 0), (0, _CAP_SEGMENTS * n - v)), constant_values=-jnp.inf,
+    ).reshape(b, _CAP_SEGMENTS, n)
+    _, top = jax.lax.top_k(segs.max(axis=-1), cap)  # [B, cap] segments
+    within = jnp.take_along_axis(segs, top[:, :, None], axis=1)
+    vals, at = jax.lax.top_k(within.reshape(b, cap * n), cap)
+    return vals, jnp.take_along_axis(top, at // n, axis=1) * n + at % n
+
+
+def _draw(vals, idx, noise, top_k, top_p):
+    """[B, n] tempered logits sorted descending and their token ids ->
+    [B] tokens: ``jax.random.categorical`` over ``_kept``'s support,
+    written out as the argmax of the support plus the rows' Gumbel noise
+    over the whole vocabulary (``noise``, [B, V]), of which the first n
+    columns are read. Row r's noise is ``gumbel(key_r, (1, V))``, whose
+    first columns do not depend on V: a row whose top_k fits the cap
+    draws the same token from the whole vocabulary sorted as from its
+    top ``SAMPLE_CAP`` (tied values aside, whose order a top-k may
+    choose)."""
+    n = vals.shape[-1]
+    choice = jnp.argmax(
+        _kept(vals, top_k, top_p) + noise[:, :n], axis=-1)
+    return jnp.take_along_axis(
+        idx, choice[:, None], axis=-1
+    )[:, 0].astype(jnp.int32)
+
+
+def _draw_capped_or_full(full, x, noise, top_k, top_p):
+    """ONE ``lax.cond``: the whole vocabulary sorted where ``full``, else
+    the top ``SAMPLE_CAP`` alone."""
+    return jax.lax.cond(
+        full,
+        lambda: _draw(*jax.lax.top_k(x, x.shape[-1]), noise, top_k, top_p),
+        lambda: _draw(*_top_cap(x), noise, top_k, top_p),
+    )
 
 
 def _sample_traced(logits, temperature, key, top_k, top_p):
@@ -945,25 +1033,19 @@ def _sample_traced(logits, temperature, key, top_k, top_p):
     Given BOTH, top-k applies first and the nucleus is taken within it
     (HF semantics: the renormalised mass is over the top-k support).
 
-    Mechanics: one full-vocab descending sort per step (``lax.top_k`` at
-    k = V — the price of a traced k; HF's sampler pays the same sort for
-    top_p), then rank/cumulative-mass masks. The argmax token always
-    survives both filters, so top_k=1 or top_p->0 reduce to greedy.
+    Mechanics: a ``lax.cond`` on the traced k. At most ``SAMPLE_CAP``
+    (top_k 1..128) the draw reads the top ``SAMPLE_CAP`` alone
+    (``_top_cap``); above it (top-k off included: k = V) the whole
+    vocabulary is sorted, the price of a traced k (HF's sampler pays the
+    same sort for top_p). Then rank/cumulative-mass masks; top_k=1 or
+    top_p->0 reduce to greedy. The noise is drawn once, outside the
+    branch: a second draw would be traced and lowered again in every
+    program that samples.
     """
-    logits = logits.astype(jnp.float32) / temperature
-    v = logits.shape[-1]
-    vals, idx = jax.lax.top_k(logits, v)  # [B, V], sorted desc
-    rank = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-    in_k = rank < top_k
-    probs = jax.nn.softmax(jnp.where(in_k, vals, -jnp.inf), axis=-1)
-    # Keep tokens whose CUMULATIVE mass (within the top-k support)
-    # before them is < p — the argmax token always survives.
-    cum_before = jnp.cumsum(probs, axis=-1) - probs
-    vals = jnp.where(in_k & (cum_before < top_p), vals, -jnp.inf)
-    choice = jax.random.categorical(key, vals, axis=-1)  # [B]
-    return jnp.take_along_axis(
-        idx, choice[:, None], axis=-1
-    )[:, 0].astype(jnp.int32)
+    x = logits.astype(jnp.float32) / temperature
+    noise = jax.random.gumbel(key, x.shape, jnp.float32)
+    return _draw_capped_or_full(
+        top_k > _sample_cap(x.shape[-1]), x, noise, top_k, top_p)
 
 
 def sample_token(logits, sampled: bool, temperature, key, top_k, top_p):
@@ -984,20 +1066,25 @@ def sample_token_rows(logits, greedy, temperature, keys, top_k, top_p):
     PRNG keys (one per request, already folded to the row's step).
 
     Row r's draw is bit-identical to the serial path's
-    ``sample_token(logits[r:r+1], ...)`` with the same key: the sampled
-    branch IS the B=1 ``_sample_traced`` body vmapped over rows (vmap of
-    threefry is elementwise in (key, counter), so the drawn bits match the
-    individual calls), and greedy rows select the same argmax. Unlike the
-    serial engine's static greedy/sampled split, greedy here is a traced
-    flag — the batch must serve both kinds of row in one program, so the
-    sort always runs and greedy rows discard the draw (the price of one
-    program for every traffic mix)."""
-
-    def row(l, g, t, key, k, p):
-        drawn = _sample_traced(l[None], t, key, k, p)[0]
-        return jnp.where(g, _sample_greedy(l[None])[0], drawn)
-
-    return jax.vmap(row)(logits, greedy, temperature, keys, top_k, top_p)
+    ``sample_token(logits[r:r+1], ...)`` with the same key: the top-k and
+    the masks are row by row, and row r's noise is drawn from its own key
+    at the serial path's shape (vmap of threefry is elementwise in (key,
+    counter), so the bits match the individual calls). Greedy is a traced
+    flag here — the batch serves both kinds of row in one program — so
+    every row draws and greedy rows select the argmax instead. ONE
+    ``lax.cond`` at batch level (under vmap a cond would run both
+    branches) takes the partial top-k unless some sampled row asks for
+    more than ``SAMPLE_CAP`` (``needs_full_sort``): greedy rows and
+    top-k <= 128 rows never pay for the full-vocabulary sort. Nothing is
+    vmapped but the noise: the top-k sees ``[B, V]``, where a vmap would
+    hand the compiler ``[B, 1, V]``, which it sorts whole."""
+    x = logits.astype(jnp.float32) / temperature[:, None]
+    noise = jax.vmap(lambda key: jax.random.gumbel(
+        key, (1, x.shape[-1]), jnp.float32)[0])(keys)
+    drawn = _draw_capped_or_full(
+        needs_full_sort(greedy, top_k, x.shape[-1]), x, noise,
+        top_k[:, None], top_p[:, None])
+    return jnp.where(greedy, _sample_greedy(logits), drawn)
 
 
 def speculative_accept(

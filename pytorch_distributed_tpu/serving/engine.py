@@ -1048,7 +1048,9 @@ class BatchedDecodeEngine:
 
     Unlike the serial engine there is no greedy/sampled program split:
     one batch serves both kinds of row, so greedy is a traced per-row
-    flag and the full-vocab sort always runs (see
+    flag and every row draws: from its top ``decode.SAMPLE_CAP`` tokens,
+    or over the whole vocabulary sorted when some sampled row asks for
+    more, a branch inside the same program (see
     ``decode.sample_token_rows``). Program count: ONE decode_step shape
     + (buckets x prefill group sizes) prefill shapes — compile_count()
     is asserted flat across admit/retire churn in tests.
@@ -3121,6 +3123,7 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         )
         for kind in self._program_kinds():
             self.counters[f"arg_transfers.{kind}"] = 0
+            self.counters[f"sample_full_sort.{kind}"] = 0
         # A model that counts its work has its programs hand the counts
         # back between the tokens and the sentinel; they accumulate here
         # per program kind, beside what the engine itself knows of a
@@ -4038,7 +4041,13 @@ class PagedBatchedDecodeEngine(BatchedDecodeEngine):
         """The build's ONE host-to-device transfer (``engine.put.<kind>``,
         counted in ``arg_transfers.<kind>``): the dispatch's operands after
         ``params``, the cache's place left None for ``_dispatch`` and the
-        adapter tree (uploaded once a registration) after it."""
+        adapter tree (uploaded once a registration) after it. A dispatch
+        whose rows ask the sampler for the full-vocabulary sort, by the
+        rule its program applies, counts in ``sample_full_sort.<kind>``."""
+        a = self._layouts[kind].views(staged)
+        if decode.needs_full_sort(
+                a["greedy"] != 0, a["k"], self.cfg.vocab_size):
+            self.counters["sample_full_sort." + kind] += 1
         with self.timers.span("engine.put." + kind):
             self.counters["arg_transfers." + kind] += 1
             return (jax.device_put(staged, self.device), None,

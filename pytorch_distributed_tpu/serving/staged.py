@@ -108,18 +108,17 @@ def paged_layouts(*, chunk: int, spec_width: int, table_width: int,
     row a prefill group's padding points at where rows carry state (None:
     they do not, and there is no ``slot_rows`` operand); ``tenants``: an
     adapter registry is attached (a ``tenants`` operand, the rows' slots).
-    The defaults are what the builds staged before (PR 38): a free lane
-    greedy in a decode step, not in a prefill group, no top-k (``vocab``),
-    top-p off (2.0), table 0 (the scratch page)."""
+    The defaults: a lane nothing writes is greedy (so it never asks the
+    sampler for the full-vocabulary sort, ``decode.needs_full_sort``), no
+    top-k (``vocab``), top-p off (2.0), table 0 (the scratch page)."""
 
-    def sampling(greedy: bool) -> tuple[Field, ...]:
-        return (
-            Field("greedy", None, np.bool_, greedy),
-            Field("t", None, np.float32, 1.0),
-            Field("k", None, np.int32, vocab),
-            Field("p", None, np.float32, 2.0),
-            Field("keydata", key_words, np.uint32),
-        )
+    sampling = (
+        Field("greedy", None, np.bool_, True),
+        Field("t", None, np.float32, 1.0),
+        Field("k", None, np.int32, vocab),
+        Field("p", None, np.float32, 2.0),
+        Field("keydata", key_words, np.uint32),
+    )
 
     lora = (Field("tenants", None, np.int32),) if tenants else ()
     step = (
@@ -133,16 +132,16 @@ def paged_layouts(*, chunk: int, spec_width: int, table_width: int,
             Field("valid", None, np.int32, 1),
             Field("start", None, np.int32),
             Field("tables", table_width, np.int32),
-            *sampling(False),
+            *sampling,
             *(() if scratch_row is None
               else (Field("slot_rows", None, np.int32, scratch_row),)),
             *lora,
         )),
         "decode_step": StagedLayout((
-            Field("toks", None, np.int32), *step, *sampling(True), *lora,
+            Field("toks", None, np.int32), *step, *sampling, *lora,
         )),
         "decode_spec_step": StagedLayout((
-            Field("toks", spec_width, np.int32), *step, *sampling(True),
+            Field("toks", spec_width, np.int32), *step, *sampling,
             Field("n_draft", None, np.int32), *lora,
         )),
     }
